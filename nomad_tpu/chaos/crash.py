@@ -111,7 +111,10 @@ class ServerProcess:
             os.path.abspath(__file__))))
         env = dict(os.environ)
         env["PYTHONPATH"] = root + os.pathsep + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # the child servers run the host binpack scheduler
+        # (crash_server.py) and need no chip; a chip belongs to one
+        # process, so never hand them the parent's platform
+        env["JAX_PLATFORMS"] = "cpu"
         self._logf = open(os.path.join(self.data_dir, "server.log"), "ab")
         self.proc = subprocess.Popen(
             cmd, stdout=self._logf, stderr=subprocess.STDOUT, env=env,

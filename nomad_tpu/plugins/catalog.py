@@ -28,10 +28,14 @@ _external_instances: Dict[str, object] = {}
 
 
 def _plugin_env() -> dict:
-    """Subprocess env: make the framework importable from the repo root."""
+    """Subprocess env: make the framework importable from the repo root,
+    and keep the plugin off the accelerator. A chip belongs to one
+    process — the agent whose scheduler dispatches to it — so a plugin
+    subprocess that imports JAX must never initialise the TPU backend."""
     env = dict(os.environ)
     pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
     return env
 
 

@@ -36,6 +36,12 @@ class TPUDevicePlugin(DevicePlugin):
         return self.config_schema_spec
 
     def _detect(self) -> List[DeviceGroup]:
+        # jax.devices() initialises the backend in WHICHEVER process hosts
+        # this plugin. In-process (builtin_device_plugin, e.g. a -dev
+        # agent) that is the server's own process, which already owns the
+        # chip. Out of process the catalog pins JAX_PLATFORMS=cpu
+        # (catalog._plugin_env), so a plugin subprocess can never take
+        # the chip from the server — it reports no TPU devices instead.
         try:
             import jax
 

@@ -319,7 +319,7 @@ class DeviceBatcher:
             # gather-window latency (enqueue -> dispatch start), the
             # quantity the adaptive idle gap bounds: an operator watching
             # /v1/metrics sees directly whether batching is adding
-            # scheduling latency (VERDICT r4 weak #6)
+            # scheduling latency
             "gather_wait_ms_total": 0.0,
             "gather_wait_ms_max": 0.0,
             # per-dispatch timing split (ISSUE 4 device profiling hooks):
@@ -330,6 +330,14 @@ class DeviceBatcher:
             "compute_ms_total": 0.0,
             "transfer_ms_total": 0.0,
             "d2h_bytes_total": 0,
+            # degradations that keep the eval alive but hide a device
+            # problem unless counted: a batched dispatch that raised and
+            # was retried eval-by-eval on the single scan, and a sibling
+            # bucket whose background compile failed. Zero in a healthy
+            # run; chip_smoke.py fails on either. (Published with the rest
+            # of this dict as nomad.device_batcher.* gauges.)
+            "batch_fallbacks": 0,
+            "prewarm_failures": 0,
         })
         # Demand-aware gather (guarded-by: _lock): workers announce an
         # encode-in-flight destined for this batcher via expect(); the
@@ -499,18 +507,21 @@ class DeviceBatcher:
     def _run_batch_safe(self, batch: List[_Request]) -> None:
         try:
             self._run_batch(batch)
-        except BaseException:  # noqa: BLE001 — confine the blast radius
-            logger.exception(
+        except Exception:  # noqa: BLE001 — confine the blast radius
+            logger.warning(
                 "batched dispatch failed; retrying %d evals individually",
-                len(batch),
+                len(batch), exc_info=True,
             )
+            with self._lock:
+                self.stats["batch_fallbacks"] += 1
             from .engine import TpuPlacementEngine
 
             engine = TpuPlacementEngine.shared()
             for req in batch:
                 try:
                     req.result = engine.run_scan_single(req.enc)
-                except BaseException as e:  # noqa: BLE001
+                except Exception as e:  # noqa: BLE001 — the engine's
+                    # dispatch guard degrades this eval to the host stack
                     req.error = e
                 req.event.set()
 
@@ -579,8 +590,12 @@ class DeviceBatcher:
                     )
                     scan = self._scan_fn()
                     np.asarray(scan(*stacked)[1][0])
-                except BaseException:  # noqa: BLE001 — warming is best-effort
-                    logger.debug("bucket prewarm failed", exc_info=True)
+                except Exception:  # noqa: BLE001 — warming never fails a
+                    # dispatch: the bucket compiles on its first real use
+                    logger.warning("bucket prewarm failed (b=%d)", b,
+                                   exc_info=True)
+                    with self._lock:
+                        self.stats["prewarm_failures"] += 1
 
         t = threading.Thread(target=warm, name="batcher-prewarm", daemon=True)
         with self._lock:
@@ -648,6 +663,8 @@ class DeviceBatcher:
         }
 
     def _run_batch(self, batch: List[_Request]) -> None:
+        import jax
+
         from ..utils import metrics
         from ..utils import phases as _phases
 
@@ -712,12 +729,7 @@ class DeviceBatcher:
             # device work so np.asarray below times ONLY the D2H copy
             _carry, (chosen, scores, pulls, skipped, evict) = scan(
                 static_b, carry_b, xs_b)
-            try:
-                import jax
-
-                jax.block_until_ready((chosen, scores, pulls, skipped, evict))
-            except Exception:  # noqa: BLE001 — non-jax outputs need no fence
-                pass
+            jax.block_until_ready((chosen, scores, pulls, skipped, evict))
             t_compute = metrics.now()
             chosen = np.asarray(chosen)
             scores = np.asarray(scores)
@@ -795,7 +807,7 @@ class DeviceBatcher:
                 ]
                 n_pad = n_pad2
         # Warm the SIBLING batch buckets of this shape in the background
-        # (VERDICT r3 #3: precompile pinned buckets): the first dispatch
+        # (precompile pinned buckets): the first dispatch
         # of a new shape pays its own compile/cache-load synchronously,
         # but the follow-up waves (smaller tails, single-eval retries)
         # must not stall multi-second on theirs. One zero-input call per

@@ -21,10 +21,8 @@ def compute_placements_with_engine(sched, destructive, place):
         # the lazy engine import is part of the gate cost: the first
         # eval pays it (jax + kernel modules), and outside the span it
         # surfaced as a one-shot unexplained worker_busy chunk
-        try:
-            from .engine import TpuPlacementEngine
-        except ImportError:
-            return NotImplemented
+        from .engine import TpuPlacementEngine
+
         engine = TpuPlacementEngine.shared()
         return engine.compute_placements(sched, destructive, place)
 
@@ -35,9 +33,7 @@ def compute_system_placements_with_engine(sched, place, sched_config=None):
     nodes remain for the host loop, NotImplemented to fall back to the
     host per-node stack wholesale."""
     with _phases.track("engine_gate"):
-        try:
-            from .engine import TpuPlacementEngine
-        except ImportError:
-            return NotImplemented
+        from .engine import TpuPlacementEngine
+
         engine = TpuPlacementEngine.shared()
         return engine.compute_system_placements(sched, place, sched_config)

@@ -18,7 +18,9 @@ from typing import List, Optional
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # shared compile cache: each agent process would otherwise pay the full
-# first-jit cost on CPU
+# first-jit cost on CPU. The engine sets no cache directory in code when
+# JAX_COMPILATION_CACHE_DIR is set (engine._enable_persistent_compile_cache),
+# so every agent's entries land here.
 JAX_CACHE = "/tmp/nomad-e2e-jax-cache"
 
 

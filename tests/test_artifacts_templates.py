@@ -293,7 +293,7 @@ class TestVaultTemplateEndToEnd:
 
 class TestEndToEnd:
     def test_artifact_template_restart_on_change(self, http_files, consul):
-        """The VERDICT's done-condition: a job whose task fetches an
+        """The done-condition: a job whose task fetches an
         artifact from a local HTTP server and renders a template from
         the mock Consul, restarting when the KV value changes."""
         from nomad_tpu.client.client import Client, ClientConfig, ServerProxy

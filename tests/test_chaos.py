@@ -206,10 +206,14 @@ def _placement_map(server, job):
     return {a.name: a.node_id for a in allocs}
 
 
-def test_device_fault_forces_host_fallback_with_parity():
+def test_device_fault_forces_host_fallback_with_parity(caplog, monkeypatch):
     """The same eval placed twice — once through the device batcher, once
     with every device dispatch failing (host-iterator fallback) — must
-    land every task on the same node (the bit-parity contract)."""
+    land every task on the same node (the bit-parity contract). The
+    degrade is loud: counted and logged at warning with the exception."""
+    from tests.test_system_engine import _CounterSpy
+
+    spy = _CounterSpy(monkeypatch)
     cfg = ServerConfig(
         num_schedulers=1,
         deterministic=True,
@@ -244,8 +248,15 @@ def test_device_fault_forces_host_fallback_with_parity():
             s.stop()
 
     device_map, device_stats = run_once(faulted=False)
-    host_map, host_stats = run_once(faulted=True)
+    assert "nomad.tpu_engine.dispatch_fallback_host" not in spy.calls
+    with caplog.at_level("WARNING", logger="nomad_tpu.tpu.engine"):
+        host_map, host_stats = run_once(faulted=True)
 
+    assert "nomad.tpu_engine.dispatch_fallback_host" in spy.calls
+    warned = [r for r in caplog.records
+              if "device dispatch failed" in r.getMessage()]
+    assert warned and all(r.levelname == "WARNING" and r.exc_info
+                          for r in warned)
     assert device_stats["dispatches"] > 0, "control run must use the device"
     assert host_stats["dispatches"] == 0, \
         "faulted run must never complete a device dispatch"

@@ -1,5 +1,5 @@
 """Cluster operations: runtime join, force-leave, gossip key rotation,
-client GC (VERDICT r3 #6; reference command/agent/http.go:176-185,
+client GC (reference command/agent/http.go:176-185,
 serf keyring protocol, client/gc.go)."""
 
 import base64

@@ -310,7 +310,7 @@ def test_dense_store_snapshot_roundtrip(server):
 
 
 def test_encode_cache_shares_arrays_across_identical_jobs(server):
-    """Whole-eval encode cache (VERDICT r4 #1/#4): a burst of identical
+    """Whole-eval encode cache: a burst of identical
     fresh jobs encodes ONCE; the cached arrays produce plans identical
     to uncached encoding, and per-eval ring offsets still differ under
     ring decorrelation."""

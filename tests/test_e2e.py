@@ -364,7 +364,7 @@ class TestHostVolumes:
 
 class TestClusterOpsE2E:
     """Config-file boot + runtime join + key rotation + force-leave +
-    client GC, over REAL forked agent processes (VERDICT r3 #4/#6 e2e
+    client GC, over REAL forked agent processes (e2e
     criteria; reference e2e slots for agent config and cluster ops)."""
 
     def test_config_boot_join_rotate_forceleave_gc(self, tmp_path):
@@ -469,7 +469,7 @@ client {{
 
 
 class TestServerFailoverE2E:
-    """Multi-server black-box failover (VERDICT r4 ask #6; reference
+    """Multi-server black-box failover (reference
     nomad/testing.go:41 multi-server clusters + testutil/wait.go:85
     WaitForLeader): 3 fork-exec wire-raft server agents + a client
     agent; SIGKILL the leader mid-workload and assert a new leader

@@ -103,7 +103,7 @@ class TestInteractiveExec:
 
     def test_exec_shell_session_via_cli(self, agent, monkeypatch):
         """CLI `alloc exec -i` round-trips a shell session against a live
-        agent (VERDICT item 8 done-criterion)."""
+        agent (the done-criterion)."""
         import io
         import sys as sys_mod
 

@@ -1,4 +1,4 @@
-"""Concurrency stress: the ``-race``-slot suite (VERDICT r3 #9; reference
+"""Concurrency stress: the ``-race``-slot suite (reference
 GNUmakefile:293 runs `go test -race`). Python has no race sanitizer, so
 these tests hammer the heavily-threaded subsystems — eval broker, plan
 queue/applier, device batcher, state store — from many threads and assert
@@ -636,7 +636,7 @@ class TestFlightRecorderOverhead:
 
 
 class TestBlockingQueryFanout:
-    """VERDICT r4 ask #7: fleet-scale client fan-out — hundreds of
+    """Fleet-scale client fan-out — hundreds of
     simulated clients holding Node.GetClientAllocs blocking queries
     (state_store.blocking_query, the reference's
     state_store.go:188 / client.go:1873 watch path) while a C1M-shaped
