@@ -1,0 +1,8 @@
+"""Plan applier: seconds of the window in which some thread was inside
+plan_evaluate (utils/phases.py unions, summed), per 1,000 placements
+committed in the window. layer: plan applier; moves submit_commit_p50_ms."""
+from harness.scan import phase_seconds_per_kp
+
+
+def read(ctx):
+    return phase_seconds_per_kp(ctx, ('plan_evaluate',))

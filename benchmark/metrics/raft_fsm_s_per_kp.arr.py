@@ -1,0 +1,8 @@
+"""Raft/fsm and state store: seconds of the window in which some thread was inside
+raft_fsm (utils/phases.py unions, summed), per 1,000 placements
+committed in the window. layer: raft/FSM and state store; moves submit_commit_p50_ms."""
+from harness.scan import phase_seconds_per_kp
+
+
+def read(ctx):
+    return phase_seconds_per_kp(ctx, ('raft_fsm',))
