@@ -15,7 +15,7 @@ import json
 import os
 import re
 from dataclasses import dataclass
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
 
 @dataclass(frozen=True)
@@ -100,6 +100,44 @@ def resolve_call_name(func: ast.AST, aliases: Dict[str, str]) -> Optional[str]:
     if full is not None:
         name = full + ("." + rest if rest else "")
     return name
+
+
+def module_locals(tree: ast.Module, leaf: str) -> Set[str]:
+    """Local names an import statement (absolute or relative, at any
+    depth of the module) binds to a module whose last segment is
+    ``leaf``: ``from ..trace import lifecycle as _tlc`` -> {"_tlc"}."""
+    names: Set[str] = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom):
+            for a in node.names:
+                if a.name == leaf:
+                    names.add(a.asname or a.name)
+        elif isinstance(node, ast.Import):
+            for a in node.names:
+                if a.asname and a.name.split(".")[-1] == leaf:
+                    names.add(a.asname)
+    return names
+
+
+#: the span calls of the served path, as ``span_site`` names them
+_PHASES_CALLS = ("track", "record")
+_LIFECYCLE_CALLS = ("stage", "pipeline_stage", "pipeline_record")
+
+
+def span_site(call: ast.Call, phases_names: Set[str],
+              lifecycle_names: Set[str]) -> Optional[str]:
+    """``"phases.track"``, ``"phases.record"``, ``"lifecycle.stage"``,
+    ``"lifecycle.pipeline_stage"`` or ``"lifecycle.pipeline_record"`` when
+    ``call`` is that function called on a local name bound to the module
+    (``module_locals``); else None."""
+    func = call.func
+    if not (isinstance(func, ast.Attribute) and isinstance(func.value, ast.Name)):
+        return None
+    if func.value.id in phases_names and func.attr in _PHASES_CALLS:
+        return "phases." + func.attr
+    if func.value.id in lifecycle_names and func.attr in _LIFECYCLE_CALLS:
+        return "lifecycle." + func.attr
+    return None
 
 
 def body_walk(fn: ast.AST) -> Iterable[ast.AST]:

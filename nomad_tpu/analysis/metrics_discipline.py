@@ -21,6 +21,12 @@ string grows every retained interval without bound and makes
      in the scanned module set, i.e. on full-tree runs; fixtures opt in
      via ``extra_modules``).
 
+The same holds for the names of spans: ``utils/phases`` keeps a list of
+intervals per phase name and ``trace/lifecycle`` a ring per stage name,
+so the name argument of ``phases.track``/``phases.record`` and of
+``lifecycle.stage``/``pipeline_stage``/``pipeline_record`` is a string
+literal or an UPPER_CASE module constant, never minted at run time.
+
 ``publish_family`` itself must be called with a literal registered
 prefix. The registry module is exempt (it IS the blessed door), as is
 ``utils/metrics.py`` (the sink's internal fan-out plumbing).
@@ -31,7 +37,14 @@ import ast
 import re
 from typing import Dict, List, Optional, Set
 
-from .core import Finding, ParsedModule, import_aliases, resolve_call_name
+from .core import (
+    Finding,
+    ParsedModule,
+    import_aliases,
+    module_locals,
+    resolve_call_name,
+    span_site,
+)
 
 RULE = "metrics-discipline"
 
@@ -43,6 +56,8 @@ _CONST_RE = re.compile(r"^[A-Z][A-Z0-9_]*$")
 #: modules exempt from call-site checks: the blessed dynamic-name door
 #: and the sink's own plumbing
 _EXEMPT = ("utils/metric_names.py", "utils/metrics.py")
+#: the span plumbing itself: ``stage`` hands its name on to ``record``
+_SPAN_EXEMPT = ("trace/lifecycle.py", "utils/phases.py")
 
 
 def _is_metrics_call(call: ast.Call, aliases: Dict[str, str]) -> Optional[str]:
@@ -124,6 +139,37 @@ class MetricsDisciplineChecker:
         aliases = import_aliases(module.tree)
         findings: List[Finding] = []
         self._visit(module, module.tree, aliases, False, findings)
+        findings.extend(self._span_names(module))
+        return findings
+
+    @staticmethod
+    def _span_names(module: ParsedModule) -> List[Finding]:
+        """Phase and stage names are literals or module constants."""
+        if module.rel.endswith(_SPAN_EXEMPT):
+            return []
+        phases_names = module_locals(module.tree, "phases")
+        lifecycle_names = module_locals(module.tree, "lifecycle")
+        if not phases_names and not lifecycle_names:
+            return []
+        findings: List[Finding] = []
+        for node in ast.walk(module.tree):
+            if not isinstance(node, ast.Call) or not node.args:
+                continue
+            site = span_site(node, phases_names, lifecycle_names)
+            if site is None:
+                continue
+            name_arg = node.args[0]
+            if isinstance(name_arg, ast.Constant) \
+                    and isinstance(name_arg.value, str):
+                continue
+            if _const_name(name_arg) is not None:
+                continue
+            findings.append(Finding(
+                RULE, module.rel, node.lineno,
+                f"span name passed to {site}() is dynamic: phases and "
+                f"lifecycle keep a table per name, so it must be a string "
+                f"literal or an UPPER_CASE constant",
+            ))
         return findings
 
     def _visit(self, module: ParsedModule, node: ast.AST,

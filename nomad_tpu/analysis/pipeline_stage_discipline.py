@@ -18,8 +18,17 @@ The eval-lifecycle pipeline (``nomad_tpu/pipeline/``) only stays correct
    an explicit positive ``maxsize``) so backpressure propagates to the
    producer instead.
 
-Scope is syntactic: modules whose path sits under ``nomad_tpu/pipeline/``.
-Violations are recognized by call shape — a call whose resolved dotted
+3. **One interval, one call.** A stage of the served path is bracketed by
+   ``lifecycle.stage(name, eval_id)`` alone: it feeds the eval's record,
+   the phase union and the pipeline ring in one go, on one clock. A
+   ``with`` statement that opens both ``phases.track(...)`` and a
+   ``lifecycle.stage``/``pipeline_stage`` span (in one statement, or one
+   directly inside the other) stamps one interval twice — the shape
+   ``stage`` replaced — and is flagged in EVERY module, not only under
+   ``pipeline/``.
+
+Scope of rules 1 and 2 is syntactic: modules whose path sits under
+``nomad_tpu/pipeline/``. Violations are recognized by call shape — a call whose resolved dotted
 name ends in ``raft_apply``, a ``<...>.raft.apply(...)`` chain, an
 attribute call named ``upsert_<x>``/``delete_<x>``, or a
 ``queue.Queue``/``SimpleQueue`` construction without a positive
@@ -28,9 +37,16 @@ attribute call named ``upsert_<x>``/``delete_<x>``, or a
 from __future__ import annotations
 
 import ast
-from typing import List, Optional
+from typing import List, Optional, Set
 
-from .core import Finding, ParsedModule, import_aliases, resolve_call_name
+from .core import (
+    Finding,
+    ParsedModule,
+    import_aliases,
+    module_locals,
+    resolve_call_name,
+    span_site,
+)
 
 RULE = "pipeline-stage-discipline"
 
@@ -63,14 +79,51 @@ def _unbounded_queue(call: ast.Call, name: Optional[str]) -> Optional[str]:
     return None  # explicit non-constant/positive maxsize: caller's bound
 
 
+def _double_brackets(module: ParsedModule) -> List[Finding]:
+    """Rule 3: ``with`` statements that open a phases.track span and a
+    lifecycle span over one interval."""
+    phases_names = module_locals(module.tree, "phases")
+    lifecycle_names = module_locals(module.tree, "lifecycle")
+    if not phases_names or not lifecycle_names:
+        return []
+
+    def kinds(node: ast.With) -> Set[str]:
+        return {
+            site.split(".")[0]
+            for item in node.items
+            if isinstance(item.context_expr, ast.Call)
+            for site in [span_site(item.context_expr, phases_names,
+                                   lifecycle_names)]
+            if site in ("phases.track", "lifecycle.stage",
+                        "lifecycle.pipeline_stage")
+        }
+
+    findings: List[Finding] = []
+    for node in ast.walk(module.tree):
+        if not isinstance(node, ast.With):
+            continue
+        opened = kinds(node)
+        if opened and len(node.body) == 1 and isinstance(node.body[0], ast.With):
+            opened |= kinds(node.body[0])
+        if opened == {"phases", "lifecycle"}:
+            findings.append(Finding(
+                RULE, module.rel, node.lineno,
+                "one interval bracketed by both phases.track and a "
+                "lifecycle span: use lifecycle.stage(name, eval_id) alone "
+                "— it feeds the eval's record, the phase union and the "
+                "pipeline ring on one clock",
+            ))
+    return findings
+
+
 class PipelineStageDisciplineChecker:
     rule = RULE
 
     def check(self, module: ParsedModule) -> List[Finding]:
+        findings = _double_brackets(module)
         if not _in_scope(module.rel):
-            return []
+            return findings
         aliases = import_aliases(module.tree)
-        findings: List[Finding] = []
         for node in ast.walk(module.tree):
             if not isinstance(node, ast.Call):
                 continue

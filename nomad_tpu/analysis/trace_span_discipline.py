@@ -21,23 +21,38 @@ span it left minutes ago, and ``coverage()`` double-counts.
 
 Span factories are recognized syntactically: a call whose resolved
 dotted name ends in ``phases.track`` (any alias — ``_phases.track``,
-``nomad_tpu.utils.phases.track``), or an attribute call named ``_span``
-(the Worker span helper's naming convention).
+``nomad_tpu.utils.phases.track``), ``stage`` or ``pipeline_stage`` called
+on a local name an import binds to ``trace/lifecycle`` (``_tlc.stage``,
+the served path's one span call), or an attribute call named ``_span``
+(the Worker span helper's naming convention). ``phases.record`` and
+``lifecycle.pipeline_record`` take an interval timed elsewhere and open
+nothing: plain calls.
 """
 from __future__ import annotations
 
 import ast
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Set
 
-from .core import Finding, ParsedModule, import_aliases, resolve_call_name
+from .core import (
+    Finding,
+    ParsedModule,
+    import_aliases,
+    module_locals,
+    resolve_call_name,
+    span_site,
+)
 
 RULE = "trace-span-discipline"
 
 
-def _is_span_factory(call: ast.Call, aliases: Dict[str, str]) -> Optional[str]:
+def _is_span_factory(call: ast.Call, aliases: Dict[str, str],
+                     lifecycle_names: Set[str]) -> Optional[str]:
     """The display name of the span factory being called, or None."""
     if isinstance(call.func, ast.Attribute) and call.func.attr == "_span":
         return "._span"
+    if span_site(call, set(), lifecycle_names) in (
+            "lifecycle.stage", "lifecycle.pipeline_stage"):
+        return f"{call.func.value.id}.{call.func.attr}"
     name = resolve_call_name(call.func, aliases)
     if name is None:
         return None
@@ -56,6 +71,7 @@ class TraceSpanDisciplineChecker:
 
     def check(self, module: ParsedModule) -> List[Finding]:
         aliases = import_aliases(module.tree)
+        lifecycle_names = module_locals(module.tree, "lifecycle")
 
         # pass 1: collect the call nodes sitting in a legal position
         ok = set()
@@ -74,7 +90,7 @@ class TraceSpanDisciplineChecker:
         for node in ast.walk(module.tree):
             if not isinstance(node, ast.Call) or id(node) in ok:
                 continue
-            name = _is_span_factory(node, aliases)
+            name = _is_span_factory(node, aliases, lifecycle_names)
             if name is None:
                 continue
             findings.append(Finding(

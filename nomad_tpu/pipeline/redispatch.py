@@ -194,7 +194,7 @@ class Redispatcher:
 
         engine = TpuPlacementEngine.shared()
         batcher = self.server.device_batcher
-        with _lifecycle.pipeline_stage("dispatch", plan.eval_id):
+        with _lifecycle.stage("device_wait", plan.eval_id):
             if batcher is not None:
                 chosen, scores, pulls, skipped, _evict = batcher.run(retry_enc)
             else:

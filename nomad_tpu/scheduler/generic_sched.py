@@ -241,11 +241,10 @@ class GenericScheduler:
         # The host-work semaphore parks excess worker threads (GIL
         # convoy guard — utils/hostwork.py); it is released before
         # placement, which may block on the batched device dispatch.
-        from ..utils import phases as _phases
         from ..utils.hostwork import HOST_WORK_SEM
 
         with HOST_WORK_SEM:
-            with _phases.track("reconcile"):
+            with _trace_lc.stage("reconcile", self.eval.id):
                 results = self._reconcile_job_allocs()
         if results is not None:
             self._compute_placements(results.destructive_update, results.place)

@@ -36,7 +36,7 @@ from ..chaos.injector import fire as chaos_fire
 from ..structs.funcs import remove_allocs
 from ..structs.network import NetworkIndex
 from ..trace import lifecycle as _lifecycle
-from ..utils import metrics, phases
+from ..utils import metrics
 from ..structs.structs import (
     EVAL_STATUS_PENDING,
     EVAL_TRIGGER_PREEMPTION,
@@ -656,9 +656,7 @@ class Planner:
         for bi, pending in enumerate(batch):
             try:
                 start = metrics.now()
-                with phases.track("plan_evaluate"), \
-                        _lifecycle.pipeline_stage("evaluate",
-                                                  pending.plan.eval_id):
+                with _lifecycle.stage("plan_evaluate", pending.plan.eval_id):
                     result = self.evaluate_plan(snap, pending.plan)
                 metrics.measure_since("nomad.plan.evaluate", start)
                 if result.is_noop():
@@ -740,18 +738,15 @@ class Planner:
         def waiter() -> None:
             try:
                 start = metrics.now()
-                commit_t0 = _lifecycle.pipeline_now()
-                with phases.track("raft_fsm"):
+                # one raft_fsm stage (and commit ring span) per wave in
+                # the batched entry, all of the one interval
+                with _lifecycle.stage(
+                        "raft_fsm", [p["eval_id"] for p in payloads]) as commit:
                     index, errors = self.raft.apply(
                         self.peer, APPLY_PLAN_RESULTS_BATCH, payloads
                     )
                 metrics.measure_since("nomad.plan.apply", start)
-                commit_t1 = _lifecycle.pipeline_now()
                 for i, (pending, result, payload) in enumerate(items):
-                    # one commit-stage span per wave in the batched entry
-                    _lifecycle.pipeline_record(
-                        "commit", payload["eval_id"], commit_t0, commit_t1
-                    )
                     # per-payload isolation (fsm._apply_plan_results_batch):
                     # a failed payload must not be reported as committed,
                     # and committed ones must not be reported as failed
@@ -771,7 +766,8 @@ class Planner:
                         if stored is not None:
                             alloc.create_index = stored.create_index
                             alloc.modify_index = stored.modify_index
-                    _lifecycle.on_apply(payload["eval_id"])
+                    _lifecycle.on_apply(payload["eval_id"], commit_t=commit.t1,
+                                        commit_index=index)
                     pending.future.set_result(result)
                 index_future.set_result(index)
             except Exception as e:  # noqa: BLE001

@@ -404,21 +404,14 @@ class Server:
         # applier's own tracked region too — the phase union dedups): the
         # worker-thread applies (eval status updates, follow-up evals)
         # otherwise show up as unexplained worker_busy time
-        from ..utils import phases
-
         chaos_fire("raft_apply", entry_type=entry_type)
         from ..trace import lifecycle as _lc
 
-        t0 = _lc.pipeline_now()
-        try:
-            with phases.track("raft_fsm"):
-                return self.raft.apply(self.peer, entry_type, payload)
-        finally:
-            # same span on the lifecycle (monotonic) clock, keyed by entry
-            # type: attribution joins it against the wave windows (phases
-            # uses perf_counter and bench-window unions — wrong clock and
-            # wrong granularity for per-wave critical paths)
-            _lc.pipeline_record("raft_fsm", entry_type, t0, _lc.pipeline_now())
+        # no eval to name here: the raft_fsm phase plus an aux ring span
+        # keyed by entry type, which attribution joins against the wave
+        # windows
+        with _lc.stage("raft_fsm", tag=entry_type):
+            return self.raft.apply(self.peer, entry_type, payload)
 
     def start(self) -> None:
         for i in range(self.config.num_schedulers):

@@ -243,25 +243,21 @@ class Worker:
             # wait for the raft index WITHOUT the host-work permit (it can
             # block seconds); the snapshot COPY is a pure-GIL table clone —
             # park excess threads for that part only
-            wait_t0 = _lifecycle.pipeline_now()
-            with phases.track("wait_index"):
+            # ONE stage call: the eval's record, the wait_index phase and
+            # the wait_min_index ring span the attribution engine joins
+            # against the wave windows ("wait_min_index: 41% of makespan"
+            # names this exact block)
+            with _lifecycle.stage("wait_index", evaluation.id) as waited:
                 self.server.fsm.state.wait_min_index(wait_index)
-            # per-eval SnapshotMinIndex wait span on the lifecycle clock:
-            # the attribution engine joins these against the wave windows
-            # ("wait_min_index: 41% of makespan" names this exact block)
-            wait_t1 = _lifecycle.pipeline_now()
-            _lifecycle.pipeline_record(
-                "wait_min_index", evaluation.id, wait_t0, wait_t1,
-            )
             _xcontext.record_span(
                 "eval.wait_min_index",
-                _xcontext.wall_from_monotonic(wait_t0),
-                _xcontext.wall_from_monotonic(wait_t1),
+                _xcontext.wall_from_monotonic(waited.t0),
+                _xcontext.wall_from_monotonic(waited.t1),
                 trace_id=trace_id, parent_id=trace_parent,
                 attrs=span_attrs,
             )
             with HOST_WORK_SEM:
-                with phases.track("snapshot"):
+                with _lifecycle.stage("snapshot", evaluation.id):
                     # read-only shared view: a burst of evals at one state
                     # version shares one table clone (schedulers never
                     # mutate their snapshot; the plan applier, which does,
@@ -271,6 +267,7 @@ class Worker:
                     )
         metrics.measure_since("nomad.worker.wait_for_index", start)
         self._snapshot_index = snapshot.latest_index
+        _lifecycle.on_snapshot(evaluation.id, snapshot.latest_index)
         sched = new_scheduler(evaluation.type, self.logger, snapshot, self)
         if hasattr(sched, "deterministic"):
             sched.deterministic = self.server.config.deterministic
@@ -355,7 +352,7 @@ class Worker:
                 )
                 try:
                     with self._span("submit_plan", plan.eval_id):
-                        with phases.track("plan_submit"):
+                        with _lifecycle.stage("plan_submit", plan.eval_id):
                             pending = self.server.plan_queue.enqueue(plan)
                             result = pending.future.result(timeout=60)
                 finally:

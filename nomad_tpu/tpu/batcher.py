@@ -26,9 +26,13 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+import itertools
+
 from ..chaos.injector import fire as chaos_fire
 from .engine import EncodedEval, _build_batched_scan, _round_up
 from .intscore import E27_ONE as _E27_NEUTRAL
+from ..trace import lifecycle as _lifecycle
+from ..utils import phases as _phases
 from ..utils.lock_witness import witness_lock
 from ..utils.race_witness import tracked_dict
 
@@ -39,6 +43,8 @@ logger = logging.getLogger("nomad_tpu.tpu.batcher")
 # warm-compile threads deterministically instead of letting interpreter
 # teardown race them into the runtime (the multichip dryrun's rc 139)
 _LIVE: "weakref.WeakSet" = weakref.WeakSet()
+# tells one batcher's dispatch records from another's in lifecycle's ring
+_SERIAL = itertools.count(1)
 
 
 def shutdown_all() -> None:
@@ -250,16 +256,16 @@ def pad_encoded(enc: EncodedEval, n_pad: int, g_pad: int, s_pad: int,
 
 
 class _Request:
-    __slots__ = ("enc", "event", "result", "error", "t_enqueue")
+    __slots__ = ("enc", "eval_id", "event", "result", "error", "t_enqueue")
 
     def __init__(self, enc: EncodedEval) -> None:
         self.enc = enc
+        # made on the worker's thread, inside its device_wait stage
+        self.eval_id = _lifecycle.current_eval()
         self.event = threading.Event()
         self.result = None
         self.error: Optional[BaseException] = None
-        import time
-
-        self.t_enqueue = time.monotonic()
+        self.t_enqueue = _phases.now()
 
 
 class DeviceBatcher:
@@ -299,6 +305,7 @@ class DeviceBatcher:
         self._lock = witness_lock("batcher.DeviceBatcher._lock")
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
+        self._serial = next(_SERIAL)
         # observability — the server publishes these as
         # nomad.device_batcher.* gauges in its stats sweep (/v1/metrics).
         # Written by the dispatcher thread AND by scheduler workers on the
@@ -322,14 +329,19 @@ class DeviceBatcher:
             # scheduling latency
             "gather_wait_ms_total": 0.0,
             "gather_wait_ms_max": 0.0,
-            # per-dispatch timing split (ISSUE 4 device profiling hooks):
-            # host pad/stack vs device compute (scan + block_until_ready)
-            # vs D2H transfer (np.asarray), feeding dispatch_profile()'s
-            # roofline note
+            # per-dispatch timing split: host pad/stack vs compute (scan
+            # call + block_until_ready: H2D, launch and kernel) vs D2H
+            # transfer (np.asarray). Totals of the stamps each dispatch's
+            # record keeps (trace/lifecycle.on_dispatch), which splits
+            # compute further and feeds dispatch_profile()
             "pad_stack_ms_total": 0.0,
             "compute_ms_total": 0.0,
             "transfer_ms_total": 0.0,
             "d2h_bytes_total": 0,
+            # placement steps the evals asked for against the steps the
+            # padded batch ran (b_pad x p_pad): what the buckets pad
+            "steps": 0,
+            "padded_steps": 0,
             # degradations that keep the eval alive but hide a device
             # problem unless counted: a batched dispatch that raised and
             # was retried eval-by-eval on the single scan, and a sibling
@@ -427,6 +439,8 @@ class DeviceBatcher:
         """Submit one encoded eval; blocks until its results are ready.
         Returns (chosen, scores, pulls, skipped, evict) numpy arrays of
         length enc.p (already sliced back from the padded batch).
+        The eval whose stage is open on the calling thread
+        (``lifecycle.current_eval``) is named in its dispatch's record.
 
         ``expected=True`` consumes one prior expect() announcement
         (arrival: the demand token converts into a queued request).
@@ -462,13 +476,14 @@ class DeviceBatcher:
             except queue.Empty:
                 continue
             batch = [first]
+            # what ended the gather, for the dispatch's record
+            closed_by = "full"
             if self.window_s > 0 and self.max_batch > 1:
-                import time
-
-                deadline = time.monotonic() + self.window_s
+                deadline = _phases.now() + self.window_s
                 while len(batch) < self.max_batch:
-                    remaining = deadline - time.monotonic()
+                    remaining = deadline - _phases.now()
                     if remaining <= 0:
+                        closed_by = "window"
                         break
                     # adaptive mode waits only as long as the arrival gap
                     wait = min(remaining, self.idle_s) if self.idle_s else remaining
@@ -484,12 +499,19 @@ class DeviceBatcher:
                     except queue.Empty:
                         if demand > 0 and self._expected_now() > 0:
                             continue  # encodes still en route
-                        break  # stream paused (or window expired)
+                        # stream paused (or window expired)
+                        closed_by = (
+                            "demand_drained" if demand > 0
+                            else "idle_gap" if wait < remaining
+                            else "window"
+                        )
+                        break
             else:
                 while len(batch) < self.max_batch:
                     try:
                         batch.append(self._queue.get_nowait())
                     except queue.Empty:
+                        closed_by = "no_window"
                         break
             with self._lock:
                 self.stats["gathers"] += 1
@@ -502,11 +524,12 @@ class DeviceBatcher:
             for dtype in (np.int32, np.float64, np.float32):
                 group = [r for r in batch if r.enc.dtype == dtype]
                 if group:
-                    self._run_batch_safe(group)
+                    self._run_batch_safe(group, closed_by)
 
-    def _run_batch_safe(self, batch: List[_Request]) -> None:
+    def _run_batch_safe(self, batch: List[_Request],
+                        closed_by: Optional[str] = None) -> None:
         try:
-            self._run_batch(batch)
+            self._run_batch(batch, closed_by)
         except Exception:  # noqa: BLE001 — confine the blast radius
             logger.warning(
                 "batched dispatch failed; retrying %d evals individually",
@@ -625,50 +648,66 @@ class DeviceBatcher:
 
     def dispatch_profile(self) -> Dict[str, object]:
         """Per-dispatch timing split + a roofline note for the batched
-        placement scan: where does a dispatch's wall time go (host
-        pad/stack vs device compute vs D2H transfer), and what D2H
-        bandwidth does the transfer leg sustain? The note names the
-        binding resource so four-rounds-flat throughput plateaus read as
-        "compute-bound at X ms/dispatch" instead of a bare number."""
+        placement scan, from this batcher's dispatch records in
+        trace/lifecycle's ring (the last 4,096): where does a dispatch's
+        wall time go (host pad/stack vs H2D + launch vs kernel wait vs
+        D2H), and what D2H bandwidth does the transfer leg sustain? The
+        note names the binding leg so four-rounds-flat throughput
+        plateaus read as "kernel wait-bound at X ms/dispatch" instead of
+        a bare number."""
         with self._lock:
             s = dict(self.stats)
-        n = s["dispatches"]
+        recs = [r for r in _lifecycle.dispatch_records()
+                if r["batcher"] == self._serial]
+        n = len(recs)
         if n == 0:
-            return {"dispatches": 0, "note": "no dispatches recorded"}
-        pad = s["pad_stack_ms_total"] / n
-        comp = s["compute_ms_total"] / n
-        xfer = s["transfer_ms_total"] / n
-        gbps = 0.0
-        if s["transfer_ms_total"] > 0:
-            gbps = s["d2h_bytes_total"] / (s["transfer_ms_total"] / 1e3) / 1e9
-        legs = {"pad/stack (host)": pad, "compute (device)": comp,
-                "transfer (D2H)": xfer}
+            return {"dispatches": s["dispatches"],
+                    "note": "no dispatches recorded"}
+
+        def avg_ms(a: str, b: str) -> float:
+            return sum(r[b] - r[a] for r in recs) * 1000.0 / n
+
+        pad = avg_ms("t_start", "t_stack")
+        launch = avg_ms("t_stack", "t_called")
+        kernel = avg_ms("t_called", "t_ready")
+        xfer = avg_ms("t_ready", "t_host")
+        d2h_bytes = sum(r["d2h_bytes"] for r in recs)
+        gbps = d2h_bytes / (xfer * n / 1e3) / 1e9 if xfer > 0 else 0.0
+        legs = {"pad/stack (host)": pad, "H2D + launch": launch,
+                "kernel wait (device)": kernel, "transfer (D2H)": xfer}
         bound = max(legs, key=legs.get)
-        total = pad + comp + xfer
+        total = pad + launch + kernel + xfer
+        evals = sum(r["b"] for r in recs)
         note = (
             f"{bound}-bound: {legs[bound]:.2f}ms of {total:.2f}ms per "
-            f"dispatch (pad/stack {pad:.2f}ms, compute {comp:.2f}ms, "
-            f"transfer {xfer:.2f}ms at {gbps:.2f} GB/s D2H, "
-            f"{s['evals'] / n:.1f} evals/dispatch)"
+            f"dispatch (pad/stack {pad:.2f}ms, H2D + launch {launch:.2f}ms, "
+            f"kernel wait {kernel:.2f}ms, transfer {xfer:.2f}ms at "
+            f"{gbps:.2f} GB/s D2H, {evals / n:.1f} evals/dispatch)"
         )
         return {
-            "dispatches": n,
+            "dispatches": s["dispatches"],
             "evals": s["evals"],
+            "recorded": n,
             "pad_stack_ms_avg": round(pad, 3),
-            "compute_ms_avg": round(comp, 3),
+            "h2d_launch_ms_avg": round(launch, 3),
+            "kernel_wait_ms_avg": round(kernel, 3),
+            "compute_ms_avg": round(launch + kernel, 3),
             "transfer_ms_avg": round(xfer, 3),
             "d2h_bytes_total": s["d2h_bytes_total"],
             "d2h_gbps": round(gbps, 3),
+            "useful_steps_pct": round(
+                100.0 * sum(r["steps"] for r in recs)
+                / max(1, sum(r["padded_steps"] for r in recs)), 2),
             "note": note,
         }
 
-    def _run_batch(self, batch: List[_Request]) -> None:
+    def _run_batch(self, batch: List[_Request],
+                   closed_by: Optional[str] = None) -> None:
         import jax
 
-        from ..utils import metrics
-        from ..utils import phases as _phases
-
-        t_start = metrics.now()
+        annotate = jax.profiler.TraceAnnotation
+        wave = _lifecycle.next_wave()
+        t_start = _phases.now()
         encs = [r.enc for r in batch]
         # shared bucketed dims (pow2 to bound recompiles); G always gets a
         # padded slot so padded steps have a pre-failed TG to point at
@@ -714,41 +753,40 @@ class DeviceBatcher:
         )
         dtype = encs[0].dtype  # dispatch loop groups by dtype
 
-        with _phases.track("pad_stack"):
+        # each leg is bracketed once: a TraceAnnotation so the profiler's
+        # trace carries the dispatch above the device line, by wave, and
+        # stamps on phases.now's clock, which become this dispatch's
+        # record and its phases (pad_stack, h2d_launch, kernel_wait, d2h)
+        with annotate("nomad.pad_stack", wave=wave):
             static_b, carry_b, xs_b, b, b_pad = self._pad_and_stack(
                 encs, n_pad, g_pad, s_pad, v_pad, p_pad, dtype, d_pad,
                 k_pad, aff_pad, evd_pad, fac_pad, dpd_pad, dpv_pad, fnd_pad,
                 prec_pad, pregp_pad,
             )
-
-        scan = self._scan_fn()
-        t_stack = metrics.now()
-        metrics.measure_since("nomad.device_batcher.pad_stack", t_start)
-        with _phases.track("device"):
-            # compute vs transfer split: block_until_ready fences the
-            # device work so np.asarray below times ONLY the D2H copy
+            scan = self._scan_fn()
+        t_stack = _phases.now()
+        with annotate("nomad.h2d_launch", wave=wave):
             _carry, (chosen, scores, pulls, skipped, evict) = scan(
                 static_b, carry_b, xs_b)
+        t_called = _phases.now()
+        with annotate("nomad.kernel_wait", wave=wave):
+            # the fence: np.asarray below then times ONLY the D2H copy
             jax.block_until_ready((chosen, scores, pulls, skipped, evict))
-            t_compute = metrics.now()
+        t_ready = _phases.now()
+        with annotate("nomad.d2h", wave=wave):
             chosen = np.asarray(chosen)
             scores = np.asarray(scores)
             pulls = np.asarray(pulls)
             skipped = np.asarray(skipped)
             evict = np.asarray(evict)
-            t_transfer = metrics.now()
-        metrics.measure_since("nomad.device_batcher.dispatch", t_stack)
-        metrics.add_sample(
-            "nomad.device_batcher.compute", (t_compute - t_stack) * 1000.0
-        )
-        metrics.add_sample(
-            "nomad.device_batcher.transfer",
-            (t_transfer - t_compute) * 1000.0,
-        )
+        t_host = _phases.now()
         d2h_bytes = (
             chosen.nbytes + scores.nbytes + pulls.nbytes + skipped.nbytes
             + evict.nbytes
         )
+        steps = sum(e.p for e in encs)
+        padded_steps = b_pad * p_pad
+        t_first_enqueue = min(r.t_enqueue for r in batch)
 
         with self._lock:
             self.stats["dispatches"] += 1
@@ -756,11 +794,12 @@ class DeviceBatcher:
             self.stats["padded_evals"] += b_pad - b
             self.stats["max_batch_seen"] = max(self.stats["max_batch_seen"], b)
             self.stats["pad_stack_ms_total"] += (t_stack - t_start) * 1000.0
-            self.stats["compute_ms_total"] += (t_compute - t_stack) * 1000.0
-            self.stats["transfer_ms_total"] += (t_transfer - t_compute) * 1000.0
+            self.stats["compute_ms_total"] += (t_ready - t_stack) * 1000.0
+            self.stats["transfer_ms_total"] += (t_host - t_ready) * 1000.0
             self.stats["d2h_bytes_total"] += d2h_bytes
+            self.stats["steps"] += steps
+            self.stats["padded_steps"] += padded_steps
             for req in batch:
-                # t_start and t_enqueue share the monotonic clock
                 wait_ms = (t_start - req.t_enqueue) * 1000.0
                 if wait_ms > 0:
                     self.stats["gather_wait_ms_total"] += wait_ms
@@ -775,6 +814,24 @@ class DeviceBatcher:
                 evict[bi, :p],
             )
             req.event.set()
+        t_handed = _phases.now()
+
+        _lifecycle.on_dispatch(
+            wave=wave, source="batcher", batcher=self._serial,
+            eval_ids=[r.eval_id for r in batch if r.eval_id is not None],
+            b=b, b_pad=b_pad, p_pad=p_pad,
+            # the mesh may have widened the node axis past n_pad
+            n_pad=int(static_b[0].shape[1]), steps=steps,
+            padded_steps=padded_steps, closed_by=closed_by,
+            d2h_bytes=d2h_bytes, t_first_enqueue=t_first_enqueue,
+            t_start=t_start, t_stack=t_stack, t_called=t_called,
+            t_ready=t_ready, t_host=t_host, t_handed=t_handed,
+        )
+        _phases.record("gather", t_first_enqueue, t_start)
+        _phases.record("pad_stack", t_start, t_stack)
+        _phases.record("h2d_launch", t_stack, t_called)
+        _phases.record("kernel_wait", t_called, t_ready)
+        _phases.record("d2h", t_ready, t_host)
 
     def _pad_and_stack(self, encs, n_pad, g_pad, s_pad, v_pad, p_pad, dtype,
                        d_pad, k_pad, aff_pad, evd_pad, fac_pad, dpd_pad,

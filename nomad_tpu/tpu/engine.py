@@ -77,6 +77,7 @@ RETRY_DEVICE_FLOOR = 4
 # GIL convoy guard shared with the scheduler's other host phases
 # (utils/hostwork.py): encode/apply are pure-Python, so letting hundreds
 # of worker threads enter them at once only buys context-switch thrash.
+from ..utils import phases as _phases
 from ..utils.hostwork import HOST_WORK_SEM as _HOST_WORK_SEM
 
 
@@ -229,7 +230,19 @@ def _make_step():
     All transformations are exact (integer adds / one-hot sums with a
     single non-zero term), so outputs are bit-identical to the direct
     indexed formulation — fuzz-asserted against the host pipeline in
-    tests/test_tpu_parity.py."""
+    tests/test_tpu_parity.py.
+
+    The stages of a step carry ``jax.named_scope`` names, so a profiler
+    trace groups the scan's operations by what they are for (metadata
+    only: the lowered program is bit for bit the unscoped one):
+    ``evict_prev`` (the previous alloc's eviction), ``row_select`` (the
+    task group's rows), ``feasibility``, ``preemption`` (the greedy sweep
+    and the chosen node's eviction set), ``affinity`` (reschedule penalty,
+    job anti-affinity), ``spread``, ``binpack_score``, ``score_mean``
+    (term presence and the mean), ``select`` (ring-ordered limit and
+    first maximum), ``carry_update`` (the placement's one-hot adds and a
+    failed placement's revert)."""
+    import jax
     import jax.numpy as jnp
     from jax import lax as jlax
 
@@ -282,157 +295,162 @@ def _make_step():
 
         skip_step = jnp.any(sel_g & failed)
 
-        # -- eviction of the previous alloc (one-hot adds) -----------------
-        # shape specialization: an eval with NO destructive updates (the
-        # common case — every fresh placement) encodes evict_res with a
-        # ZERO trailing axis, and the entire eviction/revert machinery
-        # (~15 array passes per step) compiles away.
-        has_evict = evict_res.shape[-1] > 0
-        if has_evict:
-            do_evict = (evict_node >= 0) & (~skip_step)
-            ev_node = jnp.maximum(evict_node, 0)
-            ev_tg = jnp.maximum(evict_tg, 0)
-            oh_ev_node = (iota == ev_node)              # [N]
-            oh_ev_nodef = oh_ev_node.astype(fdt)
-            sel_evg = (iota_g == ev_tg)                 # [G]
+        with jax.named_scope("evict_prev"):
+            # -- eviction of the previous alloc (one-hot adds) -----------------
+            # shape specialization: an eval with NO destructive updates (the
+            # common case — every fresh placement) encodes evict_res with a
+            # ZERO trailing axis, and the entire eviction/revert machinery
+            # (~15 array passes per step) compiles away.
+            has_evict = evict_res.shape[-1] > 0
+            if has_evict:
+                do_evict = (evict_node >= 0) & (~skip_step)
+                ev_node = jnp.maximum(evict_node, 0)
+                ev_tg = jnp.maximum(evict_tg, 0)
+                oh_ev_node = (iota == ev_node)              # [N]
+                oh_ev_nodef = oh_ev_node.astype(fdt)
+                sel_evg = (iota_g == ev_tg)                 # [G]
 
-            def pick_evg(arr, fill=0):
-                shape = (g_count,) + (1,) * (arr.ndim - 1)
-                out = jnp.sum(jnp.where(sel_evg.reshape(shape), arr, fill), axis=0)
-                return out.astype(arr.dtype)
+                def pick_evg(arr, fill=0):
+                    shape = (g_count,) + (1,) * (arr.ndim - 1)
+                    out = jnp.sum(jnp.where(sel_evg.reshape(shape), arr, fill), axis=0)
+                    return out.astype(arr.dtype)
 
-            evict_vec = jnp.where(do_evict, evict_res, 0)  # [D]
-            used = used - oh_ev_nodef[:, None] * evict_vec[None, :]
-            dec_tg = jnp.where(do_evict & (evict_tg >= 0), 1, 0)
-            tg_counts = tg_counts - (sel_evg[:, None] & oh_ev_node[None, :]) * dec_tg
-            job_counts = job_counts - oh_ev_node * jnp.where(do_evict, 1, 0)
-            # The evicted alloc's spread usage clears too (host: propertyset
-            # cleared_values from plan.node_update; floor-at-zero at read).
-            ev_active = pick_evg(spread_active, False)       # [S]
-            ev_dec = jnp.where(do_evict & (evict_tg >= 0) & ev_active, 1, 0).astype(fdt)
-            vids_evg = pick_evg(spread_vids)                 # [S, N]
-            ev_vid = jnp.sum(jnp.where(oh_ev_node[None, :], vids_evg, 0), axis=1)
-            oh_ev_vid = (iota_v[None, :] == ev_vid[:, None]).astype(fdt)  # [S, V]
-            spread_counts = spread_counts - jnp.where(
-                sel_evg[:, None, None], (oh_ev_vid * ev_dec[:, None])[None, :, :], 0
+                evict_vec = jnp.where(do_evict, evict_res, 0)  # [D]
+                used = used - oh_ev_nodef[:, None] * evict_vec[None, :]
+                dec_tg = jnp.where(do_evict & (evict_tg >= 0), 1, 0)
+                tg_counts = tg_counts - (sel_evg[:, None] & oh_ev_node[None, :]) * dec_tg
+                job_counts = job_counts - oh_ev_node * jnp.where(do_evict, 1, 0)
+                # The evicted alloc's spread usage clears too (host: propertyset
+                # cleared_values from plan.node_update; floor-at-zero at read).
+                ev_active = pick_evg(spread_active, False)       # [S]
+                ev_dec = jnp.where(do_evict & (evict_tg >= 0) & ev_active, 1, 0).astype(fdt)
+                vids_evg = pick_evg(spread_vids)                 # [S, N]
+                ev_vid = jnp.sum(jnp.where(oh_ev_node[None, :], vids_evg, 0), axis=1)
+                oh_ev_vid = (iota_v[None, :] == ev_vid[:, None]).astype(fdt)  # [S, V]
+                spread_counts = spread_counts - jnp.where(
+                    sel_evg[:, None, None], (oh_ev_vid * ev_dec[:, None])[None, :, :], 0
+                )
+                # eviction frees capacity -> multiply the node's Q27
+                # exponential by the precomputed per-placement factor
+                if e_base.shape[0]:
+                    from .intscore import E27_BITS, E27_ONE
+
+                    ev_f = jnp.where(do_evict, ev_factor, E27_ONE).astype(i64)  # [2]
+                    eb_ev = (e_base.astype(i64) * ev_f[None, :]) >> E27_BITS
+                    e_base = jnp.where(
+                        oh_ev_node[:, None], eb_ev, e_base.astype(i64)
+                    ).astype(jnp.int32)
+                # (distinct_property + in-eval evictions never encode together
+                # — the host PropertySet cleared-refund quirk can't be
+                # replayed by exact counters; encode gates that combination)
+
+        with jax.named_scope("row_select"):
+            # -- row selects ---------------------------------------------------
+            ask = pick_g(asks)                               # [D]
+            # ONE packed uint8 feature plane carries feasibility and affinity
+            # presence (intscore.pack_feat_planes): one pick_g pass where the
+            # unpacked layout needed two
+            feat_g = pick_g(feat_packed)                     # [N] uint8
+            feas_g = unpack_feat_lane(feat_g, FEAT_FEAS_BIT)
+            tg_counts_g = pick_g(tg_counts)                  # [N]
+            desired_g = pick_g(desired_counts).astype(fdt)
+            dh_job_g = jnp.any(sel_g & dh_job)
+            dh_tg_g = jnp.any(sel_g & dh_tg)
+            # shape specialization (compile-time): a job without affinities
+            # encodes aff_score with a ZERO G axis, so the f64 pick and the
+            # score term vanish from the compiled step entirely (the packed
+            # plane's affinity lane is all-zero and never read)
+            if aff_score.shape[0] == 0:
+                aff = jnp.zeros(n_pad, fdt)
+                aff_p = jnp.zeros(n_pad, bool)
+            else:
+                aff = pick_g(aff_score)
+                aff_p = unpack_feat_lane(feat_g, FEAT_AFF_BIT)
+
+        with jax.named_scope("feasibility"):
+            # -- feasibility ---------------------------------------------------
+            # int mode folds reserved into totals at encode (the scoring
+            # exponentials are precomputed factors, so nothing else needs the
+            # split) and passes a ZERO-height reserved — one [N, D] add less
+            # per step
+            if reserved.shape[0]:
+                util = used + reserved + ask[None, :]  # [N, D]
+            else:
+                util = used + ask[None, :]
+            fits = jnp.all(util <= totals, axis=-1)  # superset + bandwidth check
+
+            # job-level distinct_hosts: any co-located alloc of the job rejects;
+            # tg-level requires both a job and task-group collision
+            dh_mask = jnp.where(
+                dh_job_g,
+                job_counts == 0,
+                jnp.where(dh_tg_g, ~((tg_counts_g > 0) & (job_counts > 0)), True),
             )
-            # eviction frees capacity -> multiply the node's Q27
-            # exponential by the precomputed per-placement factor
-            if e_base.shape[0]:
-                from .intscore import E27_BITS, E27_ONE
 
-                ev_f = jnp.where(do_evict, ev_factor, E27_ONE).astype(i64)  # [2]
-                eb_ev = (e_base.astype(i64) * ev_f[None, :]) >> E27_BITS
-                e_base = jnp.where(
-                    oh_ev_node[:, None], eb_ev, e_base.astype(i64)
-                ).astype(jnp.int32)
-            # (distinct_property + in-eval evictions never encode together
-            # — the host PropertySet cleared-refund quirk can't be
-            # replayed by exact counters; encode gates that combination)
+        with jax.named_scope("preemption"):
+            # -- device-side preemption (tpu/preempt.py) -----------------------
+            # shape specialization: non-preempting evals encode the candidate
+            # axis C as ZERO width and the whole greedy sweep compiles away.
+            # When present, a node whose capacity check fails may be rescued
+            # by an eviction set of lower-priority allocs (the reference's
+            # PreemptForTaskGroup): cap_ok = fits | pre_met. Preemption never
+            # rescues class/constraint/distinct-hosts infeasibility — those
+            # masks still AND in below, matching the host stack ordering.
+            has_pre = pre_res.shape[1] > 0
+            if has_pre:
+                from .preempt import CQ_BITS, PENALTY_UNIT, greedy_select_jnp
 
-        # -- row selects ---------------------------------------------------
-        ask = pick_g(asks)                               # [D]
-        # ONE packed uint8 feature plane carries feasibility and affinity
-        # presence (intscore.pack_feat_planes): one pick_g pass where the
-        # unpacked layout needed two
-        feat_g = pick_g(feat_packed)                     # [N] uint8
-        feas_g = unpack_feat_lane(feat_g, FEAT_FEAS_BIT)
-        tg_counts_g = pick_g(tg_counts)                  # [N]
-        desired_g = pick_g(desired_counts).astype(fdt)
-        dh_job_g = jnp.any(sel_g & dh_job)
-        dh_tg_g = jnp.any(sel_g & dh_tg)
-        # shape specialization (compile-time): a job without affinities
-        # encodes aff_score with a ZERO G axis, so the f64 pick and the
-        # score term vanish from the compiled step entirely (the packed
-        # plane's affinity lane is all-zero and never read)
-        if aff_score.shape[0] == 0:
-            aff = jnp.zeros(n_pad, fdt)
-            aff_p = jnp.zeros(n_pad, bool)
-        else:
-            aff = pick_g(aff_score)
-            aff_p = unpack_feat_lane(feat_g, FEAT_AFF_BIT)
+                gp_w = pre_counts.shape[0]
+                iota_gp = jnp.arange(gp_w, dtype=jnp.int32)
+                # num preemptions already planned for each candidate's
+                # (job, ns, tg) group — the reference's maxParallel penalty
+                oh_gid = pre_gid[:, :, None] == iota_gp[None, None, :]
+                num_pre = jnp.sum(
+                    jnp.where(oh_gid, pre_counts[None, None, :], 0), axis=-1
+                ).astype(jnp.int32)                                    # [N, C]
+                pen = jnp.where(
+                    (pre_mp > 0) & (num_pre >= pre_mp),
+                    (((num_pre + 1) - pre_mp).astype(i64) * PENALTY_UNIT)
+                    << CQ_BITS,
+                    i64(0),
+                )
+                ask3 = ask[:3].astype(i64)                             # cpu/mem/disk
+                pre_res3 = pre_res[:, :, :3].astype(i64)
+                sel_ord, pre_met = greedy_select_jnp(
+                    ask3, pre_res3, pre_prio, pen,
+                    pre_alive & pre_elig, pre_remaining,
+                )
+                cap_ok = fits | pre_met
+            else:
+                cap_ok = fits
 
-        # -- feasibility ---------------------------------------------------
-        # int mode folds reserved into totals at encode (the scoring
-        # exponentials are precomputed factors, so nothing else needs the
-        # split) and passes a ZERO-height reserved — one [N, D] add less
-        # per step
-        if reserved.shape[0]:
-            util = used + reserved + ask[None, :]  # [N, D]
-        else:
-            util = used + ask[None, :]
-        fits = jnp.all(util <= totals, axis=-1)  # superset + bandwidth check
+        with jax.named_scope("feasibility"):
+            feasible = feas_g & cap_ok & dh_mask  # [N]
+            # system-scheduler mode: the candidate node is FIXED per placement
+            # (one alloc per eligible node, system_sched.go:268-286); a
+            # zero-width axis (generic evals) compiles the restriction away
+            if forced_node.shape[-1]:
+                fnode = forced_node[0]
+                feasible = feasible & ((fnode < 0) | (iota == fnode))
 
-        # job-level distinct_hosts: any co-located alloc of the job rejects;
-        # tg-level requires both a job and task-group collision
-        dh_mask = jnp.where(
-            dh_job_g,
-            job_counts == 0,
-            jnp.where(dh_tg_g, ~((tg_counts_g > 0) & (job_counts > 0)), True),
-        )
-
-        # -- device-side preemption (tpu/preempt.py) -----------------------
-        # shape specialization: non-preempting evals encode the candidate
-        # axis C as ZERO width and the whole greedy sweep compiles away.
-        # When present, a node whose capacity check fails may be rescued
-        # by an eviction set of lower-priority allocs (the reference's
-        # PreemptForTaskGroup): cap_ok = fits | pre_met. Preemption never
-        # rescues class/constraint/distinct-hosts infeasibility — those
-        # masks still AND in below, matching the host stack ordering.
-        has_pre = pre_res.shape[1] > 0
-        if has_pre:
-            from .preempt import CQ_BITS, PENALTY_UNIT, greedy_select_jnp
-
-            gp_w = pre_counts.shape[0]
-            iota_gp = jnp.arange(gp_w, dtype=jnp.int32)
-            # num preemptions already planned for each candidate's
-            # (job, ns, tg) group — the reference's maxParallel penalty
-            oh_gid = pre_gid[:, :, None] == iota_gp[None, None, :]
-            num_pre = jnp.sum(
-                jnp.where(oh_gid, pre_counts[None, None, :], 0), axis=-1
-            ).astype(jnp.int32)                                    # [N, C]
-            pen = jnp.where(
-                (pre_mp > 0) & (num_pre >= pre_mp),
-                (((num_pre + 1) - pre_mp).astype(i64) * PENALTY_UNIT)
-                << CQ_BITS,
-                i64(0),
-            )
-            ask3 = ask[:3].astype(i64)                             # cpu/mem/disk
-            pre_res3 = pre_res[:, :, :3].astype(i64)
-            sel_ord, pre_met = greedy_select_jnp(
-                ask3, pre_res3, pre_prio, pen,
-                pre_alive & pre_elig, pre_remaining,
-            )
-            cap_ok = fits | pre_met
-        else:
-            cap_ok = fits
-
-        feasible = feas_g & cap_ok & dh_mask  # [N]
-        # system-scheduler mode: the candidate node is FIXED per placement
-        # (one alloc per eligible node, system_sched.go:268-286); a
-        # zero-width axis (generic evals) compiles the restriction away
-        if forced_node.shape[-1]:
-            fnode = forced_node[0]
-            feasible = feasible & ((fnode < 0) | (iota == fnode))
-
-        # distinct_property (feasible.go:353): per-constraint value-count
-        # carry, same mechanism as spread counts but FILTERING — a node is
-        # infeasible when its value's count reached the allowed limit or
-        # the property is missing. D == 0 compiles all of this away.
-        if dp_vids.shape[0]:
-            v2 = dp_counts.shape[-1]
-            iota_v2 = jnp.arange(v2, dtype=jnp.int32)
-            oh_dpv = dp_vids[:, None, :] == iota_v2[None, :, None]  # [D, V2, N]
-            dp_cnts = jnp.maximum(dp_counts, 0)  # cleared-value floor
-            dp_cnt_n = jnp.sum(
-                jnp.where(oh_dpv, dp_cnts[:, :, None], 0), axis=1
-            )  # [D, N]
-            dp_applies_g = pick_g(dp_applies, False)  # [D]
-            dp_missing = dp_vids == (v2 - 1)
-            dp_ok = (~dp_applies_g[:, None]) | (
-                (~dp_missing) & (dp_cnt_n < dp_limit[:, None])
-            )
-            feasible = feasible & jnp.all(dp_ok, axis=0)
+            # distinct_property (feasible.go:353): per-constraint value-count
+            # carry, same mechanism as spread counts but FILTERING — a node is
+            # infeasible when its value's count reached the allowed limit or
+            # the property is missing. D == 0 compiles all of this away.
+            if dp_vids.shape[0]:
+                v2 = dp_counts.shape[-1]
+                iota_v2 = jnp.arange(v2, dtype=jnp.int32)
+                oh_dpv = dp_vids[:, None, :] == iota_v2[None, :, None]  # [D, V2, N]
+                dp_cnts = jnp.maximum(dp_counts, 0)  # cleared-value floor
+                dp_cnt_n = jnp.sum(
+                    jnp.where(oh_dpv, dp_cnts[:, :, None], 0), axis=1
+                )  # [D, N]
+                dp_applies_g = pick_g(dp_applies, False)  # [D]
+                dp_missing = dp_vids == (v2 - 1)
+                dp_ok = (~dp_applies_g[:, None]) | (
+                    (~dp_missing) & (dp_cnt_n < dp_limit[:, None])
+                )
+                feasible = feasible & jnp.all(dp_ok, axis=0)
 
         # -- score terms ---------------------------------------------------
         # Two compile-time modes sharing one structure:
@@ -441,31 +459,33 @@ def _make_step():
         #        exponentials, score60 selection. Bit-identical on every
         #        backend, so plan parity holds ON the real TPU.
         #   float (throughput): f32 arithmetic, non-parity.
-        # same specialization: no reschedule history -> penalty_idx has a
-        # zero K axis and the [N, K] compare disappears
-        if penalty_idx.shape[-1] == 0:
-            pmask = jnp.zeros(n_pad, bool)
-        else:
-            pmask = jnp.any(iota[:, None] == penalty_idx[None, :], axis=-1)
+        with jax.named_scope("affinity"):
+            # same specialization: no reschedule history -> penalty_idx has a
+            # zero K axis and the [N, K] compare disappears
+            if penalty_idx.shape[-1] == 0:
+                pmask = jnp.zeros(n_pad, bool)
+            else:
+                pmask = jnp.any(iota[:, None] == penalty_idx[None, :], axis=-1)
 
-        anti_present = tg_counts_g > 0
+            anti_present = tg_counts_g > 0
 
-        # spread row selects (shared) — value-id lookups as one-hot sums
-        vids = pick_g(spread_vids)                       # [S, N]
-        # floor-at-zero matches the host's cleared-value clamping
-        s_counts = jnp.maximum(pick_g(spread_counts), 0)    # [S, V]
-        s_entry = pick_g(spread_entry, False)            # [S, V]
-        desired_sv = pick_g(spread_desired)              # [S, V]
-        weights_s = pick_g(spread_weights)
-        has_targets_s = pick_g(spread_has_targets, False)
-        active_s = pick_g(spread_active, False)
+        with jax.named_scope("spread"):
+            # spread row selects (shared) — value-id lookups as one-hot sums
+            vids = pick_g(spread_vids)                       # [S, N]
+            # floor-at-zero matches the host's cleared-value clamping
+            s_counts = jnp.maximum(pick_g(spread_counts), 0)    # [S, V]
+            s_entry = pick_g(spread_entry, False)            # [S, V]
+            desired_sv = pick_g(spread_desired)              # [S, V]
+            weights_s = pick_g(spread_weights)
+            has_targets_s = pick_g(spread_has_targets, False)
+            active_s = pick_g(spread_active, False)
 
-        invalid_bucket = v_plus - 1
-        oh_vids = vids[:, None, :] == iota_v[None, :, None]  # [S, V, N]
-        current = jnp.sum(jnp.where(oh_vids, s_counts[:, :, None], 0), axis=1)
-        d = jnp.sum(jnp.where(oh_vids, desired_sv[:, :, None], 0), axis=1)
-        missing = vids == invalid_bucket
-        has_entries = jnp.any(s_entry[:, :invalid_bucket], axis=-1)  # [S]
+            invalid_bucket = v_plus - 1
+            oh_vids = vids[:, None, :] == iota_v[None, :, None]  # [S, V, N]
+            current = jnp.sum(jnp.where(oh_vids, s_counts[:, :, None], 0), axis=1)
+            d = jnp.sum(jnp.where(oh_vids, desired_sv[:, :, None], 0), axis=1)
+            missing = vids == invalid_bucket
+            has_entries = jnp.any(s_entry[:, :invalid_bucket], axis=-1)  # [S]
 
         if int_mode:
             from .intscore import (
@@ -477,383 +497,395 @@ def _make_step():
                 TERM_ONE,
             )
 
-            # selection-time exponentials: e_base (running product in the
-            # carry) times the static per-TG ask factor — 10**(free - ask/cap)
-            ea = pick_g(e_ask)                                 # [N, 2] int32
-            e_sel = (e_base.astype(i64) * ea.astype(i64)) >> E27_BITS
-            e_sel_i32 = e_sel.astype(jnp.int32)                # placement update
-            fit = i64(20 * E27_ONE) - e_sel[:, 0] - e_sel[:, 1]
-            fit = jnp.clip(fit, 0, 18 * E27_ONE)
-            # Q30 = fit * 2**30 / (18 * 2**27) = (fit*4)//9 (const divisor)
-            binpack = (fit * 4) // 9
+            with jax.named_scope("binpack_score"):
+                # selection-time exponentials: e_base (running product in the
+                # carry) times the static per-TG ask factor — 10**(free - ask/cap)
+                ea = pick_g(e_ask)                                 # [N, 2] int32
+                e_sel = (e_base.astype(i64) * ea.astype(i64)) >> E27_BITS
+                e_sel_i32 = e_sel.astype(jnp.int32)                # placement update
+                fit = i64(20 * E27_ONE) - e_sel[:, 0] - e_sel[:, 1]
+                fit = jnp.clip(fit, 0, 18 * E27_ONE)
+                # Q30 = fit * 2**30 / (18 * 2**27) = (fit*4)//9 (const divisor)
+                binpack = (fit * 4) // 9
 
-            rsh = RECIP_BITS - TERM_BITS
-            # -(c+1)/desired via the Q45 reciprocal of the (small, per-step
-            # scalar) desired count — error < 4 Q30-ulp
-            q_d = jnp.floor_divide(
-                i64(1 << RECIP_BITS), jnp.maximum(desired_g.astype(i64), 1)
-            )
-            anti = jnp.where(
-                anti_present,
-                -(((tg_counts_g.astype(i64) + 1) * q_d) >> rsh),
-                0,
-            )
-            resched = jnp.where(pmask, i64(-TERM_ONE), i64(0))
+            with jax.named_scope("affinity"):
+                rsh = RECIP_BITS - TERM_BITS
+                # -(c+1)/desired via the Q45 reciprocal of the (small, per-step
+                # scalar) desired count — error < 4 Q30-ulp
+                q_d = jnp.floor_divide(
+                    i64(1 << RECIP_BITS), jnp.maximum(desired_g.astype(i64), 1)
+                )
+                anti = jnp.where(
+                    anti_present,
+                    -(((tg_counts_g.astype(i64) + 1) * q_d) >> rsh),
+                    0,
+                )
+                resched = jnp.where(pmask, i64(-TERM_ONE), i64(0))
 
-            d64 = d.astype(i64)
-            u64 = current.astype(i64) + 1
-            w64 = weights_s.astype(i64)[:, None]
-            sw64 = jnp.maximum(sum_sw_p.astype(i64), 1)
-            # targeted boost: ((d - u)/d)*(w/sum_w) as ONE fused Q30
-            # rational, floor-rounded (d in hundredths: d = pct*count)
-            t_num = (d64 - 100 * u64) * w64 * TERM_ONE
-            t_den = jnp.maximum(d64, 1) * sw64
-            targeted_raw = jnp.where(
-                d64 > 0,
-                jnp.floor_divide(t_num, t_den),
-                jnp.where(d64 == 0, i64(-BIG_FP), i64(-TERM_ONE)),
-            )
+            with jax.named_scope("spread"):
+                d64 = d.astype(i64)
+                u64 = current.astype(i64) + 1
+                w64 = weights_s.astype(i64)[:, None]
+                sw64 = jnp.maximum(sum_sw_p.astype(i64), 1)
+                # targeted boost: ((d - u)/d)*(w/sum_w) as ONE fused Q30
+                # rational, floor-rounded (d in hundredths: d = pct*count)
+                t_num = (d64 - 100 * u64) * w64 * TERM_ONE
+                t_den = jnp.maximum(d64, 1) * sw64
+                targeted_raw = jnp.where(
+                    d64 > 0,
+                    jnp.floor_divide(t_num, t_den),
+                    jnp.where(d64 == 0, i64(-BIG_FP), i64(-TERM_ONE)),
+                )
 
-            # even-spread boost (same branch structure as the host);
-            # divisions by min_c (a count) via its Q45 reciprocal — [S]-
-            # shaped, so the division is off the hot [N] axis
-            LARGE = i64(1) << 40
-            sc64 = s_counts.astype(i64)[:, :invalid_bucket]
-            se = s_entry[:, :invalid_bucket]
-            min_c = jnp.where(
-                has_entries, jnp.min(jnp.where(se, sc64, LARGE), axis=-1), 0
-            )  # [S]
-            max_c = jnp.where(
-                has_entries, jnp.max(jnp.where(se, sc64, -LARGE), axis=-1), 0
-            )
-            r_min = jnp.floor_divide(
-                i64(1 << RECIP_BITS), jnp.maximum(min_c, 1)
-            )  # [S]
-            min_cn = min_c[:, None]
-            cur64 = current.astype(i64)
-            delta_boost = jnp.where(
-                min_cn == 0,
-                i64(-TERM_ONE),
-                ((min_cn - cur64) * r_min[:, None]) >> rsh,
-            )
-            even = jnp.where(
-                cur64 != min_cn,
-                delta_boost,
-                jnp.where(
-                    min_cn == max_c[:, None],
+                # even-spread boost (same branch structure as the host);
+                # divisions by min_c (a count) via its Q45 reciprocal — [S]-
+                # shaped, so the division is off the hot [N] axis
+                LARGE = i64(1) << 40
+                sc64 = s_counts.astype(i64)[:, :invalid_bucket]
+                se = s_entry[:, :invalid_bucket]
+                min_c = jnp.where(
+                    has_entries, jnp.min(jnp.where(se, sc64, LARGE), axis=-1), 0
+                )  # [S]
+                max_c = jnp.where(
+                    has_entries, jnp.max(jnp.where(se, sc64, -LARGE), axis=-1), 0
+                )
+                r_min = jnp.floor_divide(
+                    i64(1 << RECIP_BITS), jnp.maximum(min_c, 1)
+                )  # [S]
+                min_cn = min_c[:, None]
+                cur64 = current.astype(i64)
+                delta_boost = jnp.where(
+                    min_cn == 0,
                     i64(-TERM_ONE),
-                    jnp.where(
-                        min_cn == 0,
-                        i64(TERM_ONE),
-                        ((max_c[:, None] - min_cn) * r_min[:, None]) >> rsh,
-                    ),
-                ),
-            )
-            even = jnp.where(has_entries[:, None], even, 0)
-
-            per_spread = jnp.where(has_targets_s[:, None], targeted_raw, even)
-            per_spread = jnp.where(missing, i64(-TERM_ONE), per_spread)
-            per_spread = jnp.where(active_s[:, None], per_spread, 0)
-            spread_total = jnp.sum(per_spread, axis=0)  # [N] int64
-            spread_p = spread_total != 0
-
-            # term-presence bits packed into ONE uint8 plane: num_terms is
-            # 1 + popcount instead of four astype(int32) planes and adds —
-            # the whole (presence -> factor -> final) chain is a single
-            # fused elementwise expression over [N]
-            presence = pack_presence_lanes(anti_present, pmask, aff_p, spread_p)
-            num_terms = 1 + jlax.population_count(presence).astype(jnp.int32)
-            # mean of terms via EXACT scale-by-60 (all of 1..5 divide 60)
-            factor = jnp.floor_divide(60, num_terms).astype(i64)
-            final = (
-                binpack + anti + resched
-                + jnp.where(aff_p, aff.astype(i64), 0) + spread_total
-            ) * factor
-            neg_inf = jnp.iinfo(jnp.int64).min // 4
-            score_zero = i64(0)
-        else:
-            node_cpu = totals[:, DIM_CPU] - reserved[:, DIM_CPU]
-            node_mem = totals[:, DIM_MEM] - reserved[:, DIM_MEM]
-            free_cpu = 1.0 - util[:, DIM_CPU] / jnp.maximum(node_cpu, 1e-9)
-            free_mem = 1.0 - util[:, DIM_MEM] / jnp.maximum(node_mem, 1e-9)
-            fitness = 20.0 - (jnp.power(10.0, free_cpu) + jnp.power(10.0, free_mem))
-            binpack = jnp.clip(fitness, 0.0, 18.0) / 18.0
-
-            collisions = tg_counts_g.astype(fdt)
-            anti = jnp.where(anti_present, -(collisions + 1.0) / desired_g.astype(fdt), 0.0)
-            resched = jnp.where(pmask, -1.0, 0.0)
-
-            big = jnp.finfo(fdt).max / 16.0
-            used_count = current.astype(fdt) + 1.0           # [S, N]
-            df = d.astype(fdt)
-            # divisor: the host SpreadIterator's weight sum accumulates
-            # across visited task groups -> passed per placement (sum_sw_p)
-            weight_frac = weights_s[:, None] / jnp.maximum(sum_sw_p, 1e-9)
-            # Go float semantics: d == 0 -> -Inf boost (clamped large neg)
-            targeted_raw = jnp.where(
-                df > 0.0,
-                (df - used_count) / jnp.where(df > 0.0, df, 1.0) * weight_frac,
-                jnp.where(df == 0.0, -big, -1.0),  # d<0: no target -> -1
-            )
-
-            # even-spread boost
-            scf = s_counts.astype(fdt)[:, :invalid_bucket]
-            entry_counts = jnp.where(s_entry[:, :invalid_bucket], scf, jnp.inf)
-            min_c = jnp.where(has_entries, jnp.min(entry_counts, axis=-1), 0.0)  # [S]
-            max_counts = jnp.where(s_entry[:, :invalid_bucket], scf, -jnp.inf)
-            max_c = jnp.where(has_entries, jnp.max(max_counts, axis=-1), 0.0)
-            currentf = current.astype(fdt)
-            delta_boost = jnp.where(
-                min_c[:, None] == 0.0, -1.0,
-                (min_c[:, None] - currentf) / jnp.maximum(min_c[:, None], 1e-9)
-            )
-            even = jnp.where(
-                currentf != min_c[:, None],
-                delta_boost,
-                jnp.where(
-                    min_c[:, None] == max_c[:, None],
-                    -1.0,
-                    jnp.where(
-                        min_c[:, None] == 0.0,
-                        1.0,
-                        (max_c[:, None] - min_c[:, None]) / jnp.maximum(min_c[:, None], 1e-9),
-                    ),
-                ),
-            )
-            even = jnp.where(has_entries[:, None], even, 0.0)
-
-            per_spread = jnp.where(has_targets_s[:, None], targeted_raw, even)
-            per_spread = jnp.where(missing, -1.0, per_spread)
-            per_spread = jnp.where(active_s[:, None], per_spread, 0.0)
-            spread_total = jnp.sum(per_spread, axis=0)  # [N]
-            spread_p = spread_total != 0.0
-
-            # same popcount fusion as int mode (small counts are exact in
-            # any float dtype, so the quotient is bit-identical to the
-            # astype-chain form)
-            presence = pack_presence_lanes(anti_present, pmask, aff_p, spread_p)
-            num_terms = (1 + jlax.population_count(presence)).astype(fdt)
-            final = (binpack + anti + resched + jnp.where(aff_p, aff, 0.0) + spread_total) / num_terms
-            neg_inf = -jnp.inf
-            score_zero = jnp.asarray(0.0, fdt)
-
-        # -- ring-ordered limit + max-score selection (no permutation) -----
-        # Ring prefix sums at natural index i: with S = natural inclusive
-        # cumsum, T = total, o = offset, the ring-order cumsum is
-        # S(i) - S(o-1) for i >= o and S(i) + (T - S(o-1)) for i < o —
-        # elementwise, so the LimitIterator emulation needs no gathers.
-        #
-        # ONE packed int32 ring cumsum carries everything: the low-score
-        # and feasible count planes ride 16-bit lanes of one int32 plane
-        # (intscore.pack_count_lanes). Lane exactness: both totals are
-        # bounded by n_pad < 2**15, so the low lane never carries into the
-        # high lane, and every SELECTED ring branch is lane-wise
-        # non-negative (i >= o selects S(i) - S(o-1) with [0..o-1] a
-        # subset of [0..i]; i < o selects S(i) + the suffix sum — both
-        # >= 0 per lane), so no borrow crosses lanes either. The skip
-        # prefix is then min(low_cum, MAX_SKIP) (skipped = the first
-        # MAX_SKIP low entries in ring order) and the source prefix is
-        # feas_cum - skip_cum. (int64 field-packing would lift the 2**15
-        # bound, but int64 prefix sums are pathologically slow on this
-        # backend — int32 lanes are free.)
-        valid = iota < n_real
-        nr = jnp.maximum(n_real, 1)
-
-        feas_v = feasible & valid
-        # threshold 0 is exact in both modes (int: score60 <= 0 iff the
-        # rational score <= 0; float: the host's 0.0 skip threshold)
-        low = feas_v & (final <= 0)
-
-        def ring_cumsum(a_int):
-            s_nat = jnp.cumsum(a_int)
-            total = s_nat[-1]
-            before = jnp.sum(jnp.where(iota < offset, a_int, 0),
-                             dtype=jnp.int32)
-            ring = jnp.where(
-                iota >= offset, s_nat - before, s_nat + (total - before)
-            )
-            return ring, total
-
-        if n_pad < PACK_COUNT_MAX:
-            packed_cum, packed_total = ring_cumsum(pack_count_lanes(low, feas_v))
-            low_cum = unpack_count_lo(packed_cum)
-            feas_cum = unpack_count_hi(packed_cum)
-            low_total = unpack_count_lo(packed_total)
-            feas_total = unpack_count_hi(packed_total)
-        else:
-            # lanes would overflow on a >32K-node pad: two plain cumsums
-            low_cum, low_total = ring_cumsum(low.astype(jnp.int32))
-            feas_cum, feas_total = ring_cumsum(feas_v.astype(jnp.int32))
-
-        skipped = low & (low_cum <= MAX_SKIP)
-        skip_cum = jnp.minimum(low_cum, MAX_SKIP)
-        ret = feas_v & ~skipped
-        ret_i = ret.astype(jnp.int32)
-        ret_cum = feas_cum - skip_cum
-        ret_excl = ret_cum - ret_i
-
-        limit = limit_p
-        pulled = valid & (ret_excl < limit)
-        src_cand = ret & pulled
-        ret_total = feas_total - jnp.minimum(low_total, MAX_SKIP)
-        backlog_n = jnp.maximum(limit - ret_total, 0)
-        skip_i = skipped.astype(jnp.int32)
-        skip_excl = skip_cum - skip_i
-        backlog_cand = skipped & (skip_excl < backlog_n)
-        cand = src_cand | backlog_cand
-
-        # ranks are unique across candidates (source ranks < ret_total <=
-        # backlog ranks), so (max score, min rank) names one node exactly
-        rank = jnp.where(src_cand, ret_excl, ret_total + skip_excl)
-
-        cand_scores = jnp.where(cand, final, neg_inf)
-        best_score = jnp.max(cand_scores)
-        winners = cand & (cand_scores == best_score)
-        winner_rank = jnp.where(winners, rank, jnp.int32(2**31 - 1))
-        best_rank = jnp.min(winner_rank)
-        any_cand = jnp.any(cand)
-        chosen = jnp.where(
-            any_cand & (~skip_step),
-            jnp.argmax(winners & (rank == best_rank)).astype(jnp.int32),
-            -1,
-        )
-
-        pulls = jnp.where(skip_step, 0, jnp.sum(pulled.astype(jnp.int32))).astype(jnp.int32)
-        offset = jnp.where(skip_step, offset, (offset + pulls) % nr).astype(jnp.int32)
-
-        # -- apply placement / revert eviction (one-hot adds) --------------
-        success = chosen >= 0
-        ch = jnp.maximum(chosen, 0)
-        oh_ch = (iota == ch)
-        oh_chf = oh_ch.astype(fdt)
-        add_vec = jnp.where(success, ask, 0)
-        used = used + oh_chf[:, None] * add_vec[None, :]
-        inc_i = jnp.where(success, 1, 0)
-        tg_counts = tg_counts + (sel_g[:, None] & oh_ch[None, :]) * inc_i
-        job_counts = job_counts + oh_ch * inc_i
-
-        ch_vid = jnp.sum(jnp.where(oh_ch[None, :], vids, 0), axis=1)  # [S]
-        oh_ch_vid = (iota_v[None, :] == ch_vid[:, None])              # [S, V]
-        inc = jnp.where(success & active_s, 1, 0).astype(fdt)
-        spread_counts = spread_counts + jnp.where(
-            sel_g[:, None, None], (oh_ch_vid.astype(fdt) * inc[:, None])[None, :, :], 0
-        )
-        entry_set = sel_g[:, None, None] & (oh_ch_vid & (inc > 0)[:, None])[None, :, :]
-        spread_entry = spread_entry | entry_set
-
-        # placement commits the chosen node's new exponential — EXACTLY the
-        # already-computed selection value (running-product spec)
-        if e_base.shape[0]:
-            e_base = jnp.where((oh_ch & success)[:, None], e_sel_i32, e_base)
-        if dp_vids.shape[0]:
-            ch_vid_dp = jnp.sum(jnp.where(oh_ch[None, :], dp_vids, 0), axis=1)  # [D]
-            inc_dp = dp_applies_g & success
-            dp_counts = dp_counts + (
-                (iota_v2[None, :] == ch_vid_dp[:, None]) & inc_dp[:, None]
-            ).astype(jnp.int32)
-
-        # -- commit the eviction set on the chosen node --------------------
-        # Host ordering: preemption fires only when the node did NOT fit
-        # outright. The greedy set is filtered by the reference's second
-        # pass (distance vs the FRESH ask, descending) on the chosen
-        # node's extracted [C] row — off the hot [N] axis.
-        if has_pre:
-            c_w = pre_res.shape[1]
-            from .preempt import second_pass_jnp
-
-            fits_ch = jnp.any(oh_ch & fits)
-            use_pre = success & (~fits_ch) & (~skip_step)
-
-            def row_c(arr):
-                # arr[ch] without gather: one-hot sum over N (exactly one
-                # non-zero term, so negative fills survive intact)
-                shape = (n_pad,) + (1,) * (arr.ndim - 1)
-                out = jnp.sum(jnp.where(oh_ch.reshape(shape), arr, 0), axis=0)
-                return out.astype(arr.dtype)
-
-            sel_ord_ch = row_c(sel_ord)                        # [C]
-            res3_ch = row_c(pre_res3)                          # [C, 3] i64
-            rem_ch = row_c(pre_remaining)                      # [3] i64
-            keep, p_rank = second_pass_jnp(ask3, res3_ch, sel_ord_ch, rem_ch)
-            keep = keep & use_pre                              # [C]
-
-            # freed capacity credits `used` (the alloc itself stays
-            # overcommitted for SCORING, matching the host's allocs_fit
-            # used — the credit lands after the score terms above)
-            res4_ch = row_c(pre_res)                           # [C, 4] i32
-            freed4 = jnp.sum(
-                jnp.where(keep[:, None], res4_ch.astype(fdt), 0), axis=0,
-                dtype=fdt,
-            )                                                  # [4]
-            d_dims = totals.shape[1]
-            if d_dims > 4:
-                # batch padding may widen D past the gate's 4 dims; the
-                # extra (device) dims free nothing
-                freed_vec = jnp.concatenate(
-                    [freed4, jnp.zeros(d_dims - 4, freed4.dtype)]
+                    ((min_cn - cur64) * r_min[:, None]) >> rsh,
                 )
+                even = jnp.where(
+                    cur64 != min_cn,
+                    delta_boost,
+                    jnp.where(
+                        min_cn == max_c[:, None],
+                        i64(-TERM_ONE),
+                        jnp.where(
+                            min_cn == 0,
+                            i64(TERM_ONE),
+                            ((max_c[:, None] - min_cn) * r_min[:, None]) >> rsh,
+                        ),
+                    ),
+                )
+                even = jnp.where(has_entries[:, None], even, 0)
+
+                per_spread = jnp.where(has_targets_s[:, None], targeted_raw, even)
+                per_spread = jnp.where(missing, i64(-TERM_ONE), per_spread)
+                per_spread = jnp.where(active_s[:, None], per_spread, 0)
+                spread_total = jnp.sum(per_spread, axis=0)  # [N] int64
+                spread_p = spread_total != 0
+
+            with jax.named_scope("score_mean"):
+                # term-presence bits packed into ONE uint8 plane: num_terms is
+                # 1 + popcount instead of four astype(int32) planes and adds —
+                # the whole (presence -> factor -> final) chain is a single
+                # fused elementwise expression over [N]
+                presence = pack_presence_lanes(anti_present, pmask, aff_p, spread_p)
+                num_terms = 1 + jlax.population_count(presence).astype(jnp.int32)
+                # mean of terms via EXACT scale-by-60 (all of 1..5 divide 60)
+                factor = jnp.floor_divide(60, num_terms).astype(i64)
+                final = (
+                    binpack + anti + resched
+                    + jnp.where(aff_p, aff.astype(i64), 0) + spread_total
+                ) * factor
+                neg_inf = jnp.iinfo(jnp.int64).min // 4
+                score_zero = i64(0)
+        else:
+            with jax.named_scope("binpack_score"):
+                node_cpu = totals[:, DIM_CPU] - reserved[:, DIM_CPU]
+                node_mem = totals[:, DIM_MEM] - reserved[:, DIM_MEM]
+                free_cpu = 1.0 - util[:, DIM_CPU] / jnp.maximum(node_cpu, 1e-9)
+                free_mem = 1.0 - util[:, DIM_MEM] / jnp.maximum(node_mem, 1e-9)
+                fitness = 20.0 - (jnp.power(10.0, free_cpu) + jnp.power(10.0, free_mem))
+                binpack = jnp.clip(fitness, 0.0, 18.0) / 18.0
+
+            with jax.named_scope("affinity"):
+                collisions = tg_counts_g.astype(fdt)
+                anti = jnp.where(anti_present, -(collisions + 1.0) / desired_g.astype(fdt), 0.0)
+                resched = jnp.where(pmask, -1.0, 0.0)
+
+            with jax.named_scope("spread"):
+                big = jnp.finfo(fdt).max / 16.0
+                used_count = current.astype(fdt) + 1.0           # [S, N]
+                df = d.astype(fdt)
+                # divisor: the host SpreadIterator's weight sum accumulates
+                # across visited task groups -> passed per placement (sum_sw_p)
+                weight_frac = weights_s[:, None] / jnp.maximum(sum_sw_p, 1e-9)
+                # Go float semantics: d == 0 -> -Inf boost (clamped large neg)
+                targeted_raw = jnp.where(
+                    df > 0.0,
+                    (df - used_count) / jnp.where(df > 0.0, df, 1.0) * weight_frac,
+                    jnp.where(df == 0.0, -big, -1.0),  # d<0: no target -> -1
+                )
+
+                # even-spread boost
+                scf = s_counts.astype(fdt)[:, :invalid_bucket]
+                entry_counts = jnp.where(s_entry[:, :invalid_bucket], scf, jnp.inf)
+                min_c = jnp.where(has_entries, jnp.min(entry_counts, axis=-1), 0.0)  # [S]
+                max_counts = jnp.where(s_entry[:, :invalid_bucket], scf, -jnp.inf)
+                max_c = jnp.where(has_entries, jnp.max(max_counts, axis=-1), 0.0)
+                currentf = current.astype(fdt)
+                delta_boost = jnp.where(
+                    min_c[:, None] == 0.0, -1.0,
+                    (min_c[:, None] - currentf) / jnp.maximum(min_c[:, None], 1e-9)
+                )
+                even = jnp.where(
+                    currentf != min_c[:, None],
+                    delta_boost,
+                    jnp.where(
+                        min_c[:, None] == max_c[:, None],
+                        -1.0,
+                        jnp.where(
+                            min_c[:, None] == 0.0,
+                            1.0,
+                            (max_c[:, None] - min_c[:, None]) / jnp.maximum(min_c[:, None], 1e-9),
+                        ),
+                    ),
+                )
+                even = jnp.where(has_entries[:, None], even, 0.0)
+
+                per_spread = jnp.where(has_targets_s[:, None], targeted_raw, even)
+                per_spread = jnp.where(missing, -1.0, per_spread)
+                per_spread = jnp.where(active_s[:, None], per_spread, 0.0)
+                spread_total = jnp.sum(per_spread, axis=0)  # [N]
+                spread_p = spread_total != 0.0
+
+            with jax.named_scope("score_mean"):
+                # same popcount fusion as int mode (small counts are exact in
+                # any float dtype, so the quotient is bit-identical to the
+                # astype-chain form)
+                presence = pack_presence_lanes(anti_present, pmask, aff_p, spread_p)
+                num_terms = (1 + jlax.population_count(presence)).astype(fdt)
+                final = (binpack + anti + resched + jnp.where(aff_p, aff, 0.0) + spread_total) / num_terms
+                neg_inf = -jnp.inf
+                score_zero = jnp.asarray(0.0, fdt)
+
+        with jax.named_scope("select"):
+            # -- ring-ordered limit + max-score selection (no permutation) -----
+            # Ring prefix sums at natural index i: with S = natural inclusive
+            # cumsum, T = total, o = offset, the ring-order cumsum is
+            # S(i) - S(o-1) for i >= o and S(i) + (T - S(o-1)) for i < o —
+            # elementwise, so the LimitIterator emulation needs no gathers.
+            #
+            # ONE packed int32 ring cumsum carries everything: the low-score
+            # and feasible count planes ride 16-bit lanes of one int32 plane
+            # (intscore.pack_count_lanes). Lane exactness: both totals are
+            # bounded by n_pad < 2**15, so the low lane never carries into the
+            # high lane, and every SELECTED ring branch is lane-wise
+            # non-negative (i >= o selects S(i) - S(o-1) with [0..o-1] a
+            # subset of [0..i]; i < o selects S(i) + the suffix sum — both
+            # >= 0 per lane), so no borrow crosses lanes either. The skip
+            # prefix is then min(low_cum, MAX_SKIP) (skipped = the first
+            # MAX_SKIP low entries in ring order) and the source prefix is
+            # feas_cum - skip_cum. (int64 field-packing would lift the 2**15
+            # bound, but int64 prefix sums are pathologically slow on this
+            # backend — int32 lanes are free.)
+            valid = iota < n_real
+            nr = jnp.maximum(n_real, 1)
+
+            feas_v = feasible & valid
+            # threshold 0 is exact in both modes (int: score60 <= 0 iff the
+            # rational score <= 0; float: the host's 0.0 skip threshold)
+            low = feas_v & (final <= 0)
+
+            def ring_cumsum(a_int):
+                s_nat = jnp.cumsum(a_int)
+                total = s_nat[-1]
+                before = jnp.sum(jnp.where(iota < offset, a_int, 0),
+                                 dtype=jnp.int32)
+                ring = jnp.where(
+                    iota >= offset, s_nat - before, s_nat + (total - before)
+                )
+                return ring, total
+
+            if n_pad < PACK_COUNT_MAX:
+                packed_cum, packed_total = ring_cumsum(pack_count_lanes(low, feas_v))
+                low_cum = unpack_count_lo(packed_cum)
+                feas_cum = unpack_count_hi(packed_cum)
+                low_total = unpack_count_lo(packed_total)
+                feas_total = unpack_count_hi(packed_total)
             else:
-                freed_vec = freed4[:d_dims]
-            used = used - oh_chf[:, None] * freed_vec[None, :]
+                # lanes would overflow on a >32K-node pad: two plain cumsums
+                low_cum, low_total = ring_cumsum(low.astype(jnp.int32))
+                feas_cum, feas_total = ring_cumsum(feas_v.astype(jnp.int32))
 
-            # running Q27 exponential: multiply the just-committed chosen
-            # row by each kept candidate's eviction factor (slot-ascending
-            # product order is fixed, so the result is deterministic)
-            if e_base.shape[0]:
-                from .intscore import E27_BITS as _PB, E27_ONE as _PO
+            skipped = low & (low_cum <= MAX_SKIP)
+            skip_cum = jnp.minimum(low_cum, MAX_SKIP)
+            ret = feas_v & ~skipped
+            ret_i = ret.astype(jnp.int32)
+            ret_cum = feas_cum - skip_cum
+            ret_excl = ret_cum - ret_i
 
-                eb_ch = row_c(e_base).astype(i64)              # [2]
-                evf_ch = row_c(pre_evf)                        # [C, 2] i32
-                for ci in range(c_w):
-                    f = jnp.where(keep[ci], evf_ch[ci].astype(i64), i64(_PO))
-                    eb_ch = (eb_ch * f) >> _PB
-                e_base = jnp.where(
-                    (oh_ch & use_pre)[:, None], eb_ch.astype(jnp.int32), e_base
-                )
+            limit = limit_p
+            pulled = valid & (ret_excl < limit)
+            src_cand = ret & pulled
+            ret_total = feas_total - jnp.minimum(low_total, MAX_SKIP)
+            backlog_n = jnp.maximum(limit - ret_total, 0)
+            skip_i = skipped.astype(jnp.int32)
+            skip_excl = skip_cum - skip_i
+            backlog_cand = skipped & (skip_excl < backlog_n)
+            cand = src_cand | backlog_cand
 
-            evicted = oh_ch[:, None] & keep[None, :]           # [N, C]
-            pre_alive = pre_alive & ~evicted
-            freed3 = jnp.sum(jnp.where(keep[:, None], res3_ch, 0), axis=0)
-            pre_remaining = pre_remaining + jnp.where(
-                oh_ch[:, None], freed3[None, :], 0
+            # ranks are unique across candidates (source ranks < ret_total <=
+            # backlog ranks), so (max score, min rank) names one node exactly
+            rank = jnp.where(src_cand, ret_excl, ret_total + skip_excl)
+
+            cand_scores = jnp.where(cand, final, neg_inf)
+            best_score = jnp.max(cand_scores)
+            winners = cand & (cand_scores == best_score)
+            winner_rank = jnp.where(winners, rank, jnp.int32(2**31 - 1))
+            best_rank = jnp.min(winner_rank)
+            any_cand = jnp.any(cand)
+            chosen = jnp.where(
+                any_cand & (~skip_step),
+                jnp.argmax(winners & (rank == best_rank)).astype(jnp.int32),
+                -1,
             )
-            gid_ch = row_c(pre_gid)                            # [C]
-            pre_counts = pre_counts + jnp.sum(
-                ((gid_ch[:, None] == iota_gp[None, :]) & keep[:, None])
-                .astype(jnp.int32),
-                axis=0,
-                dtype=jnp.int32,
-            )
-            # output column: second-pass rank per evicted slot (ascending
-            # rank = final eviction order), -1 for untouched slots
-            evict_out = jnp.where(keep, p_rank, jnp.int32(-1))  # [C]
-        else:
-            evict_out = jnp.zeros((0,), jnp.int32)
 
-        # failed placement: revert eviction, mark TG failed
-        if has_evict:
-            revert = do_evict & (~success)
-            used = used + oh_ev_nodef[:, None] * jnp.where(revert, evict_res, 0)[None, :]
-            rev_i = jnp.where(revert & (evict_tg >= 0), 1, 0)
-            tg_counts = tg_counts + (sel_evg[:, None] & oh_ev_node[None, :]) * rev_i
-            job_counts = job_counts + oh_ev_node * jnp.where(revert, 1, 0)
+            pulls = jnp.where(skip_step, 0, jnp.sum(pulled.astype(jnp.int32))).astype(jnp.int32)
+            offset = jnp.where(skip_step, offset, (offset + pulls) % nr).astype(jnp.int32)
+
+        with jax.named_scope("carry_update"):
+            # -- apply placement / revert eviction (one-hot adds) --------------
+            success = chosen >= 0
+            ch = jnp.maximum(chosen, 0)
+            oh_ch = (iota == ch)
+            oh_chf = oh_ch.astype(fdt)
+            add_vec = jnp.where(success, ask, 0)
+            used = used + oh_chf[:, None] * add_vec[None, :]
+            inc_i = jnp.where(success, 1, 0)
+            tg_counts = tg_counts + (sel_g[:, None] & oh_ch[None, :]) * inc_i
+            job_counts = job_counts + oh_ch * inc_i
+
+            ch_vid = jnp.sum(jnp.where(oh_ch[None, :], vids, 0), axis=1)  # [S]
+            oh_ch_vid = (iota_v[None, :] == ch_vid[:, None])              # [S, V]
+            inc = jnp.where(success & active_s, 1, 0).astype(fdt)
             spread_counts = spread_counts + jnp.where(
-                sel_evg[:, None, None],
-                (oh_ev_vid * jnp.where(revert, ev_dec, 0).astype(fdt)[:, None])[None, :, :],
-                0,
+                sel_g[:, None, None], (oh_ch_vid.astype(fdt) * inc[:, None])[None, :, :], 0
             )
-            if e_base.shape[0]:
-                from .intscore import E27_BITS as _E27B, E27_ONE as _E27O
+            entry_set = sel_g[:, None, None] & (oh_ch_vid & (inc > 0)[:, None])[None, :, :]
+            spread_entry = spread_entry | entry_set
 
-                rev_f = jnp.where(revert, rev_factor, _E27O).astype(i64)  # [2]
-                eb_rev = (e_base.astype(i64) * rev_f[None, :]) >> _E27B
-                e_base = jnp.where(
-                    oh_ev_node[:, None], eb_rev, e_base.astype(i64)
+            # placement commits the chosen node's new exponential — EXACTLY the
+            # already-computed selection value (running-product spec)
+            if e_base.shape[0]:
+                e_base = jnp.where((oh_ch & success)[:, None], e_sel_i32, e_base)
+            if dp_vids.shape[0]:
+                ch_vid_dp = jnp.sum(jnp.where(oh_ch[None, :], dp_vids, 0), axis=1)  # [D]
+                inc_dp = dp_applies_g & success
+                dp_counts = dp_counts + (
+                    (iota_v2[None, :] == ch_vid_dp[:, None]) & inc_dp[:, None]
                 ).astype(jnp.int32)
-        # forced-node (system) placements are independent per-node
-        # decisions: a failure must NOT poison the TG for later nodes
-        unforced = (forced_node[0] < 0) if forced_node.shape[-1] else True
-        failed = failed | (sel_g & ((~success) & (~skip_step) & unforced))
+
+        with jax.named_scope("preemption"):
+            # -- commit the eviction set on the chosen node --------------------
+            # Host ordering: preemption fires only when the node did NOT fit
+            # outright. The greedy set is filtered by the reference's second
+            # pass (distance vs the FRESH ask, descending) on the chosen
+            # node's extracted [C] row — off the hot [N] axis.
+            if has_pre:
+                c_w = pre_res.shape[1]
+                from .preempt import second_pass_jnp
+
+                fits_ch = jnp.any(oh_ch & fits)
+                use_pre = success & (~fits_ch) & (~skip_step)
+
+                def row_c(arr):
+                    # arr[ch] without gather: one-hot sum over N (exactly one
+                    # non-zero term, so negative fills survive intact)
+                    shape = (n_pad,) + (1,) * (arr.ndim - 1)
+                    out = jnp.sum(jnp.where(oh_ch.reshape(shape), arr, 0), axis=0)
+                    return out.astype(arr.dtype)
+
+                sel_ord_ch = row_c(sel_ord)                        # [C]
+                res3_ch = row_c(pre_res3)                          # [C, 3] i64
+                rem_ch = row_c(pre_remaining)                      # [3] i64
+                keep, p_rank = second_pass_jnp(ask3, res3_ch, sel_ord_ch, rem_ch)
+                keep = keep & use_pre                              # [C]
+
+                # freed capacity credits `used` (the alloc itself stays
+                # overcommitted for SCORING, matching the host's allocs_fit
+                # used — the credit lands after the score terms above)
+                res4_ch = row_c(pre_res)                           # [C, 4] i32
+                freed4 = jnp.sum(
+                    jnp.where(keep[:, None], res4_ch.astype(fdt), 0), axis=0,
+                    dtype=fdt,
+                )                                                  # [4]
+                d_dims = totals.shape[1]
+                if d_dims > 4:
+                    # batch padding may widen D past the gate's 4 dims; the
+                    # extra (device) dims free nothing
+                    freed_vec = jnp.concatenate(
+                        [freed4, jnp.zeros(d_dims - 4, freed4.dtype)]
+                    )
+                else:
+                    freed_vec = freed4[:d_dims]
+                used = used - oh_chf[:, None] * freed_vec[None, :]
+
+                # running Q27 exponential: multiply the just-committed chosen
+                # row by each kept candidate's eviction factor (slot-ascending
+                # product order is fixed, so the result is deterministic)
+                if e_base.shape[0]:
+                    from .intscore import E27_BITS as _PB, E27_ONE as _PO
+
+                    eb_ch = row_c(e_base).astype(i64)              # [2]
+                    evf_ch = row_c(pre_evf)                        # [C, 2] i32
+                    for ci in range(c_w):
+                        f = jnp.where(keep[ci], evf_ch[ci].astype(i64), i64(_PO))
+                        eb_ch = (eb_ch * f) >> _PB
+                    e_base = jnp.where(
+                        (oh_ch & use_pre)[:, None], eb_ch.astype(jnp.int32), e_base
+                    )
+
+                evicted = oh_ch[:, None] & keep[None, :]           # [N, C]
+                pre_alive = pre_alive & ~evicted
+                freed3 = jnp.sum(jnp.where(keep[:, None], res3_ch, 0), axis=0)
+                pre_remaining = pre_remaining + jnp.where(
+                    oh_ch[:, None], freed3[None, :], 0
+                )
+                gid_ch = row_c(pre_gid)                            # [C]
+                pre_counts = pre_counts + jnp.sum(
+                    ((gid_ch[:, None] == iota_gp[None, :]) & keep[:, None])
+                    .astype(jnp.int32),
+                    axis=0,
+                    dtype=jnp.int32,
+                )
+                # output column: second-pass rank per evicted slot (ascending
+                # rank = final eviction order), -1 for untouched slots
+                evict_out = jnp.where(keep, p_rank, jnp.int32(-1))  # [C]
+            else:
+                evict_out = jnp.zeros((0,), jnp.int32)
+
+        with jax.named_scope("carry_update"):
+            # failed placement: revert eviction, mark TG failed
+            if has_evict:
+                revert = do_evict & (~success)
+                used = used + oh_ev_nodef[:, None] * jnp.where(revert, evict_res, 0)[None, :]
+                rev_i = jnp.where(revert & (evict_tg >= 0), 1, 0)
+                tg_counts = tg_counts + (sel_evg[:, None] & oh_ev_node[None, :]) * rev_i
+                job_counts = job_counts + oh_ev_node * jnp.where(revert, 1, 0)
+                spread_counts = spread_counts + jnp.where(
+                    sel_evg[:, None, None],
+                    (oh_ev_vid * jnp.where(revert, ev_dec, 0).astype(fdt)[:, None])[None, :, :],
+                    0,
+                )
+                if e_base.shape[0]:
+                    from .intscore import E27_BITS as _E27B, E27_ONE as _E27O
+
+                    rev_f = jnp.where(revert, rev_factor, _E27O).astype(i64)  # [2]
+                    eb_rev = (e_base.astype(i64) * rev_f[None, :]) >> _E27B
+                    e_base = jnp.where(
+                        oh_ev_node[:, None], eb_rev, e_base.astype(i64)
+                    ).astype(jnp.int32)
+            # forced-node (system) placements are independent per-node
+            # decisions: a failure must NOT poison the TG for later nodes
+            unforced = (forced_node[0] < 0) if forced_node.shape[-1] else True
+            failed = failed | (sel_g & ((~success) & (~skip_step) & unforced))
 
         new_carry = (used, tg_counts, job_counts, spread_counts, spread_entry,
                      offset, failed, e_base, dp_counts,
@@ -1144,6 +1176,26 @@ def _release_enc_claim(claim_cell: Dict[str, object]) -> None:
 # ---------------------------------------------------------------------------
 
 
+def _record_lone_dispatch(source: str, enc: "EncodedEval", p_pad: int,
+                          t_stack: float, t_called: float) -> None:
+    """The dispatch record (trace/lifecycle.on_dispatch) of a path that
+    goes around the batcher — the forced kernel, the single scan — with
+    the fields it has: one eval (the one whose stage is open on this
+    thread), no gather, no fence between the kernel and the copy back
+    (``t_ready`` stays None). Called once the outputs are numpy arrays."""
+    from ..trace import lifecycle as _tlc
+
+    t_host = _phases.now()
+    eval_id = _tlc.current_eval()
+    _tlc.on_dispatch(
+        wave=_tlc.next_wave(), source=source,
+        eval_ids=[eval_id] if eval_id is not None else [],
+        b=1, b_pad=1, p_pad=p_pad, n_pad=enc.n_pad, steps=enc.p,
+        padded_steps=p_pad, t_start=t_stack, t_stack=t_stack,
+        t_called=t_called, t_host=t_host, t_handed=t_host,
+    )
+
+
 class TpuPlacementEngine:
     _shared: Optional["TpuPlacementEngine"] = None
     _atexit_registered = False
@@ -1215,8 +1267,6 @@ class TpuPlacementEngine:
         kernel = self._forced_fn()
         import jax.numpy as jnp
 
-        from ..utils import phases as _phases
-
         p = enc.p
         p_pad = _round_up(max(p, 1))
         xs = enc.xs
@@ -1237,14 +1287,18 @@ class TpuPlacementEngine:
         static = tuple(jnp.asarray(a) for a in enc.static)
         init_carry = tuple(jnp.asarray(a) for a in enc.carry)
         xs = tuple(jnp.asarray(a) for a in xs)
+        t_stack = _phases.now()
         with _phases.track("device"):
             chosen, scores, pulls, skipped, evict = kernel(static, init_carry, xs)
+            t_called = _phases.now()
             chosen = np.asarray(chosen)
-        return (
+        out = (
             chosen[:p], np.asarray(scores)[:p],
             np.asarray(pulls)[:p], np.asarray(skipped)[:p],
             np.asarray(evict)[:p],
         )
+        _record_lone_dispatch("forced", enc, p_pad, t_stack, t_called)
+        return out
 
     # -- chunked throughput tier ---------------------------------------
 
@@ -1297,8 +1351,6 @@ class TpuPlacementEngine:
 
         assert_chunk_gate(enc)
         import jax.numpy as jnp
-
-        from ..utils import phases as _phases
 
         tg_idx_p = np.asarray(enc.xs[0])[: enc.p]
         counts: Dict[int, int] = {}
@@ -1455,7 +1507,6 @@ class TpuPlacementEngine:
             _metrics.incr_counter("nomad.tpu_engine.small_eval_device_retry")
 
         from ..trace import lifecycle as _tlc
-        from ..utils import phases as _phases
 
         wave_id = sched.eval.id
         batcher = getattr(sched.planner, "device_batcher", None)
@@ -1473,7 +1524,7 @@ class TpuPlacementEngine:
             t0 = _metrics.now()
             with _HOST_WORK_SEM:
                 t1 = _metrics.now()
-                with _phases.track("encode"), _tlc.pipeline_stage("encode", wave_id):
+                with _tlc.stage("encode", wave_id):
                     enc = self.encode_eval(sched, destructive, place)
                 _metrics.measure_since("nomad.tpu_engine.encode_work", t1)
             _metrics.measure_since("nomad.tpu_engine.encode", t0)
@@ -1501,8 +1552,7 @@ class TpuPlacementEngine:
                 batcher.cancel_expected()
                 expected_held = False
             try:
-                with _phases.track("device_wait"), \
-                        _tlc.pipeline_stage("dispatch", wave_id):
+                with _tlc.stage("device_wait", wave_id):
                     if use_chunked:
                         chosen, scores, pulls, skipped_steps, evict = self.run_chunked(
                             enc, chunk_k=int(getattr(sched, "chunk_k", 128)))
@@ -1535,7 +1585,7 @@ class TpuPlacementEngine:
         t0 = _metrics.now()
         with _HOST_WORK_SEM:
             t1 = _metrics.now()
-            with _phases.track("apply"):
+            with _tlc.stage("apply", wave_id):
                 chosen = np.asarray(chosen)
                 skipped_steps = np.asarray(skipped_steps)
                 evict = np.asarray(evict)
@@ -2123,13 +2173,17 @@ class TpuPlacementEngine:
         init_carry = tuple(jnp.asarray(a) for a in enc.carry)
         xs = tuple(jnp.asarray(a) for a in enc.xs)
 
+        t_stack = _phases.now()
         _carry, (chosen, scores, pulls, skipped, evict) = place_scan(
             enc.n_pad, static, init_carry, xs
         )
-        return (
+        t_called = _phases.now()
+        out = (
             np.asarray(chosen), np.asarray(scores),
             np.asarray(pulls), np.asarray(skipped), np.asarray(evict),
         )
+        _record_lone_dispatch("single", enc, enc.p, t_stack, t_called)
+        return out
 
     @staticmethod
     def _pipeline_remember(sched, enc: "EncodedEval") -> None:
@@ -2199,15 +2253,12 @@ class TpuPlacementEngine:
             if len({net.device for net in node.node_resources.networks if net.device}) > 1:
                 return fallback("multi-NIC node")
 
-        from ..utils import phases as _phases
-
         from ..trace import lifecycle as _tlc
 
         tg_specs: Dict[str, TGSpec] = {}
         port_cache: Dict[str, object] = {}
         try:
-            with _phases.track("encode"), \
-                    _tlc.pipeline_stage("encode", sched.eval.id):
+            with _tlc.stage("encode", sched.eval.id):
                 for tup in place:
                     tg = tup.task_group
                     if tg.name not in tg_specs:
@@ -2388,8 +2439,7 @@ class TpuPlacementEngine:
         # allocs on one node) interact through used/tg_counts and keep
         # the sequential scan.
         batcher = getattr(sched.planner, "device_batcher", None)
-        with _phases.track("device_wait"), \
-                _tlc.pipeline_stage("dispatch", sched.eval.id):
+        with _tlc.stage("device_wait", sched.eval.id):
             if len(set(forced.tolist())) == p and pre_tables is None:
                 # (the forced fast path never encodes preemption — a preempt
                 # pass always takes the sequential scan below)

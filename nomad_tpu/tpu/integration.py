@@ -4,7 +4,7 @@
 eval's whole placement batch, or NotImplemented to fall back to the host
 iterator stack (the host path is always semantically complete).
 
-Both entry points run under the ``engine_gate`` phase: the gate checks,
+Both entry points run under the ``engine_gate`` stage: the gate checks,
 encode attempts and fallback decisions are host work the worker pays on
 EVERY eval (device-handled or not), and without a span of their own they
 showed up as unexplained worker_busy time in phases.coverage. The
@@ -13,11 +13,11 @@ coverage union dedups the overlap.
 """
 from __future__ import annotations
 
-from ..utils import phases as _phases
+from ..trace import lifecycle as _lifecycle
 
 
 def compute_placements_with_engine(sched, destructive, place):
-    with _phases.track("engine_gate"):
+    with _lifecycle.stage("engine_gate", sched.eval.id):
         # the lazy engine import is part of the gate cost: the first
         # eval pays it (jax + kernel modules), and outside the span it
         # surfaced as a one-shot unexplained worker_busy chunk
@@ -32,7 +32,7 @@ def compute_system_placements_with_engine(sched, place, sched_config=None):
     handled, a list of leftover placements when only preemption-needing
     nodes remain for the host loop, NotImplemented to fall back to the
     host per-node stack wholesale."""
-    with _phases.track("engine_gate"):
+    with _lifecycle.stage("engine_gate", sched.eval.id):
         from .engine import TpuPlacementEngine
 
         engine = TpuPlacementEngine.shared()

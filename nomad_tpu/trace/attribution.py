@@ -32,7 +32,7 @@ spans, instrumented idle, nor the complement explains stays
 unattributed and drags coverage below the floor: an instrumentation
 hole must still fail the self-check, never get laundered as idle.
 
-All interval math is on the lifecycle clock (``time.monotonic``).
+All interval math is on the lifecycle clock (``utils.phases.now``).
 """
 from __future__ import annotations
 

@@ -29,8 +29,9 @@ This module is the missing carrier:
 Span times are WALL clock (``time.time()``) — cross-process stitching
 needs a common axis, and trace/stitch.py estimates per-process clock
 offset from client/server span pairs rather than trusting it. The
-in-process lifecycle/pipeline layers stay on ``time.monotonic``;
-:func:`wall_from_monotonic` converts when they emit spans here.
+in-process lifecycle/pipeline layers stay on ``utils.phases.now``
+(``time.perf_counter``); :func:`wall_from_monotonic` converts when they
+emit spans here.
 """
 from __future__ import annotations
 
@@ -44,6 +45,7 @@ from collections import deque
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
+from ..utils import phases as _phases
 from ..utils.lock_witness import module_witness_lock
 
 #: ring capacity: at ~300B/span this bounds the table at ~20MB while
@@ -167,9 +169,10 @@ def inject() -> Optional[Dict[str, str]]:
 
 
 def wall_from_monotonic(t: float) -> float:
-    """Convert a ``time.monotonic`` stamp to the wall-clock axis spans
-    are recorded on."""
-    return t + (time.time() - time.monotonic())
+    """Convert a stamp of the in-process span clock (``phases.now``, the
+    monotonic clock lifecycle records and stages are on) to the
+    wall-clock axis spans are recorded on."""
+    return t + (time.time() - _phases.now())
 
 
 def record_span(name: str, start: float, end: float, *,

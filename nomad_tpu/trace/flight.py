@@ -232,22 +232,29 @@ class FlightRecorder:
 
 
 def _batcher_probe(batcher) -> Callable[[], Dict[str, object]]:
-    """Queue depth plus dispatch-profile DELTAS: the profile's running
-    totals tell you nothing per-frame; the tick-over-tick delta is the
-    instantaneous dispatch rate."""
+    """Queue depth plus dispatch DELTAS: the running totals tell you
+    nothing per-frame; the tick-over-tick delta is the instantaneous
+    dispatch rate. Reads the batcher's stats totals, not
+    dispatch_profile(): that walks the dispatch-record ring, too much
+    for a 250ms cadence."""
     last = {"dispatches": 0, "evals": 0}
 
     def probe() -> Dict[str, object]:
-        prof = batcher.dispatch_profile()
-        cur_d = int(prof.get("dispatches", 0) or 0)
-        cur_e = int(prof.get("evals", 0) or 0)
+        with batcher._lock:
+            stats = dict(batcher.stats)
+        cur_d = int(stats.get("dispatches", 0) or 0)
+        cur_e = int(stats.get("evals", 0) or 0)
+
+        def avg(key: str):
+            return round(stats[key] / cur_d, 3) if cur_d else None
+
         out = {
             "queue_depth": batcher.queue_depth(),
             "dispatches": cur_d,
             "dispatches_delta": cur_d - last["dispatches"],
             "evals_delta": cur_e - last["evals"],
-            "compute_ms_avg": prof.get("compute_ms_avg"),
-            "pad_stack_ms_avg": prof.get("pad_stack_ms_avg"),
+            "compute_ms_avg": avg("compute_ms_total"),
+            "pad_stack_ms_avg": avg("pad_stack_ms_total"),
         }
         last["dispatches"], last["evals"] = cur_d, cur_e
         return out

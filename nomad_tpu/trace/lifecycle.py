@@ -1,8 +1,23 @@
 """Eval lifecycle spans: enqueue -> dequeue -> invoke -> submit -> apply -> ack.
 
-Each DELIVERY ATTEMPT of an evaluation gets one ``EvalTrace`` record,
-stamped in place by the broker, the worker, the scheduler (host/device
-path tag) and the plan applier. Records move from an in-flight table to
+THE span store of the served path. Each DELIVERY ATTEMPT of an
+evaluation gets one ``EvalTrace`` record, stamped in place by the broker,
+the worker, the scheduler (host/device path tag), the batcher and the
+plan applier; the intervals between the stamps are its ``stages``, each
+written by ONE call at its site::
+
+    with lifecycle.stage("encode", eval_id):
+        ...
+
+which appends ``(name, t0, t1)`` to the eval's record, feeds
+``utils/phases`` under the same name while a bench has phases enabled,
+and feeds the per-wave pipeline ring under the name the attribution
+engine reads (``_STAGE_RING``). Beside the records sits a bounded ring of
+per-dispatch records (``on_dispatch``/``dispatch_records``): one per
+device dispatch, with the stamps that tie it to the run of its program
+in a profiler trace. Every stamp here, in ``utils/phases`` and in the
+batcher reads one clock, ``phases.now`` (``time.perf_counter``): the one
+the benchmark's mark ties to the profiler's. Records move from an in-flight table to
 a bounded ring buffer on ack/nack, so memory is O(inflight + ring) no
 matter how long the server runs. A nacked eval's re-enqueue (after the
 broker's compounding delay) opens a FRESH record; the broker's delivery
@@ -20,30 +35,33 @@ same stages, joined per evaluation instead of aggregated per call.
 """
 from __future__ import annotations
 
+import itertools
 import threading
-import time
 from collections import deque
 from contextlib import contextmanager
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple, Union
 
-from ..utils import metric_names, metrics
+from ..utils import metric_names, metrics, phases
 from ..utils.lock_witness import module_witness_lock
 from ..utils.race_witness import tracked_deque, tracked_dict
 from . import context as _xcontext
 
 _DONE_CAP = 2048
+_DISPATCH_CAP = 4096
 
-_clock = time.monotonic
+_clock = phases.now
 
 
 class EvalTrace:
-    """One delivery attempt of one evaluation (all times ``time.monotonic``)."""
+    """One delivery attempt of one evaluation (all times ``phases.now``)."""
 
     __slots__ = (
         "eval_id", "job_id", "namespace", "type", "triggered_by", "priority",
         "attempt", "worker_id", "path",
         "enqueue_t", "dequeue_t", "invoke_start_t", "invoke_end_t",
-        "submit_t", "apply_t", "end_t", "outcome", "trace_ctx",
+        "submit_t", "evaluate_start_t", "apply_t", "commit_t", "end_t",
+        "outcome", "trace_ctx",
+        "snapshot_index", "commit_index", "waves", "stages",
     )
 
     def __init__(self, eval_id: str, job_id: str, namespace: str,
@@ -63,9 +81,23 @@ class EvalTrace:
         self.invoke_start_t: Optional[float] = None
         self.invoke_end_t: Optional[float] = None
         self.submit_t: Optional[float] = None
+        # the applier began evaluate_plan for this eval's (first) plan:
+        # the start of its first ``plan_evaluate`` stage
+        self.evaluate_start_t: Optional[float] = None
         self.apply_t: Optional[float] = None
+        # the raft apply that committed its (last) plan returned, and the
+        # index it returned: the program's own per-job commit time
+        self.commit_t: Optional[float] = None
+        self.commit_index: Optional[int] = None
         self.end_t: Optional[float] = None
         self.outcome: Optional[str] = None  # "ack" | "nack" | "failed" | "flush"
+        # raft index of the snapshot the worker scheduled on (what
+        # Plan.snapshot_index carries and the commit drops)
+        self.snapshot_index: Optional[int] = None
+        # sequence numbers of the dispatches that served it (one per
+        # re-dispatch) and its (name, t0, t1) stages, in completion order
+        self.waves: List[int] = []
+        self.stages: List[Tuple[str, float, float]] = []
         # carried distributed-trace context ({"trace_id","span_id"}) so
         # the record's phase spans land in the cross-process trace
         self.trace_ctx: Optional[Dict[str, str]] = None
@@ -94,16 +126,29 @@ class EvalTrace:
             "queue_ms": ms(self.enqueue_t, self.dequeue_t),
             "invoke_wait_ms": ms(self.dequeue_t, self.invoke_start_t),
             "invoke_ms": ms(self.invoke_start_t, self.invoke_end_t),
+            "plan_queue_ms": ms(self.submit_t, self.evaluate_start_t),
             "submit_to_apply_ms": ms(self.submit_t, self.apply_t),
             "apply_to_end_ms": ms(self.apply_t, self.end_t),
+            "enqueue_to_commit_ms": ms(self.enqueue_t, self.commit_t),
             "total_ms": round(self.total_ms(now), 3),
+            "snapshot_index": self.snapshot_index,
+            "commit_index": self.commit_index,
+            "waves": list(self.waves),
+            "stages": [
+                {"stage": name, "at_ms": ms(self.enqueue_t, t0),
+                 "ms": ms(t0, t1)}
+                for name, t0, t1 in self.stages
+            ],
         }
 
     def raw(self) -> Dict[str, object]:
-        """Raw monotonic stamps (attribution joins these with pipeline
-        spans on the same clock; to_dict() only exposes durations)."""
+        """Raw stamps on ``phases.now``'s clock (attribution and the
+        benchmark's readers join these with pipeline spans, dispatch
+        records and phases on the same clock; to_dict() only exposes
+        durations)."""
         return {
             "eval_id": self.eval_id,
+            "job_id": self.job_id,
             "type": self.type,
             "attempt": self.attempt,
             "path": self.path,
@@ -113,8 +158,15 @@ class EvalTrace:
             "invoke_start_t": self.invoke_start_t,
             "invoke_end_t": self.invoke_end_t,
             "submit_t": self.submit_t,
+            "evaluate_start_t": self.evaluate_start_t,
             "apply_t": self.apply_t,
+            "commit_t": self.commit_t,
             "end_t": self.end_t,
+            "snapshot_index": self.snapshot_index,
+            "commit_index": self.commit_index,
+            "wave": self.waves[-1] if self.waves else None,
+            "waves": list(self.waves),
+            "stages": list(self.stages),
         }
 
 
@@ -122,6 +174,12 @@ _lock = module_witness_lock("lifecycle._lock")
 _inflight: Dict[str, EvalTrace] = tracked_dict("lifecycle._inflight", {})
 _done: "deque[EvalTrace]" = tracked_deque("lifecycle._done", maxlen=_DONE_CAP)
 _counts: Dict[str, int] = {"ack": 0, "nack": 0, "failed": 0, "flush": 0}
+# one record per device dispatch (on_dispatch), newest last
+_dispatches: "deque[Dict[str, object]]" = deque(maxlen=_DISPATCH_CAP)
+_wave_seq = itertools.count(1)
+# since when the in-flight table has been empty (None while it is not):
+# closed into a ``no_ready_eval`` phases interval by the next enqueue
+_idle_since: Optional[float] = _clock()
 
 # -- pipeline stage spans ---------------------------------------------------
 #
@@ -161,10 +219,12 @@ def reset() -> None:
     # re-mint the rings through the factories so a race witness armed
     # after import still gets tracked tables (the import-time ones
     # predate arming)
-    global _inflight, _done, _pipe_epoch
+    global _inflight, _done, _pipe_epoch, _idle_since
     with _lock:
         _inflight = tracked_dict("lifecycle._inflight", {})
         _done = tracked_deque("lifecycle._done", maxlen=_DONE_CAP)
+        _dispatches.clear()
+        _idle_since = _clock()
         for k in _counts:
             _counts[k] = 0
         # aux stages (wait_min_index, raft_fsm, ...) registered via
@@ -192,8 +252,12 @@ def on_enqueue(evaluation) -> None:
         getattr(evaluation, "priority", 0), _clock(),
     )
     rec.trace_ctx = getattr(evaluation, "trace_ctx", None)
+    global _idle_since
     with _lock:
+        idle_t0, _idle_since = _idle_since, None
         _inflight.setdefault(evaluation.id, rec)
+    if idle_t0 is not None:
+        phases.record("no_ready_eval", idle_t0, rec.enqueue_t)
 
 
 def on_dequeue(eval_id: str, attempt: int) -> None:
@@ -234,6 +298,14 @@ def on_invoke_end(eval_id: str) -> None:
             rec.invoke_end_t = _clock()
 
 
+def on_snapshot(eval_id: str, index: int) -> None:
+    """The worker took its state snapshot at raft index ``index``."""
+    with _lock:
+        rec = _inflight.get(eval_id)
+        if rec is not None:
+            rec.snapshot_index = index
+
+
 def on_plan_submit(eval_id: str) -> None:
     with _lock:
         rec = _inflight.get(eval_id)
@@ -241,12 +313,19 @@ def on_plan_submit(eval_id: str) -> None:
             rec.submit_t = _clock()
 
 
-def on_apply(eval_id: str) -> None:
-    """Plan applier resolved this eval's plan (committed or rejected)."""
+def on_apply(eval_id: str, commit_t: Optional[float] = None,
+             commit_index: Optional[int] = None) -> None:
+    """Plan applier resolved this eval's plan (committed or rejected).
+    ``commit_t``/``commit_index``: when the raft apply that committed it
+    returned, and with which index (a re-dispatched eval's last commit
+    overwrites its first)."""
     with _lock:
         rec = _inflight.get(eval_id)
         if rec is not None:
             rec.apply_t = _clock()
+            if commit_t is not None:
+                rec.commit_t = commit_t
+                rec.commit_index = commit_index
 
 
 def eval_trace_ids(eval_id: str,
@@ -287,6 +366,7 @@ def _emit_trace_spans(rec: EvalTrace) -> None:
 
 
 def _close(eval_id: str, outcome: str) -> None:
+    global _idle_since
     with _lock:
         rec = _inflight.pop(eval_id, None)
         if rec is None:
@@ -295,6 +375,8 @@ def _close(eval_id: str, outcome: str) -> None:
         rec.outcome = outcome
         _done.append(rec)
         _counts[outcome] = _counts.get(outcome, 0) + 1
+        if not _inflight:
+            _idle_since = rec.end_t
     # outside _lock: span recording takes the context ring's own lock
     _emit_trace_spans(rec)
 
@@ -311,6 +393,7 @@ def on_nack(eval_id: str, failed: bool = False) -> None:
 
 def on_flush() -> None:
     """Broker flushed (leadership lost): close every in-flight record."""
+    global _idle_since
     with _lock:
         now = _clock()
         flushed = list(_inflight.values())
@@ -320,53 +403,176 @@ def on_flush() -> None:
             _done.append(rec)
             _counts["flush"] += 1
         _inflight.clear()
+        if _idle_since is None:
+            _idle_since = now
     for rec in flushed:
         _emit_trace_spans(rec)
 
 
-# -- pipeline stage stamping -----------------------------------------------
+# -- stage and pipeline-ring stamping ---------------------------------------
+
+#: stage name -> the pipeline ring's name for it (the names
+#: trace/attribution.py and the overlap / retry-reuse tests read). A
+#: stage not listed here is kept on the eval's record and in phases only.
+_STAGE_RING: Dict[str, str] = {
+    "encode": "encode",
+    "device_wait": "dispatch",
+    "plan_evaluate": "evaluate",
+    "raft_fsm": "commit",
+    "wait_index": "wait_min_index",
+}
+
+EvalIds = Union[str, Iterable[str], None]
+
+
+class _Span:
+    """What ``stage`` yields: the interval's stamps, ``t1`` set on exit."""
+
+    __slots__ = ("t0", "t1")
+
+    def __init__(self, t0: float) -> None:
+        self.t0 = t0
+        self.t1: Optional[float] = None
 
 
 def pipeline_now() -> float:
-    """The clock pipeline spans are recorded on (time.monotonic)."""
+    """The clock every span here is recorded on (``phases.now``)."""
     return _clock()
 
 
-@contextmanager
-def pipeline_stage(stage: str, wave_id: str):
-    """Record one stage execution for one wave. Depth (open count) is
-    visible to gauges while the stage runs; the completed span lands in
-    the per-stage ring on exit."""
-    t0 = _clock()
+# the eval whose stage is open on this thread (innermost single-eval
+# ``stage``): lets the device paths name the eval they serve in their
+# dispatch record without threading an id through every signature
+_tls = threading.local()
+
+
+def current_eval() -> Optional[str]:
+    return getattr(_tls, "eval_id", None)
+
+
+def _ring_open(ring: str) -> None:
     with _lock:
-        _pipe_open[stage] = _pipe_open.get(stage, 0) + 1
+        _pipe_open[ring] = _pipe_open.get(ring, 0) + 1
+
+
+def _ring_append(ring: str, wave_ids: Tuple[str, ...], t0: float,
+                 t1: float) -> None:
+    """One (wave_id, t0, t1) span per id in ``ring``; caller holds _lock.
+    Spans are clamped to the last reset() so an accumulation straddling
+    a bench's warmup reset cannot stretch the measured window backwards."""
+    t0 = max(t0, _pipe_epoch)
+    if t1 <= t0:
+        return
+    _pipe_done.setdefault(ring, deque(maxlen=_PIPE_CAP)).extend(
+        (wave_id, t0, t1) for wave_id in wave_ids)
+    _pipe_counts[ring] = _pipe_counts.get(ring, 0) + len(wave_ids)
+
+
+@contextmanager
+def stage(name: str, eval_id: EvalIds = None, *, tag: Optional[str] = None):
+    """THE span call of the served path: one interval, one call.
+
+    Appends ``(name, t0, t1)`` to the in-flight record of ``eval_id`` (one
+    id, or several for an interval they share: the applier's batched raft
+    apply), feeds ``utils/phases`` under ``name`` while phases are
+    enabled, and feeds the pipeline ring under ``_STAGE_RING[name]``
+    keyed by eval id. With no eval to name (``Server.raft_apply`` from
+    any thread) ``tag`` keys an aux ring span under ``name`` itself.
+    Yields the span, whose ``t0``/``t1`` callers may reuse for the
+    cross-process trace."""
+    if eval_id is None:
+        ids: Tuple[str, ...] = ()
+        ring, keys = (name, (tag,)) if tag is not None else (None, ())
+    else:
+        ids = (eval_id,) if isinstance(eval_id, str) else tuple(eval_id)
+        ring, keys = _STAGE_RING.get(name), ids
+    outer = current_eval()
+    if len(ids) == 1:
+        _tls.eval_id = ids[0]
+    span = _Span(_clock())
+    if ring is not None:
+        _ring_open(ring)
+    try:
+        yield span
+    finally:
+        span.t1 = t1 = _clock()
+        _tls.eval_id = outer
+        with _lock:
+            for eid in ids:
+                rec = _inflight.get(eid)
+                if rec is not None:
+                    rec.stages.append((name, span.t0, t1))
+                    if name == "plan_evaluate" and rec.evaluate_start_t is None:
+                        rec.evaluate_start_t = span.t0
+            if ring is not None:
+                _pipe_open[ring] = max(0, _pipe_open.get(ring, 0) - 1)
+                _ring_append(ring, keys, span.t0, t1)
+        phases.record(name, span.t0, t1)
+
+
+@contextmanager
+def pipeline_stage(ring: str, wave_id: str):
+    """Record one execution of a ring stage for one wave, on the ring
+    alone (no eval record, no phase): for callers outside the served
+    path. Depth (open count) is visible to gauges while the stage runs;
+    the completed span lands in the per-stage ring on exit."""
+    t0 = _clock()
+    _ring_open(ring)
     try:
         yield
     finally:
         t1 = _clock()
         with _lock:
-            _pipe_open[stage] = max(0, _pipe_open.get(stage, 0) - 1)
-            _pipe_done.setdefault(stage, deque(maxlen=_PIPE_CAP)).append(
-                (wave_id, t0, t1)
-            )
-            _pipe_counts[stage] = _pipe_counts.get(stage, 0) + 1
+            _pipe_open[ring] = max(0, _pipe_open.get(ring, 0) - 1)
+            _ring_append(ring, (wave_id,), t0, t1)
 
 
-def pipeline_record(stage: str, wave_id: str, t0: float, t1: float) -> None:
-    """Record an externally-timed stage span (times from pipeline_now());
-    used by the applier's waiter thread (per-payload commit times inside
-    one batched raft entry) and by scheduler workers flushing coalesced
-    ``idle`` dequeue-wait periods. Spans are clamped to the last reset()
-    so accumulations straddling a bench's warmup reset cannot stretch the
-    measured window backwards."""
+def pipeline_record(ring: str, wave_id: str, t0: float, t1: float) -> None:
+    """Record an externally-timed ring span (times from pipeline_now());
+    used by scheduler workers flushing coalesced ``idle`` dequeue-wait
+    periods."""
     with _lock:
-        t0 = max(t0, _pipe_epoch)
-        if t1 <= t0:
-            return
-        _pipe_done.setdefault(stage, deque(maxlen=_PIPE_CAP)).append(
-            (wave_id, t0, t1)
-        )
-        _pipe_counts[stage] = _pipe_counts.get(stage, 0) + 1
+        _ring_append(ring, (wave_id,), t0, t1)
+
+
+# -- per-dispatch records ---------------------------------------------------
+
+#: every key a dispatch record has; a path that lacks a stamp (the forced
+#: kernel and the single scan have no gather) leaves it None
+DISPATCH_FIELDS = (
+    "wave", "source", "batcher", "eval_ids", "b", "b_pad", "p_pad", "n_pad",
+    "steps", "padded_steps", "closed_by", "d2h_bytes",
+    "t_first_enqueue", "t_start", "t_stack", "t_called", "t_ready",
+    "t_host", "t_handed",
+)
+
+
+def next_wave() -> int:
+    """The next dispatch sequence number (process-wide, never reused)."""
+    return next(_wave_seq)
+
+
+def on_dispatch(**fields) -> None:
+    """Keep one device dispatch's record (``DISPATCH_FIELDS``) in the
+    bounded ring and note its ``wave`` on the in-flight record of every
+    eval it served."""
+    rec = dict.fromkeys(DISPATCH_FIELDS)
+    rec.update(fields)
+    with _lock:
+        _dispatches.append(rec)
+        for eid in rec["eval_ids"] or ():
+            ev = _inflight.get(eid)
+            if ev is not None:
+                ev.waves.append(rec["wave"])
+
+
+def dispatch_records() -> List[Dict[str, object]]:
+    """The last ``_DISPATCH_CAP`` dispatch records, oldest first (copies:
+    a record is never written again once it is in the ring, so only the
+    list is taken under the lock)."""
+    with _lock:
+        recs = list(_dispatches)
+    return [dict(r) for r in recs]
 
 
 def pipeline_spans(stage: Optional[str] = None) -> List[Tuple[str, str, float, float]]:

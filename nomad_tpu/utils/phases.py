@@ -14,8 +14,13 @@ Zero overhead unless enabled; the bench enables it around its timed
 window. Phases tracked across the system path:
 
   encode         per-eval problem encoding (engine.encode_eval, GIL)
-  device         batched scan dispatch + result fetch (H2D, kernel, D2H)
+  device         the engine's own forced-kernel / chunked-scan round
+                 trip (H2D, kernel, D2H in one bracket)
   pad_stack      batch padding/stacking before dispatch (host)
+  h2d_launch     batched dispatch: the scan call (H2D of the stacked
+                 planes + launch) until it returns
+  kernel_wait    batched dispatch: block_until_ready on the outputs
+  d2h            batched dispatch: the five outputs become numpy arrays
   apply          decode results -> plan blocks (engine._apply_*, GIL)
   plan_evaluate  applier re-check against snapshot (plan_apply, GIL)
   raft_fsm       raft apply -> FSM -> state store commit (GIL)
@@ -39,6 +44,16 @@ window. Phases tracked across the system path:
   wait_index     worker parked on raft replication before snapshotting
   raft_fsm       raft log append -> FSM -> state store commit (every
                  Server.raft_apply, plan commits included)
+
+WAITS (recorded through ``record``; listed in ``wall_shares`` under their
+names, counted neither into ``busy`` nor ``any_host``): they name an
+idle device for what it is.
+
+  gather         a request is queued and the batcher's dispatcher holds
+                 the gather open (first enqueue -> dispatch start)
+  no_ready_eval  trace/lifecycle's in-flight table is empty: no eval
+                 between its READY enqueue and its ack (a nacked eval
+                 waiting out the broker's delay is not ready)
 
 META-PHASES (excluded from ``any_host``/``busy``, which aggregate only
 fine phases): ``worker_busy`` brackets the whole of a worker's eval
@@ -68,6 +83,10 @@ _enabled = False
 # phases that measure a measurement (a window, not work); never summed
 # into the busy/any_host aggregates
 _META = frozenset({"worker_busy"})
+# intervals in which nothing works: named in wall_shares, never busy
+_WAITS = frozenset({"gather", "no_ready_eval"})
+# brackets on the dispatcher thread that stand for device-side time
+_DEVICE = frozenset({"device", "h2d_launch", "kernel_wait", "d2h"})
 
 # merge same-phase spans closer than this (seconds); ~10k coalesced
 # hot-loop calls collapse into a handful of burst intervals
@@ -94,24 +113,32 @@ def track(name: str):
     if not _enabled:
         yield
         return
-    t0 = time.perf_counter()
+    t0 = now()
     try:
         yield
     finally:
-        t1 = time.perf_counter()
-        with _lock:
-            if _enabled:
-                spans = _intervals.setdefault(name, [])
-                if spans and t0 - spans[-1][1] < _COALESCE_GAP:
-                    last = spans[-1]
-                    spans[-1] = (min(last[0], t0), max(last[1], t1))
-                else:
-                    spans.append((t0, t1))
+        record(name, t0, now())
 
 
-def now() -> float:
-    """The clock phase spans are recorded on (perf_counter)."""
-    return time.perf_counter()
+def record(name: str, t0: float, t1: float) -> None:
+    """Record an interval timed elsewhere on ``now()``'s clock under
+    ``name`` (no-op when disabled)."""
+    if not _enabled:
+        return
+    with _lock:
+        if _enabled:
+            spans = _intervals.setdefault(name, [])
+            if spans and t0 - spans[-1][1] < _COALESCE_GAP:
+                last = spans[-1]
+                spans[-1] = (min(last[0], t0), max(last[1], t1))
+            else:
+                spans.append((t0, t1))
+
+
+#: THE clock of every span the served path keeps: phases, lifecycle
+#: records and stages, the batcher's dispatch records. The benchmark's
+#: mark ties it to the profiler's clock.
+now = time.perf_counter
 
 
 def _union_len(spans: List[Tuple[float, float]], lo: float, hi: float) -> float:
@@ -136,9 +163,10 @@ def wall_shares(t0: float, t1: float) -> Dict[str, float]:
     """Seconds of the [t0, t1] window during which >= 1 thread was inside
     each phase (interval union — NOT a thread-sum), plus:
 
-      any_host   union over every host-side fine phase (all but
-                 ``device`` and meta-phases)
-      busy       union over every fine phase (meta-phases excluded)
+      any_host   union over every host-side fine phase (all but the
+                 device-side brackets, waits and meta-phases)
+      busy       union over every fine phase (waits and meta-phases
+                 excluded)
       window     t1 - t0
       untracked  window - busy: wall seconds during which NO fine phase
                  had a thread inside it. r05 shipped a headline where
@@ -149,9 +177,10 @@ def wall_shares(t0: float, t1: float) -> Dict[str, float]:
     with _lock:
         snap = {k: list(v) for k, v in _intervals.items()}
     out = {k: round(_union_len(v, t0, t1), 3) for k, v in snap.items()}
+    idle = _META | _WAITS
     host = [s for k, v in snap.items()
-            if k != "device" and k not in _META for s in v]
-    every = [s for k, v in snap.items() if k not in _META for s in v]
+            if k not in _DEVICE and k not in idle for s in v]
+    every = [s for k, v in snap.items() if k not in idle for s in v]
     out["any_host"] = round(_union_len(host, t0, t1), 3)
     out["busy"] = round(_union_len(every, t0, t1), 3)
     out["window"] = round(t1 - t0, 3)
@@ -209,7 +238,8 @@ def coverage(t0: float, t1: float) -> Dict[str, float]:
     with _lock:
         snap = {k: list(v) for k, v in _intervals.items()}
     busy = _merged(snap.get("worker_busy", []), t0, t1)
-    fine = [s for k, v in snap.items() if k not in _META for s in v]
+    fine = [s for k, v in snap.items()
+            if k not in _META and k not in _WAITS for s in v]
     tracked = _intersect_len(_merged(fine, t0, t1), busy)
     busy_len = sum(b - a for a, b in busy)
     return {

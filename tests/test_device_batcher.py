@@ -519,3 +519,147 @@ class TestAdaptiveGatherLatency:
             ), sorted(n for n in gauges if "batcher" in n)
         finally:
             server.stop()
+
+
+# ---------------------------------------------------------------------------
+# per-dispatch records (trace/lifecycle's ring), the gather's phase, the
+# scan's scope names
+# ---------------------------------------------------------------------------
+
+DISPATCH_STAMPS = ("t_first_enqueue", "t_start", "t_stack", "t_called",
+                   "t_ready", "t_host", "t_handed")
+
+
+def _dispatches_of(batcher):
+    from nomad_tpu.trace import lifecycle
+
+    return [d for d in lifecycle.dispatch_records()
+            if d["batcher"] == batcher._serial]
+
+
+def _case_record_stamps_monotone_and_steps_padded():
+    batcher = DeviceBatcher(max_batch=4, window_ms=200.0)
+    try:
+        encs = [synthetic_enc(40, 2, 5, seed=1), synthetic_enc(64, 1, 20, seed=2),
+                synthetic_enc(16, 3, 9, seed=3)]
+        run_concurrent(batcher, encs)
+        recs = _dispatches_of(batcher)
+        assert sum(d["b"] for d in recs) == 3
+        for d in recs:
+            stamps = [d[k] for k in DISPATCH_STAMPS]
+            assert stamps == sorted(stamps), d
+            assert 0 < d["steps"] <= d["padded_steps"] == d["b_pad"] * d["p_pad"]
+            assert d["b"] <= d["b_pad"] and d["n_pad"] >= 64
+            assert d["d2h_bytes"] > 0 and d["closed_by"] in (
+                "full", "window", "idle_gap", "demand_drained")
+        assert sum(d["steps"] for d in recs) == 5 + 20 + 9
+        with batcher._lock:
+            stats = dict(batcher.stats)
+        assert stats["steps"] == 34
+        assert stats["padded_steps"] == sum(d["padded_steps"] for d in recs)
+        waves = [d["wave"] for d in recs]
+        assert waves == sorted(set(waves))
+    finally:
+        batcher.stop()
+
+
+def _case_gather_phase_covers_a_held_gather():
+    from nomad_tpu.utils import phases
+
+    batcher = DeviceBatcher(max_batch=4, window_ms=60.0)
+    try:
+        batcher.run(synthetic_enc(8, 1, 1, seed=0))   # compile, thread up
+        phases.enable()
+        t0 = phases.now()
+        batcher.run(synthetic_enc(8, 1, 1, seed=0))
+        shares = phases.wall_shares(t0, phases.now())
+        phases.disable()
+        d = _dispatches_of(batcher)[-1]
+        assert d["closed_by"] == "window" and d["b"] == 1
+        assert d["t_start"] - d["t_first_enqueue"] >= 0.055
+        assert shares["gather"] == pytest.approx(
+            d["t_start"] - d["t_first_enqueue"], abs=0.002)
+        # a wait: named, and neither busy nor host work
+        assert shares["busy"] < shares["gather"]
+        assert shares["any_host"] <= shares["busy"]
+    finally:
+        phases.disable()
+        batcher.stop()
+
+
+def _case_full_gather_closes_at_once():
+    batcher = DeviceBatcher(max_batch=2, window_ms=2000.0)
+    try:
+        run_concurrent(batcher, [synthetic_enc(8, 1, 2, seed=4),
+                                 synthetic_enc(8, 1, 3, seed=5)])
+        d = _dispatches_of(batcher)[-1]
+        assert (d["closed_by"], d["b"], d["b_pad"]) == ("full", 2, 2)
+        assert d["t_start"] - d["t_first_enqueue"] < 1.0
+    finally:
+        batcher.stop()
+
+
+def _case_waits_and_device_brackets_left_out_of_the_unions():
+    from nomad_tpu.utils import phases
+
+    phases.enable()
+    try:
+        t = phases.now()
+        phases.record("no_ready_eval", t, t + 1.0)
+        phases.record("gather", t + 1.0, t + 2.0)
+        phases.record("h2d_launch", t + 2.0, t + 2.1)
+        phases.record("kernel_wait", t + 2.1, t + 2.2)
+        phases.record("d2h", t + 2.2, t + 2.3)
+        phases.record("encode", t + 2.3, t + 2.5)
+        shares = phases.wall_shares(t, t + 3.0)
+    finally:
+        phases.disable()
+    assert shares["no_ready_eval"] == 1.0 and shares["gather"] == 1.0
+    assert shares["busy"] == pytest.approx(0.5)       # three legs + encode
+    assert shares["any_host"] == pytest.approx(0.2)   # encode alone
+    assert shares["untracked"] == pytest.approx(2.5)
+    # disabled: record keeps nothing
+    phases.record("gather", t, t + 9.0)
+    assert phases.wall_shares(t, t + 9.0)["gather"] == 1.0
+
+
+def _case_scan_is_jit_body_and_carries_scope_names():
+    from nomad_tpu.tpu.engine import _build_batched_scan
+
+    enc = synthetic_enc(64, 2, 16, seed=7, dtype=np.float32)
+    stacked = [tuple(np.stack([np.asarray(a)] * 2) for a in part)
+               for part in (enc.static, enc.carry, enc.xs)]
+    lowered = _build_batched_scan().lower(*stacked)
+    # benchmark/harness/scan.py finds the program by this name
+    assert lowered.as_text().splitlines()[0].startswith("module @jit_body")
+    debug = lowered.as_text(debug_info=True)
+    for scope in ("row_select", "feasibility", "affinity", "spread",
+                  "binpack_score", "score_mean", "select", "carry_update"):
+        assert f'loc("{scope}/' in debug, scope
+    # metadata only: no scope name reaches the program text itself
+    assert "binpack_score" not in lowered.as_text()
+
+
+def _case_lone_dispatch_is_recorded_with_the_fields_it_has():
+    from nomad_tpu.trace import lifecycle
+
+    lifecycle.reset()
+    enc = synthetic_enc(16, 1, 4, seed=9)
+    TpuPlacementEngine.shared().run_scan_single(enc)
+    d = lifecycle.dispatch_records()[-1]
+    assert (d["source"], d["b"], d["steps"], d["eval_ids"]) == (
+        "single", 1, 4, [])
+    assert d["t_stack"] <= d["t_called"] <= d["t_host"]
+    assert d["t_ready"] is None and d["t_first_enqueue"] is None
+
+
+@pytest.mark.parametrize("case", [
+    _case_record_stamps_monotone_and_steps_padded,
+    _case_gather_phase_covers_a_held_gather,
+    _case_full_gather_closes_at_once,
+    _case_waits_and_device_brackets_left_out_of_the_unions,
+    _case_scan_is_jit_body_and_carries_scope_names,
+    _case_lone_dispatch_is_recorded_with_the_fields_it_has,
+], ids=lambda f: f.__name__.replace("_case_", ""))
+def test_dispatch_record(case):
+    case()

@@ -1540,6 +1540,96 @@ def test_worker_idle_as_bare_span_still_trips():
 
 
 # ---------------------------------------------------------------------------
+# fixture units — lifecycle.stage / phases.record (one span call per site)
+# ---------------------------------------------------------------------------
+
+_STAGE_HEAD = """
+    from ..trace import lifecycle as _tlc
+    from ..utils import phases as _phases
+"""
+
+STAGE_FIXTURES = {
+    # trace-span-discipline: stage() is a span factory
+    "bare_stage_call_leaks": ("""
+        def encode(sched):
+            _tlc.stage("encode", sched.eval.id)
+            return work(sched)
+    """, ["trace-span-discipline"]),
+    "stored_pipeline_stage_leaks": ("""
+        def encode(sched):
+            cm = _tlc.pipeline_stage("encode", sched.eval.id)
+            cm.__enter__()
+    """, ["trace-span-discipline"]),
+    "with_stage_and_plain_record_are_clean": ("""
+        def dispatch(batch, eval_id):
+            with _tlc.stage("device_wait", eval_id) as waited:
+                run(batch)
+            _phases.record("gather", waited.t0, waited.t1)
+            _tlc.pipeline_record(_tlc.IDLE_STAGE, "worker-0", waited.t0,
+                                 waited.t1)
+    """, []),
+    # pipeline-stage-discipline: one interval, one call
+    "double_bracket_in_one_with": ("""
+        def encode(sched, wave_id):
+            with _phases.track("encode"), _tlc.pipeline_stage("encode", wave_id):
+                return work(sched)
+    """, ["pipeline-stage-discipline"]),
+    "double_bracket_nested": ("""
+        def evaluate(plan):
+            with _tlc.stage("plan_evaluate", plan.eval_id):
+                with _phases.track("plan_evaluate"):
+                    return check(plan)
+    """, ["pipeline-stage-discipline"]),
+    "stage_inside_a_wider_phase_is_clean": ("""
+        def process(ev):
+            with _phases.track("worker_busy"):
+                prepare(ev)
+                with _tlc.stage("snapshot", ev.id):
+                    snap(ev)
+    """, []),
+    # metrics-discipline: span names are a bounded set
+    "dynamic_stage_name": ("""
+        def bracket(kind, eval_id):
+            with _tlc.stage(f"stage-{kind}", eval_id):
+                pass
+    """, ["metrics-discipline"]),
+    "dynamic_record_name": ("""
+        def note(name, t0, t1):
+            _phases.record(name, t0, t1)
+    """, ["metrics-discipline"]),
+}
+
+
+@pytest.mark.parametrize("fixture", sorted(STAGE_FIXTURES))
+def test_span_call_discipline(fixture):
+    body, rules = STAGE_FIXTURES[fixture]
+    fs = run_source(dedent(_STAGE_HEAD) + dedent(body), "tpu/engine.py")
+    assert sorted(f.rule for f in fs) == rules, [f.message for f in fs]
+
+
+def test_no_site_brackets_one_interval_twice():
+    """The tree itself: no module pairs phases.track with a lifecycle
+    span, and the four device_batcher sample timers are gone."""
+    import re
+
+    from nomad_tpu.analysis.core import parse_file
+    from nomad_tpu.analysis.pipeline_stage_discipline import _double_brackets
+
+    for base, _dirs, files in os.walk(PKG):
+        for fn in files:
+            if not fn.endswith(".py"):
+                continue
+            path = os.path.join(base, fn)
+            module, err = parse_file(path, os.path.relpath(path, PKG))
+            assert err is None
+            assert _double_brackets(module) == [], path
+            with open(path) as fh:
+                assert not re.search(
+                    r"nomad\.device_batcher\.(pad_stack|dispatch|compute|transfer)\b",
+                    fh.read()), path
+
+
+# ---------------------------------------------------------------------------
 # fixture units — condition-discipline
 # ---------------------------------------------------------------------------
 
