@@ -396,7 +396,7 @@ def served_run(server, sizes: Sizes, nodes, meter: CompileMeter,
 
 def top_bucket_dispatched(stats: dict, sizes: Sizes) -> bool:
     """Whether some wave was big enough to be padded to the B=device_batch
-    program (DeviceBatcher._pad_and_stack: more than max/4 evals), i.e. the
+    program (DeviceBatcher._bucket: more than max/4 evals), i.e. the
     widest compiled bucket ran. How FULL that wave was is wave fill — a
     property of the host's gather cadence, recorded (max_batch_seen,
     fill_dispatches) but not gated here."""

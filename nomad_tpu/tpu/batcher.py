@@ -6,10 +6,13 @@ setupWorkers, worker.go:244). Host workers still dequeue and run the
 scheduler logic concurrently; when each reaches its placement step it
 submits an ``EncodedEval`` here and blocks. A dispatcher thread gathers the
 requests that arrive within a small window, pads them to shared bucketed
-shapes, stacks them along a leading eval axis and runs the eval-batched
-scan (engine._build_batched_scan) — one device dispatch for the whole
-batch, amortizing host→device transfer and dispatch latency, and sharding
-over the ("evals", "nodes") mesh when one is configured.
+shapes along a leading eval axis and runs the eval-batched scan — one
+device dispatch for the whole batch, amortizing host→device transfer and
+dispatch latency. Unsharded, a dispatch crosses the host-device boundary
+once each way: the evals are written into one flat buffer per dtype
+(tpu/wire.py), the program (engine._build_wire_scan) unpacks them and
+hands back one array. With an ("evals", "nodes") mesh configured the 48
+stacked arrays go up one by one, sharded (engine._build_batched_scan).
 
 Per-eval semantics are untouched: the batched scan vmaps the exact
 single-eval parity scan, so each eval's plan is identical to what the
@@ -29,7 +32,13 @@ import numpy as np
 import itertools
 
 from ..chaos.injector import fire as chaos_fire
-from .engine import EncodedEval, _build_batched_scan, _round_up
+from . import wire
+from .engine import (
+    EncodedEval,
+    _build_batched_scan,
+    _build_wire_scan,
+    _round_up,
+)
 from .intscore import E27_ONE as _E27_NEUTRAL
 from ..trace import lifecycle as _lifecycle
 from ..utils import phases as _phases
@@ -45,6 +54,11 @@ logger = logging.getLogger("nomad_tpu.tpu.batcher")
 _LIVE: "weakref.WeakSet" = weakref.WeakSet()
 # tells one batcher's dispatch records from another's in lifecycle's ring
 _SERIAL = itertools.count(1)
+# how many layouts' host buffers the dispatcher keeps for reuse (the least
+# recently used goes first): a served cluster cycles through a handful —
+# step buckets x batch buckets — and a 64-wide bucket of full-wave evals
+# is ~130 MB of them
+_WIRE_BUFS_KEPT = 8
 
 
 def shutdown_all() -> None:
@@ -299,6 +313,11 @@ class DeviceBatcher:
         )
         self._scan = None
         self._scan_lock = witness_lock("batcher.DeviceBatcher._scan_lock")  # prewarm + dispatcher race
+        # (shape key, b_pad) -> WireLayout, shared with the prewarm threads
+        # (guarded-by: _lock); layout -> the DISPATCHER's host buffers,
+        # touched by that one thread only
+        self._layouts: Dict[tuple, wire.WireLayout] = {}
+        self._wire_bufs: Dict[wire.WireLayout, wire.WireBuffers] = {}
         # padded-shape key -> set of batch buckets already compiled/warming
         self._warmed: Dict[tuple, set] = {}
         self._warm_threads: List[threading.Thread] = []
@@ -329,15 +348,24 @@ class DeviceBatcher:
             # scheduling latency
             "gather_wait_ms_total": 0.0,
             "gather_wait_ms_max": 0.0,
-            # per-dispatch timing split: host pad/stack vs compute (scan
-            # call + block_until_ready: H2D, launch and kernel) vs D2H
-            # transfer (np.asarray). Totals of the stamps each dispatch's
-            # record keeps (trace/lifecycle.on_dispatch), which splits
-            # compute further and feeds dispatch_profile()
+            # per-dispatch timing split: host pad/stack (the evals written
+            # into the wire buffers) vs compute (the call, then the one
+            # wait for the output on the host: H2D, launch, kernel and the
+            # output's copy down) vs transfer (the one host array split
+            # into the five results; on the mesh path compute ends at a
+            # fence and transfer is five copies down). Totals of the
+            # stamps each dispatch's record keeps (trace/lifecycle
+            # .on_dispatch), which splits compute further and feeds
+            # dispatch_profile()
             "pad_stack_ms_total": 0.0,
             "compute_ms_total": 0.0,
             "transfer_ms_total": 0.0,
             "d2h_bytes_total": 0,
+            # how many arrays crossed the host-device boundary: one per
+            # dtype group up (2-4) and one down a dispatch on the wire
+            # path, 48 and 5 on the mesh path
+            "h2d_arrays_total": 0,
+            "d2h_arrays_total": 0,
             # placement steps the evals asked for against the steps the
             # padded batch ran (b_pad x p_pad): what the buckets pad
             "steps": 0,
@@ -537,6 +565,8 @@ class DeviceBatcher:
             )
             with self._lock:
                 self.stats["batch_fallbacks"] += 1
+            # an upload of the failed dispatch may still be reading them
+            self._wire_bufs.clear()
             from .engine import TpuPlacementEngine
 
             engine = TpuPlacementEngine.shared()
@@ -549,8 +579,10 @@ class DeviceBatcher:
                 req.event.set()
 
     def _scan_fn(self):
-        """The ONE batched-scan builder (engine._build_batched_scan),
-        sharded over the configured mesh when present. Double-checked
+        """The program a dispatch runs: the wire scan
+        (engine._build_wire_scan: packed buffers in, one array out), or,
+        with a mesh configured, the unpacked batched scan
+        (engine._build_batched_scan) sharded over it. Double-checked
         lock: the prewarm thread and the dispatcher both initialize
         lazily, and losing a duplicate build would orphan the loser's
         jit compile cache."""
@@ -558,12 +590,13 @@ class DeviceBatcher:
         if scan is None:
             with self._scan_lock:
                 if self._scan is None:
-                    shardings = None
                     if self.mesh is not None:
                         from ..parallel.sharding import batched_scan_shardings
 
-                        shardings = batched_scan_shardings(self.mesh)
-                    self._scan = _build_batched_scan(in_shardings=shardings)
+                        self._scan = _build_batched_scan(
+                            in_shardings=batched_scan_shardings(self.mesh))
+                    else:
+                        self._scan = _build_wire_scan()
                 scan = self._scan
         return scan
 
@@ -579,17 +612,25 @@ class DeviceBatcher:
             out = sorted({((b + ep - 1) // ep) * ep for b in out})
         return out
 
-    def _prewarm_siblings(self, one_padded, current_b_pad: int) -> None:
+    def _layout(self, key: tuple, b_pad: int,
+                dims: Dict[str, int]) -> wire.WireLayout:
+        with self._lock:
+            layout = self._layouts.get((key, b_pad))
+            if layout is None:
+                layout = self._layouts[(key, b_pad)] = wire.WireLayout(
+                    key, b_pad, dims)
+        return layout
+
+    def _prewarm_siblings(self, shape_key: tuple, current_b_pad: int,
+                          call_at) -> None:
         """First sight of a padded shape: compile its OTHER batch buckets
-        on a background thread by calling the scan with stacked inert
-        copies. The persistent XLA cache makes repeats across restarts
-        cheap, but even a cache HIT load is seconds — hide it off the
-        dispatch path. Device time for the warming calls interleaves with
-        real dispatches at the runtime's discretion; correctness is
-        unaffected (results discarded)."""
-        shape_key = tuple(
-            (a.shape, str(a.dtype)) for part in one_padded for a in part
-        )
+        on a background thread by running the scan on inert copies of one
+        eval (``call_at(b)`` runs it at bucket ``b``). The persistent XLA
+        cache makes repeats across restarts cheap, but even a cache HIT
+        load is seconds — hide it off the dispatch path. Device time for
+        the warming calls interleaves with real dispatches at the
+        runtime's discretion; correctness is unaffected (results
+        discarded)."""
         with self._lock:
             warmed = self._warmed.setdefault(shape_key, set())
             todo = [
@@ -604,15 +645,7 @@ class DeviceBatcher:
         def warm() -> None:
             for b in todo:
                 try:
-                    stacked = tuple(
-                        tuple(
-                            np.stack([part[i]] * b)
-                            for i in range(len(part))
-                        )
-                        for part in one_padded
-                    )
-                    scan = self._scan_fn()
-                    np.asarray(scan(*stacked)[1][0])
+                    call_at(b)
                 except Exception:  # noqa: BLE001 — warming never fails a
                     # dispatch: the bucket compiles on its first real use
                     logger.warning("bucket prewarm failed (b=%d)", b,
@@ -650,11 +683,15 @@ class DeviceBatcher:
         """Per-dispatch timing split + a roofline note for the batched
         placement scan, from this batcher's dispatch records in
         trace/lifecycle's ring (the last 4,096): where does a dispatch's
-        wall time go (host pad/stack vs H2D + launch vs kernel wait vs
-        D2H), and what D2H bandwidth does the transfer leg sustain? The
-        note names the binding leg so four-rounds-flat throughput
-        plateaus read as "kernel wait-bound at X ms/dispatch" instead of
-        a bare number."""
+        wall time go — host pad/stack (the evals written into the wire
+        buffers), H2D + launch (the call: one upload per dtype group),
+        kernel wait (until the ONE output array is on the host: the
+        kernel and its copy down) and transfer (D2H: that one array split
+        into the five results, host work only; on the mesh path five
+        copies down after a fence) — and how many arrays cross the
+        boundary each way? The note names the binding leg so
+        four-rounds-flat throughput plateaus read as "kernel wait-bound at
+        X ms/dispatch" instead of a bare number."""
         with self._lock:
             s = dict(self.stats)
         recs = [r for r in _lifecycle.dispatch_records()
@@ -672,7 +709,12 @@ class DeviceBatcher:
         kernel = avg_ms("t_called", "t_ready")
         xfer = avg_ms("t_ready", "t_host")
         d2h_bytes = sum(r["d2h_bytes"] for r in recs)
-        gbps = d2h_bytes / (xfer * n / 1e3) / 1e9 if xfer > 0 else 0.0
+        # the copy down ends inside the kernel-wait leg on the wire path,
+        # so the rate is over both legs: a floor, not the link's
+        gbps = d2h_bytes / ((kernel + xfer) * n / 1e3) / 1e9 \
+            if kernel + xfer > 0 else 0.0
+        h2d_arrays = sum(r["h2d_arrays"] for r in recs) / n
+        d2h_arrays = sum(r["d2h_arrays"] for r in recs) / n
         legs = {"pad/stack (host)": pad, "H2D + launch": launch,
                 "kernel wait (device)": kernel, "transfer (D2H)": xfer}
         bound = max(legs, key=legs.get)
@@ -680,9 +722,10 @@ class DeviceBatcher:
         evals = sum(r["b"] for r in recs)
         note = (
             f"{bound}-bound: {legs[bound]:.2f}ms of {total:.2f}ms per "
-            f"dispatch (pad/stack {pad:.2f}ms, H2D + launch {launch:.2f}ms, "
-            f"kernel wait {kernel:.2f}ms, transfer {xfer:.2f}ms at "
-            f"{gbps:.2f} GB/s D2H, {evals / n:.1f} evals/dispatch)"
+            f"dispatch (pad/stack {pad:.2f}ms, H2D + launch {launch:.2f}ms "
+            f"in {h2d_arrays:.1f} arrays, kernel wait {kernel:.2f}ms, "
+            f"transfer {xfer:.2f}ms in {d2h_arrays:.1f} arrays, "
+            f"{gbps:.4f} GB/s D2H, {evals / n:.1f} evals/dispatch)"
         )
         return {
             "dispatches": s["dispatches"],
@@ -694,31 +737,25 @@ class DeviceBatcher:
             "compute_ms_avg": round(launch + kernel, 3),
             "transfer_ms_avg": round(xfer, 3),
             "d2h_bytes_total": s["d2h_bytes_total"],
-            "d2h_gbps": round(gbps, 3),
+            "d2h_gbps": round(gbps, 6),
+            "h2d_arrays_avg": round(h2d_arrays, 2),
+            "d2h_arrays_avg": round(d2h_arrays, 2),
             "useful_steps_pct": round(
                 100.0 * sum(r["steps"] for r in recs)
                 / max(1, sum(r["padded_steps"] for r in recs)), 2),
             "note": note,
         }
 
-    def _run_batch(self, batch: List[_Request],
-                   closed_by: Optional[str] = None) -> None:
-        import jax
-
-        annotate = jax.profiler.TraceAnnotation
-        wave = _lifecycle.next_wave()
-        t_start = _phases.now()
-        encs = [r.enc for r in batch]
-        # shared bucketed dims (pow2 to bound recompiles); G always gets a
-        # padded slot so padded steps have a pre-failed TG to point at
-        n_pad = max(_round_up(e.n_real) for e in encs)
+    @staticmethod
+    def _batch_dims(encs: List[EncodedEval]) -> Dict[str, int]:
+        """The batch's shared bucketed dims (pad_encoded's keywords)."""
+        # pow2 buckets bound recompiles; G always gets a padded slot so
+        # padded steps have a pre-failed TG to point at
         g_pad = _pow2ceil(max(e.g for e in encs) + 1)
         # S stays ZERO when no co-batched eval has spreads (the
         # compiled step skips the whole spread machinery); mixed
         # batches widen — same pattern as the affinity axis
         s_raw = max(e.s for e in encs)
-        s_pad = _pow2ceil(s_raw) if s_raw else 0
-        v_pad = _pow2ceil(max(max(e.v for e in encs), 2))
         # COARSE placement-count buckets (16/64/256/1024, pow2 beyond):
         # retried partial evals arrive at arbitrary small p, and a fresh
         # compile (even a persistent-cache load) per pow2 bucket costs
@@ -727,63 +764,113 @@ class DeviceBatcher:
         # few hundred placements must ride the wave cohort's warm 1024
         # bucket, not stall the dispatcher on a fresh 512 compile.
         p_raw = max(e.p for e in encs)
-        p_pad = (
-            16 if p_raw <= 16 else 64 if p_raw <= 64
-            else 256 if p_raw <= 256 else 1024 if p_raw <= 1024
-            else _pow2ceil(p_raw)
-        )
         d_pad = max(e.static[0].shape[1] for e in encs)
         # absent-feature axes stay ZERO when the whole batch lacks them
         # (the compiled step skips those ops); mixed batches widen
-        k_pad = max(e.xs[1].shape[1] for e in encs)
         aff_raw = max(e.static[4].shape[0] for e in encs)
-        aff_pad = g_pad if aff_raw else 0
         evd_raw = max(e.xs[3].shape[1] for e in encs)
-        evd_pad = d_pad if evd_raw else 0
-        fac_pad = max(e.xs[7].shape[1] for e in encs)
-        dpd_pad = max(e.static[17].shape[0] for e in encs)
-        dpv_pad = max(e.carry[8].shape[1] for e in encs)
-        fnd_pad = max(e.xs[9].shape[1] for e in encs)
         # preemption candidate axis: zero when no co-batched eval preempts
         prec_raw = max(e.static[20].shape[1] for e in encs)
         prec_pad = _pow2ceil(prec_raw) if prec_raw else 0
-        pregp_pad = (
-            _pow2ceil(max(max(e.carry[11].shape[0] for e in encs), 1))
-            if prec_pad else 0
-        )
-        dtype = encs[0].dtype  # dispatch loop groups by dtype
+        return {
+            "n_pad": max(_round_up(e.n_real) for e in encs),
+            "g_pad": g_pad,
+            "s_pad": _pow2ceil(s_raw) if s_raw else 0,
+            "v_pad": _pow2ceil(max(max(e.v for e in encs), 2)),
+            "p_pad": (
+                16 if p_raw <= 16 else 64 if p_raw <= 64
+                else 256 if p_raw <= 256 else 1024 if p_raw <= 1024
+                else _pow2ceil(p_raw)
+            ),
+            "d_pad": d_pad,
+            "k_pad": max(e.xs[1].shape[1] for e in encs),
+            "aff_pad": g_pad if aff_raw else 0,
+            "evd_pad": d_pad if evd_raw else 0,
+            "fac_pad": max(e.xs[7].shape[1] for e in encs),
+            "dpd_pad": max(e.static[17].shape[0] for e in encs),
+            "dpv_pad": max(e.carry[8].shape[1] for e in encs),
+            "fnd_pad": max(e.xs[9].shape[1] for e in encs),
+            "prec_pad": prec_pad,
+            "pregp_pad": (
+                _pow2ceil(max(max(e.carry[11].shape[0] for e in encs), 1))
+                if prec_pad else 0
+            ),
+        }
 
-        # each leg is bracketed once: a TraceAnnotation so the profiler's
-        # trace carries the dispatch above the device line, by wave, and
-        # stamps on phases.now's clock, which become this dispatch's
-        # record and its phases (pad_stack, h2d_launch, kernel_wait, d2h)
+    def _bucket(self, b: int) -> int:
+        """Three batch buckets — 1, max/4, max. Unrestricted pow2 buckets
+        each cost a tens-of-seconds XLA compile; but padding every small
+        batch to max wastes real device time (per-step cost grows with
+        the batch axis). Compiles are amortized by the persistent cache."""
+        mid = max(1, self.max_batch // 4)
+        return 1 if b == 1 else (mid if b <= mid else self.max_batch)
+
+    def _run_batch(self, batch: List[_Request],
+                   closed_by: Optional[str] = None) -> None:
+        """One dispatch, bracketed leg by leg: a TraceAnnotation each, so
+        the profiler's trace carries the dispatch above the device line,
+        by wave, and stamps on phases.now's clock, which become this
+        dispatch's record and its phases:
+
+        ``t_start`` → ``t_stack`` (pad_stack): the evals written, padded,
+        into the wire buffers. → ``t_called`` (h2d_launch): the call — one
+        upload per dtype group and the launch. → ``t_ready``
+        (kernel_wait): the ONE wait, reading the output array, so it
+        covers the kernel and the copy down (starting that copy when the
+        call returns, ``copy_to_host_async``, bought nothing on the v5e:
+        7.857 against 7.856 ms a lone dispatch). → ``t_host`` (d2h): that
+        array split into the five results, host work only. →
+        ``t_handed``: every worker released. On the mesh path ``t_ready``
+        is a fence on the device's outputs and d2h is five copies down."""
+        import jax
+
+        annotate = jax.profiler.TraceAnnotation
+        wave = _lifecycle.next_wave()
+        t_start = _phases.now()
+        encs = [r.enc for r in batch]
+        dims = self._batch_dims(encs)
+        dtype = encs[0].dtype  # dispatch loop groups by dtype
+        b = len(encs)
+        b_pad = self._bucket(b)
+        p_pad = dims["p_pad"]
+
         with annotate("nomad.pad_stack", wave=wave):
-            static_b, carry_b, xs_b, b, b_pad = self._pad_and_stack(
-                encs, n_pad, g_pad, s_pad, v_pad, p_pad, dtype, d_pad,
-                k_pad, aff_pad, evd_pad, fac_pad, dpd_pad, dpv_pad, fnd_pad,
-                prec_pad, pregp_pad,
-            )
+            if self.mesh is not None:
+                # ROADMAP D9: the mesh shards arrays by their node axis
+                # (parallel/sharding.py's positional specs), which a flat
+                # buffer does not have: this path keeps the 48 stacked
+                # arrays up, a fence, and five copies down
+                args, b_pad, n_pad = self._pad_and_stack(
+                    encs, dims, dtype, b_pad)
+                h2d_arrays, d2h_arrays = sum(len(part) for part in args), 5
+            else:
+                bufs = self._pack(encs, dims, dtype, b_pad)
+                args = (bufs.layout,) + bufs.arrays
+                n_pad = bufs.layout.n_pad
+                h2d_arrays, d2h_arrays = len(bufs.arrays), 1
             scan = self._scan_fn()
         t_stack = _phases.now()
         with annotate("nomad.h2d_launch", wave=wave):
-            _carry, (chosen, scores, pulls, skipped, evict) = scan(
-                static_b, carry_b, xs_b)
+            out = scan(*args)
         t_called = _phases.now()
         with annotate("nomad.kernel_wait", wave=wave):
-            # the fence: np.asarray below then times ONLY the D2H copy
-            jax.block_until_ready((chosen, scores, pulls, skipped, evict))
+            if self.mesh is None:
+                host = np.asarray(out)
+            else:
+                # the fence: np.asarray below then times ONLY the D2H copy
+                out = jax.block_until_ready(out[1])
         t_ready = _phases.now()
         with annotate("nomad.d2h", wave=wave):
-            chosen = np.asarray(chosen)
-            scores = np.asarray(scores)
-            pulls = np.asarray(pulls)
-            skipped = np.asarray(skipped)
-            evict = np.asarray(evict)
+            if self.mesh is None:
+                d2h_bytes = host.nbytes
+                chosen, scores, pulls, skipped, evict = wire.split_outputs(
+                    bufs.layout, host)
+            else:
+                chosen, scores, pulls, skipped, evict = (
+                    np.asarray(a) for a in out)
+                d2h_bytes = (chosen.nbytes + scores.nbytes + pulls.nbytes
+                             + skipped.nbytes + evict.nbytes)
         t_host = _phases.now()
-        d2h_bytes = (
-            chosen.nbytes + scores.nbytes + pulls.nbytes + skipped.nbytes
-            + evict.nbytes
-        )
         steps = sum(e.p for e in encs)
         padded_steps = b_pad * p_pad
         t_first_enqueue = min(r.t_enqueue for r in batch)
@@ -797,6 +884,8 @@ class DeviceBatcher:
             self.stats["compute_ms_total"] += (t_ready - t_stack) * 1000.0
             self.stats["transfer_ms_total"] += (t_host - t_ready) * 1000.0
             self.stats["d2h_bytes_total"] += d2h_bytes
+            self.stats["h2d_arrays_total"] += h2d_arrays
+            self.stats["d2h_arrays_total"] += d2h_arrays
             self.stats["steps"] += steps
             self.stats["padded_steps"] += padded_steps
             for req in batch:
@@ -820,10 +909,11 @@ class DeviceBatcher:
             wave=wave, source="batcher", batcher=self._serial,
             eval_ids=[r.eval_id for r in batch if r.eval_id is not None],
             b=b, b_pad=b_pad, p_pad=p_pad,
-            # the mesh may have widened the node axis past n_pad
-            n_pad=int(static_b[0].shape[1]), steps=steps,
+            # the mesh may have widened the node axis past the batch's
+            n_pad=n_pad, steps=steps,
             padded_steps=padded_steps, closed_by=closed_by,
-            d2h_bytes=d2h_bytes, t_first_enqueue=t_first_enqueue,
+            d2h_bytes=d2h_bytes, h2d_arrays=h2d_arrays,
+            d2h_arrays=d2h_arrays, t_first_enqueue=t_first_enqueue,
             t_start=t_start, t_stack=t_stack, t_called=t_called,
             t_ready=t_ready, t_host=t_host, t_handed=t_handed,
         )
@@ -833,54 +923,65 @@ class DeviceBatcher:
         _phases.record("kernel_wait", t_called, t_ready)
         _phases.record("d2h", t_ready, t_host)
 
-    def _pad_and_stack(self, encs, n_pad, g_pad, s_pad, v_pad, p_pad, dtype,
-                       d_pad, k_pad, aff_pad, evd_pad, fac_pad, dpd_pad,
-                       dpv_pad, fnd_pad, prec_pad=0, pregp_pad=0):
-        padded = [
-            pad_encoded(e, n_pad, g_pad, s_pad, v_pad, p_pad, dtype, d_pad,
-                        k_pad, aff_pad, evd_pad, fac_pad, dpd_pad, dpv_pad,
-                        fnd_pad, prec_pad, pregp_pad)
-            for e in encs
-        ]
+    def _pack(self, encs: List[EncodedEval], dims: Dict[str, int], dtype,
+              b_pad: int) -> wire.WireBuffers:
+        """Write the batch into the dispatcher's buffers of its layout:
+        one pass, each array straight to its padded slot. The buffers are
+        reused from dispatch to dispatch: this thread is the only one that
+        touches them, and it packs the next batch only after the ONE wait
+        on the previous output, which the device produces after it has
+        read every upload. A dispatch that raises forgets its buffers
+        (``_run_batch_safe``), so none is rewritten with a copy in flight."""
+        key = wire.shape_key(encs[0], dims, dtype)
+        layout = self._layout(key, b_pad, dims)
+        bufs = self._wire_bufs.pop(layout, None)
+        if bufs is None:
+            bufs = wire.WireBuffers(layout)
+            while len(self._wire_bufs) >= _WIRE_BUFS_KEPT:
+                del self._wire_bufs[next(iter(self._wire_bufs))]
+        self._wire_bufs[layout] = bufs  # most recently used last
+        wire.pack(bufs, encs)
 
-        b = len(padded)
-        # Three batch buckets — 1, max/4, max. Unrestricted pow2 buckets
-        # each cost a tens-of-seconds XLA compile; but padding every small
-        # batch to max wastes real device time (per-step cost grows with
-        # the batch axis). Compiles are amortized by the persistent cache.
-        mid = max(1, self.max_batch // 4)
-        b_pad = 1 if b == 1 else (mid if b <= mid else self.max_batch)
-        if self.mesh is not None:
-            ep = self.mesh.shape.get("evals", 1)
-            b_pad = ((b_pad + ep - 1) // ep) * ep
-            nn = self.mesh.shape.get("nodes", 1)
-            n_pad2 = ((n_pad + nn - 1) // nn) * nn
-            if n_pad2 != n_pad:
-                padded = [
-                    pad_encoded(e, n_pad2, g_pad, s_pad, v_pad, p_pad, dtype,
-                                d_pad, k_pad, aff_pad, evd_pad, fac_pad,
-                                dpd_pad, dpv_pad, fnd_pad, prec_pad, pregp_pad)
-                    for e in encs
-                ]
-                n_pad = n_pad2
+        def call_at(b: int, enc=encs[0]) -> None:
+            # the warm thread's own buffers: the dispatcher's are in use
+            sibling = wire.WireBuffers(self._layout(key, b, dims))
+            wire.pack(sibling, [enc])
+            np.asarray(self._scan_fn()(sibling.layout, *sibling.arrays))
+
         # Warm the SIBLING batch buckets of this shape in the background
-        # (precompile pinned buckets): the first dispatch
-        # of a new shape pays its own compile/cache-load synchronously,
-        # but the follow-up waves (smaller tails, single-eval retries)
-        # must not stall multi-second on theirs. One zero-input call per
-        # bucket populates the jit executable cache off the hot path.
-        self._prewarm_siblings(padded[0], b_pad)
+        # (precompile pinned buckets): the first dispatch of a new shape
+        # pays its own compile/cache-load synchronously, but the
+        # follow-up waves (smaller tails, single-eval retries) must not
+        # stall multi-second on theirs.
+        self._prewarm_siblings(key, b_pad, call_at)
+        return bufs
+
+    def _pad_and_stack(self, encs: List[EncodedEval], dims: Dict[str, int],
+                       dtype, b_pad: int):
+        """The mesh path's host side: every eval padded (pad_encoded), the
+        batch stacked array by array along a leading eval axis rounded to
+        the mesh's eval axis, the node axis to its node axis."""
+        ep = self.mesh.shape.get("evals", 1)
+        b_pad = ((b_pad + ep - 1) // ep) * ep
+        nn = self.mesh.shape.get("nodes", 1)
+        dims = dict(dims, n_pad=((dims["n_pad"] + nn - 1) // nn) * nn)
+        padded = [pad_encoded(e, dtype=dtype, **dims) for e in encs]
+        one = padded[0]
+
+        def call_at(b: int) -> None:
+            stacked = tuple(tuple(np.stack([a] * b) for a in part)
+                            for part in one)
+            np.asarray(self._scan_fn()(*stacked)[1][0])
+
+        self._prewarm_siblings(
+            tuple((a.shape, str(a.dtype)) for part in one for a in part),
+            b_pad, call_at)
 
         while len(padded) < b_pad:
-            padded.append(padded[0])  # inert copies; results discarded
-
-        static_b = tuple(
-            np.stack([p[0][i] for p in padded]) for i in range(len(padded[0][0]))
+            padded.append(one)  # inert copies; results discarded
+        stacked = tuple(
+            tuple(np.stack([p[part][i] for p in padded])
+                  for i in range(len(one[part])))
+            for part in range(3)
         )
-        carry_b = tuple(
-            np.stack([p[1][i] for p in padded]) for i in range(len(padded[0][1]))
-        )
-        xs_b = tuple(
-            np.stack([p[2][i] for p in padded]) for i in range(len(padded[0][2]))
-        )
-        return static_b, carry_b, xs_b, b, b_pad
+        return stacked, b_pad, dims["n_pad"]

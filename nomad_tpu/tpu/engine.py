@@ -1028,6 +1028,21 @@ def _build_forced_kernel():
     return jax.jit(forced_eval)
 
 
+def _batched_scan_fn():
+    """The eval-batched scan, not yet jitted: vmap of the per-eval parity
+    scan over a leading batch axis (the one body both programs below
+    compile)."""
+    import jax
+    import jax.lax as lax
+
+    step = _make_step()
+
+    def one(static, carry, xs):
+        return lax.scan(lambda c, x: step(static, c, x), carry, xs)
+
+    return jax.vmap(one)
+
+
 def _build_batched_scan(in_shardings=None):
     """Eval-batched scan: vmap the per-eval scan over a leading batch axis.
 
@@ -1040,25 +1055,46 @@ def _build_batched_scan(in_shardings=None):
 
     ``in_shardings``: optional (static, carry, xs) NamedSharding tuples
     (parallel.sharding.batched_scan_shardings) to shard the dispatch over
-    an ("evals", "nodes") mesh — the ONE builder both the unsharded and
-    mesh production paths share."""
+    an ("evals", "nodes") mesh. This entry takes the 48 stacked arrays one
+    by one: the mesh path's, bench.py's and chip_smoke.py's. The unsharded
+    batcher dispatches ``_build_wire_scan``'s program over the same body."""
     import jax
 
     jax.config.update("jax_enable_x64", True)
     _enable_persistent_compile_cache()
-    step = _make_step()
+    batched = _batched_scan_fn()
 
     def body(static_b, carry_b, xs_b):
-        import jax.lax as lax
-
-        def one(static, carry, xs):
-            return lax.scan(lambda c, x: step(static, c, x), carry, xs)
-
-        return jax.vmap(one)(static_b, carry_b, xs_b)
+        return batched(static_b, carry_b, xs_b)
 
     if in_shardings is not None:
         return jax.jit(body, in_shardings=in_shardings)
     return jax.jit(body)
+
+
+def _build_wire_scan():
+    """The batched scan behind the wire layout (tpu/wire.py): the program
+    takes one flat buffer per dtype, slices the 48 fields out of them by
+    the layout (a static argument: one compile per layout), runs the same
+    vmapped scan, and returns ONE int32 array holding chosen, scores,
+    pulls, skipped and evict in bit-exact lanes. The final carry, which no
+    caller of the batcher reads, is not returned. Still jitted as ``body``:
+    benchmark/harness/scan.py finds the scan by ``jit_body``."""
+    import jax
+    import jax.numpy as jnp
+
+    from . import wire
+
+    jax.config.update("jax_enable_x64", True)
+    _enable_persistent_compile_cache()
+    batched = _batched_scan_fn()
+
+    def body(layout, *buffers):
+        static_b, carry_b, xs_b = wire.unpack(layout, buffers, jnp)
+        _carry, outs = batched(static_b, carry_b, xs_b)
+        return wire.pack_outputs(layout, *outs)
+
+    return jax.jit(body, static_argnums=0)
 
 
 class _ResourceAssigner:

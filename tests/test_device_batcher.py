@@ -19,11 +19,15 @@ from nomad_tpu import mock
 from nomad_tpu.scheduler.testing import Harness
 from nomad_tpu.structs.structs import (
     EVAL_TRIGGER_JOB_REGISTER,
+    Affinity,
+    Constraint,
     Evaluation,
+    PreemptionConfig,
     SchedulerConfiguration,
     Spread,
     SpreadTarget,
 )
+from nomad_tpu.tpu import wire
 from nomad_tpu.tpu.batcher import DeviceBatcher, pad_encoded, _pow2ceil
 from nomad_tpu.tpu.engine import (
     EncodedEval,
@@ -257,8 +261,8 @@ class TestBatchedScanParity:
         counted and logged at warning, never silently."""
         good = synthetic_enc(16, 1, 2, seed=0)
         bad = synthetic_enc(16, 1, 2, seed=1)
-        # corrupt one eval so the stacked dispatch raises (shape mismatch
-        # at np.stack time inside _run_batch)
+        # corrupt one eval so the batched dispatch raises (the packer
+        # counts its arrays inside _run_batch)
         bad.static = bad.static[:-1]  # drop n_real -> unzips wrong
         batcher = DeviceBatcher(max_batch=2, window_ms=200.0)
         try:
@@ -292,10 +296,10 @@ class TestBatchedScanParity:
         batcher = DeviceBatcher(max_batch=4, window_ms=5.0)
         real_scan = batcher._scan_fn()
 
-        def scan(static_b, carry_b, xs_b):
-            if static_b[0].shape[0] != 1:  # every sibling bucket (b=4)
+        def scan(layout, *buffers):
+            if layout.b_pad != 1:  # every sibling bucket (b=4)
                 raise RuntimeError("compile refused")
-            return real_scan(static_b, carry_b, xs_b)
+            return real_scan(layout, *buffers)
 
         batcher._scan = scan
         try:
@@ -653,7 +657,85 @@ def _case_lone_dispatch_is_recorded_with_the_fields_it_has():
     assert d["t_ready"] is None and d["t_first_enqueue"] is None
 
 
+def _case_a_dispatch_crosses_the_boundary_once_each_way():
+    """Unsharded: one upload per dtype group, ONE array down, all six
+    stamps set and in order, and the counts in the record, in ``stats``
+    and in ``dispatch_profile()``."""
+    batcher = DeviceBatcher(max_batch=2, window_ms=200.0)
+    try:
+        run_concurrent(batcher, [synthetic_enc(24, 2, 5, seed=1, dtype=dt)
+                                 for dt in (np.int32, np.float32)])
+        run_concurrent(batcher, [synthetic_enc(24, 2, 5, seed=s)
+                                 for s in (2, 3)])
+        recs = _dispatches_of(batcher)
+        assert [d["b"] for d in recs] == [1, 1, 2]
+        for d in recs:
+            assert 2 <= d["h2d_arrays"] <= 4 and d["d2h_arrays"] == 1, d
+            stamps = [d[k] for k in DISPATCH_STAMPS]
+            assert None not in stamps and stamps == sorted(stamps), d
+            assert d["p_pad"] == 16
+        # int32 mode: int32 + uint8; float modes add the float group
+        assert sorted(d["h2d_arrays"] for d in recs) == [2, 3, 3]
+        # the one array down: chosen, pulls, skipped and the scores in
+        # int32 lanes (one for float32, two for int64), or, for the
+        # float64 pair, four float64 lanes
+        assert sorted(d["d2h_bytes"] for d in recs) == [
+            16 * 4 * 4, 16 * 5 * 4, 2 * 16 * 4 * 8]
+        with batcher._lock:
+            stats = dict(batcher.stats)
+        assert stats["h2d_arrays_total"] == 8 and stats["d2h_arrays_total"] == 3
+        assert stats["d2h_bytes_total"] == sum(d["d2h_bytes"] for d in recs)
+        prof = batcher.dispatch_profile()
+        assert prof["d2h_arrays_avg"] == 1.0
+        assert prof["h2d_arrays_avg"] == pytest.approx(8 / 3, abs=0.01)
+        assert "in 2.7 arrays" in prof["note"] and "in 1.0 arrays" in prof["note"]
+    finally:
+        batcher.stop()
+
+
+def _case_the_mesh_path_still_sends_48_up_and_five_down():
+    import jax
+
+    if len(jax.devices()) < 4:
+        pytest.skip("needs >=4 devices")
+    from nomad_tpu.parallel import make_mesh
+
+    batcher = DeviceBatcher(max_batch=4, window_ms=200.0,
+                            mesh=make_mesh(4, eval_parallel=2))
+    try:
+        run_concurrent(batcher, [synthetic_enc(32, 2, 6, seed=s)
+                                 for s in (11, 12)])
+        (d,) = _dispatches_of(batcher)
+        assert (d["h2d_arrays"], d["d2h_arrays"]) == (48, 5)
+        stamps = [d[k] for k in DISPATCH_STAMPS]
+        assert None not in stamps and stamps == sorted(stamps)
+        assert batcher.dispatch_profile()["h2d_arrays_avg"] == 48.0
+    finally:
+        batcher.stop()
+
+
+def _case_the_wire_scan_is_jit_body_too():
+    from nomad_tpu.tpu import wire
+    from nomad_tpu.tpu.engine import _build_wire_scan
+
+    enc = synthetic_enc(64, 2, 16, seed=7, dtype=np.float32)
+    dims = DeviceBatcher._batch_dims([enc])
+    layout = wire.WireLayout(wire.shape_key(enc, dims, enc.dtype), 2, dims)
+    bufs = wire.WireBuffers(layout)
+    lowered = _build_wire_scan().lower(layout, *bufs.arrays)
+    # benchmark/harness/scan.py finds the program by this name
+    assert lowered.as_text().splitlines()[0].startswith("module @jit_body")
+    assert 'loc("binpack_score/' in lowered.as_text(debug_info=True)
+    # one flat buffer per dtype in, one int32 array out
+    assert len(lowered.in_avals[0]) == len(layout.groups) == 3
+    out = lowered.out_info
+    assert out.dtype == np.int32 and out.shape == (2, 16 * 4)
+
+
 @pytest.mark.parametrize("case", [
+    _case_a_dispatch_crosses_the_boundary_once_each_way,
+    _case_the_mesh_path_still_sends_48_up_and_five_down,
+    _case_the_wire_scan_is_jit_body_too,
     _case_record_stamps_monotone_and_steps_padded,
     _case_gather_phase_covers_a_held_gather,
     _case_full_gather_closes_at_once,
@@ -663,3 +745,213 @@ def _case_lone_dispatch_is_recorded_with_the_fields_it_has():
 ], ids=lambda f: f.__name__.replace("_case_", ""))
 def test_dispatch_record(case):
     case()
+
+
+# ---------------------------------------------------------------------------
+# the wire layout (tpu/wire.py): packer + unpacker against pad_encoded +
+# np.stack, and run() against the single-eval scan, over synthetic evals
+# and evals the real encoder made for every axis a batch can widen
+# ---------------------------------------------------------------------------
+
+
+class _Recorder(DeviceBatcher):
+    """A batcher that keeps every eval the scheduler hands it."""
+
+    def __init__(self) -> None:
+        super().__init__(max_batch=1)
+        self.seen = []
+
+    def run(self, enc, expected=False):
+        self.seen.append(enc)
+        return super().run(enc, expected)
+
+
+def _encoded_by_the_scheduler():
+    """{name: EncodedEval} from the real encoder, in the exact integer
+    spec (``int32-*``) and the float32 throughput mode (``float32-*``):
+    a plain job, a spread, an affinity, a distinct_property constraint, a
+    destructive update (eviction steps), and a system job that preempts
+    (candidate tables, int64 ``pre_remaining``)."""
+    rec = _Recorder()
+    out = {}
+
+    def harness(nodes, preemption=False):
+        h = Harness()
+        h.device_batcher = rec
+        h.state.scheduler_set_config(h.next_index(), SchedulerConfiguration(
+            scheduler_algorithm="tpu_binpack",
+            preemption_config=PreemptionConfig(
+                system_scheduler_enabled=preemption)))
+        for n in nodes:
+            h.state.upsert_node(h.next_index(), copy.deepcopy(n))
+        return h
+
+    def process(h, job, name, kind="service", deterministic=True):
+        h.state.upsert_job(h.next_index(), copy.deepcopy(job))
+        h.process(kind, Evaluation(
+            priority=job.priority, type=job.type,
+            triggered_by=EVAL_TRIGGER_JOB_REGISTER, job_id=job.id,
+            namespace=job.namespace), deterministic=deterministic)
+        out[name] = rec.seen[-1]
+
+    def job(job_id, count):
+        j = mock.job()
+        j.id = job_id
+        j.task_groups[0].count = count
+        return j
+
+    try:
+        for det, mode in ((True, "int32"), (False, "float32")):
+            h = harness(make_nodes(30, seed=42))
+            process(h, job("plain", 5), f"{mode}-plain", deterministic=det)
+            j = job("spread", 5)
+            j.task_groups[0].spreads = [Spread(
+                attribute="${meta.rack}", weight=50,
+                spread_target=[SpreadTarget(value="r0", percent=50),
+                               SpreadTarget(value="r1", percent=50)])]
+            process(h, j, f"{mode}-spread", deterministic=det)
+            j = job("aff", 5)
+            j.affinities = [Affinity(ltarget="${attr.rack}", rtarget="r1",
+                                     operand="=", weight=50)]
+            process(h, j, f"{mode}-affinity", deterministic=det)
+            j = job("dp", 6)
+            j.constraints.append(Constraint(
+                operand="distinct_property", ltarget="${attr.rack}",
+                rtarget="2"))
+            process(h, j, f"{mode}-distinct", deterministic=det)
+            j = job("upd", 4)
+            process(h, j, f"{mode}-before-update", deterministic=det)
+            j = copy.deepcopy(j)
+            j.version = 1
+            j.task_groups[0].tasks[0].config = {"command": "/bin/new"}
+            process(h, j, f"{mode}-evict", deterministic=det)
+        nodes = make_nodes(4, seed=4)
+        for n in nodes:
+            n.node_resources.cpu_shares = 1000
+            n.compute_class()
+        h = harness(nodes, preemption=True)
+        for job_id, prio in (("low", 20), ("high", 80)):
+            j = mock.system_job()
+            j.id, j.priority = job_id, prio
+            j.task_groups[0].tasks[0].resources.cpu = 700
+            process(h, j, f"int32-preempt-{job_id}", kind="system")
+    finally:
+        rec.stop()
+    return out
+
+
+_SYNTHETIC = {
+    f"syn-{np.dtype(dt).name}-s{s}": dict(
+        n_nodes=n, n_tgs=g, n_placements=p, n_spreads=s, seed=seed, dtype=dt)
+    for seed, (dt, n, g, p, s) in enumerate([
+        (np.int32, 17, 1, 3, 0), (np.int32, 64, 3, 16, 1),
+        (np.int32, 200, 2, 40, 2), (np.float32, 33, 2, 7, 0),
+        (np.float32, 50, 4, 11, 1), (np.float32, 8, 1, 1, 2),
+        (np.float64, 24, 2, 5, 0), (np.float64, 40, 3, 17, 1),
+        (np.float64, 130, 1, 9, 2),
+    ], start=20)
+}
+_ENCODED = [f"{mode}-{what}" for mode in ("int32", "float32")
+            for what in ("plain", "spread", "affinity", "distinct",
+                         "before-update", "evict")] + ["int32-preempt-high"]
+# (evals of one dtype, batch bucket): every eval alone, then mixed batches
+# that widen each absent axis, at the buckets of max_batch=8 (1, 2, 8)
+_WIRE_CASES = [([name], 1) for name in list(_SYNTHETIC) + _ENCODED] + [
+    (["syn-int32-s0", "syn-int32-s2"], 2),
+    (["syn-int32-s0", "syn-int32-s1", "syn-int32-s2"], 8),
+    (["syn-float32-s0", "syn-float32-s1", "syn-float32-s2"], 8),
+    (["syn-float64-s0", "syn-float64-s2"], 2),
+    (["syn-float64-s2", "syn-float64-s1", "syn-float64-s0"], 8),
+    (["int32-plain", "int32-spread"], 2),
+    (["int32-affinity", "int32-distinct", "int32-evict"], 8),
+    (["int32-plain", "int32-preempt-high"], 2),
+    (["int32-preempt-high", "int32-evict", "int32-spread",
+      "int32-affinity", "int32-distinct", "syn-int32-s1"], 8),
+    (["float32-spread", "float32-plain"], 2),
+    (["float32-evict", "float32-affinity", "float32-distinct",
+      "syn-float32-s1"], 8),
+]
+
+
+@pytest.fixture(scope="module")
+def wire_evals():
+    evals = {name: synthetic_enc(**kw) for name, kw in _SYNTHETIC.items()}
+    evals.update(_encoded_by_the_scheduler())
+    # the encoder did put every axis on the wire
+    assert evals["int32-affinity"].static[4].shape[0] > 0
+    assert evals["int32-distinct"].static[17].shape[0] > 0
+    assert (np.asarray(evals["float32-evict"].xs[2]) >= 0).any()
+    assert evals["int32-preempt-high"].static[20].shape[1] > 0
+    assert evals["int32-preempt-high"].carry[10].dtype == np.int64
+    return evals
+
+
+def _wire_id(case):
+    names, b_pad = case
+    return f"{'+'.join(names)}@{b_pad}"
+
+
+@pytest.mark.parametrize("case", _WIRE_CASES, ids=_wire_id)
+def test_wire_pack_then_unpack_is_pad_and_stack(wire_evals, case):
+    """The packer followed by the unpacker yields, bit for bit, shape for
+    shape and dtype for dtype, the 48 arrays ``pad_encoded`` + ``np.stack``
+    yield — into buffers that held another batch before."""
+    names, b_pad = case
+    encs = [wire_evals[n] for n in names]
+    dims = DeviceBatcher._batch_dims(encs)
+    dtype = encs[0].dtype
+    layout = wire.WireLayout(wire.shape_key(encs[0], dims, dtype), b_pad, dims)
+    assert 2 <= len(layout.groups) <= 4
+    bufs = wire.WireBuffers(layout)
+    for stale in bufs.arrays:   # what an earlier dispatch left behind
+        stale[...] = 0x5A
+    wire.pack(bufs, encs)
+    got = wire.unpack(layout, bufs.arrays, np)
+    padded = [pad_encoded(e, dtype=dtype, **dims) for e in encs]
+    padded += [padded[0]] * (b_pad - len(padded))
+    for part, part_name in enumerate(("static", "carry", "xs")):
+        assert len(got[part]) == len(padded[0][part])
+        for i, have in enumerate(got[part]):
+            want = np.stack([p[part][i] for p in padded])
+            where = f"{part_name}[{i}]"
+            assert have.dtype == want.dtype, where
+            np.testing.assert_array_equal(have, want, err_msg=where)
+
+
+@pytest.mark.parametrize("case", _WIRE_CASES, ids=_wire_id)
+def test_wire_run_returns_what_the_single_scan_returns(wire_evals, case):
+    """``run()`` through the packed program hands every eval of the batch
+    the five arrays ``run_scan_single`` computes for it alone: same
+    dtypes, same shapes, same bits."""
+    names, b_pad = case
+    encs = [wire_evals[n] for n in names]
+    engine = TpuPlacementEngine.shared()
+    singles = [engine.run_scan_single(e) for e in encs]
+    batcher = DeviceBatcher(max_batch=1 if b_pad == 1 else 8,
+                            window_ms=300.0)
+    try:
+        batched = run_concurrent(batcher, encs)
+        (d,) = _dispatches_of(batcher)
+        assert (d["b"], d["b_pad"], d["d2h_arrays"]) == (len(encs), b_pad, 1)
+        assert batcher.stats["batch_fallbacks"] == 0
+    finally:
+        batcher.stop()
+    for name, single, got in zip(names, singles, batched):
+        for out_name, want, have in zip(
+                ("chosen", "scores", "pulls", "skipped", "evict"),
+                single, got):
+            where = f"{name} {out_name}"
+            if out_name == "evict" and have.shape[1] > want.shape[1]:
+                # a preempting neighbour widened the candidate axis: the
+                # eval's own columns first, then inert ones nobody evicts
+                assert (have[:, want.shape[1]:] == -1).all(), where
+                have = have[:, :want.shape[1]]
+                if not want.size:
+                    continue
+            assert have.dtype == want.dtype, where
+            assert have.shape == want.shape, where
+            if want.dtype.kind == "f":   # bit for bit, NaN or not
+                want, have = (a.view(f"i{a.dtype.itemsize}")
+                              for a in (np.ascontiguousarray(want),
+                                        np.ascontiguousarray(have)))
+            np.testing.assert_array_equal(have, want, err_msg=where)
