@@ -432,7 +432,7 @@ def bench_parity_scan_single(n_nodes=5000, n_placements=10_000):
 def bench_system(name, n_nodes, jobs, workers=32, device_batch=16,
                  timeout=180.0, node_seed=0, warmup=None,
                  node_factory=None, expected=None, done=None,
-                 deterministic=False, window_ms=None, idle_ms=None,
+                 deterministic=False, window_ms=None,
                  device_min_placements=None, tranches=0):
     """Run ``jobs`` through a real in-proc server; returns metrics dict.
 
@@ -445,9 +445,8 @@ def bench_system(name, n_nodes, jobs, workers=32, device_batch=16,
     check for shapes (system jobs, preemption) where per-TG counts don't
     describe the goal.
 
-    Gather-cadence knobs (``window_ms``/``idle_ms``/
-    ``device_min_placements``) default to None = the PRODUCTION
-    ServerConfig defaults, so what a bench row measures by default is
+    Gather-cadence knobs (``window_ms``/``device_min_placements``)
+    default to None = the PRODUCTION ServerConfig defaults, so what a bench row measures by default is
     what an operator actually gets; rows that pass explicit values are
     measuring a deliberate experiment and record it in batcher_config."""
     from nomad_tpu import mock
@@ -456,15 +455,13 @@ def bench_system(name, n_nodes, jobs, workers=32, device_batch=16,
 
     if window_ms is None:
         window_ms = ServerConfig.device_batch_window_ms
-    if idle_ms is None:
-        idle_ms = ServerConfig.device_batch_idle_ms
     if device_min_placements is None:
         device_min_placements = ServerConfig.device_min_placements
 
     rng = np.random.default_rng(node_seed)
     server = Server(ServerConfig(
         num_schedulers=0, device_batch=device_batch,
-        device_batch_window_ms=window_ms, device_batch_idle_ms=idle_ms,
+        device_batch_window_ms=window_ms,
         deterministic=deterministic,
         device_min_placements=device_min_placements,
         heartbeat_min_ttl=3600, heartbeat_max_ttl=7200,
@@ -679,7 +676,6 @@ def bench_system(name, n_nodes, jobs, workers=32, device_batch=16,
             "batcher_config": {
                 "device_min_placements": device_min_placements,
                 "window_ms": window_ms,
-                "idle_ms": idle_ms,
             },
         }
         if server.device_batcher:
@@ -796,7 +792,7 @@ def bench_c1m_system():
     spread+affinity stanzas on ~25%% of jobs, over 5K heterogeneous
     nodes; deterministic int-spec scoring with per-eval ring
     decorrelation; ~1K evals ride eval-batched device dispatches (the
-    adaptive gather covers the single-flight encode phase); placements
+    demand-aware gather covers the single-flight encode phase); placements
     flow as dense arrays through plan apply and the FSM. The JSON's
     ``phases`` record the measured wall share of every pipeline phase —
     the v5e-8 extrapolation in main() is computed from THOSE, not from
@@ -1013,18 +1009,18 @@ def system_benches():
     def _spread_warm():
         return _spread_job("warm-spread")
 
-    # adaptive idle-gap gather: the 10-eval burst rides 1-2 dispatches,
+    # demand-aware gather: the 10-eval burst rides 1-2 dispatches,
     # so the wall here is a few fixed per-dispatch costs (see phases in
     # the JSON) — the single-flight encode cache collapses the per-eval
     # encode
     r = _diagnostic(bench_system, "service-spread-5K", 5000, jobs, timeout=300.0,
-                    idle_ms=100.0, window_ms=2000.0, warmup=_spread_warm)
+                    window_ms=2000.0, warmup=_spread_warm)
     if r:
         results.append(r)
 
     # config 3b: the PRODUCTION batcher defaults at the 5K-node shape —
     # no gather knobs passed, so this row runs exactly what ServerConfig
-    # ships (demand-aware gather, 2s backstop window, 3ms idle gap,
+    # ships (demand-aware gather, 2s backstop window,
     # device_min_placements=24). Since r06 the headline runs these same
     # defaults, so this row is the small-shape control for the headline
     # rather than a what-an-operator-gets footnote.

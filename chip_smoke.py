@@ -463,6 +463,13 @@ def check_served(server, sizes: Sizes, run: dict, sink: CounterSink) -> list:
     for name in ENGINE_FALLBACK_COUNTERS:
         if sink.get(name):
             bad.append(f"{name} = {sink.get(name)}")
+    # the run has drained (wait_for): every announcement was consumed by
+    # an arrival or withdrawn; one left over holds later gathers open
+    with server.device_batcher._lock:
+        run["announced_outstanding"] = server.device_batcher._expected
+    if run["announced_outstanding"]:
+        bad.append(f"{run['announced_outstanding']} announced evals "
+                   "outstanding after the run drained")
     return bad
 
 
@@ -681,6 +688,7 @@ def run_smoke(sizes: Sizes, seed: int = 0) -> dict:
             top_bucket_dispatched=top_bucket_dispatched(stats, sizes),
             fill_dispatches=run["fill_dispatches"],
             fill_evals=run["fill_evals"],
+            announced_outstanding=run["announced_outstanding"],
             fallbacks={
                 **{k: stats[k] for k in BATCHER_FALLBACK_STATS},
                 **{n: sink.get(n) for n in ENGINE_FALLBACK_COUNTERS},

@@ -371,6 +371,16 @@ class EvalBroker:
             self._cond.notify_all()
         _trace.on_flush()
 
+    def backlog(self, schedulers: List[str]) -> int:
+        """Ready evals of these scheduler types beyond what the workers
+        parked in dequeue() are about to take: over 0, a worker that
+        finishes its eval now finds its next one waiting (the worker
+        then carries its batcher announcement over, worker.py)."""
+        with self._lock:
+            ready = sum(len(self.ready[s]) for s in schedulers
+                        if s in self.ready)
+            return max(0, ready - self._dequeue_waiters)
+
     def stats(self) -> Dict[str, object]:
         with self._lock:
             by_sched = {}

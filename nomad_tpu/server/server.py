@@ -147,24 +147,17 @@ class ServerConfig:
     # concurrently-scheduling evals share ONE device dispatch of the
     # batched placement scan. 0/1 disables batching (per-eval dispatch).
     device_batch: int = 8
-    # how long the batcher waits for co-arriving evals before dispatching
-    # (the total CAP when idle-gap or demand-aware gathering is on).
-    # Sized as a pure BACKSTOP, not the gather pacing: with demand-aware
-    # gathering (DeviceBatcher.expect) a wave dispatches the moment its
-    # announced cohort has arrived — typically bounded by the concurrent
-    # encode time, tens of ms — and this cap only bites when an announced
-    # encode stalls. The old 25ms default silently amputated any cohort
-    # whose encodes took longer than 25ms to trickle in, which at C1M
-    # scale meant waves never filled (r05: mean 16 evals vs a 64 cap).
+    # the most a gather holds for announced evals still en route.
+    # Sized as a pure BACKSTOP, not the gather pacing: a gather closes on
+    # announced demand alone (DeviceBatcher.expect; workers announce an
+    # eval before its snapshot) — a lone eval dispatches the moment it
+    # arrives, a wave the moment its announced cohort has — typically
+    # bounded by the concurrent snapshot and encode time, tens of ms —
+    # and this cap only bites when an announced eval stalls. The old
+    # 25ms default silently amputated any cohort whose encodes took
+    # longer than 25ms to trickle in, which at C1M scale meant waves
+    # never filled (r05: mean 16 evals vs a 64 cap).
     device_batch_window_ms: float = 2000.0
-    # adaptive gather: keep the batch growing while requests keep arriving
-    # within this gap of each other (a burst's encodes trickle in);
-    # 0 disables (fixed window only). ON by default: a lone eval pays at
-    # most the idle gap (~3ms, well under one device dispatch), a burst
-    # gathers into one dispatch, and window_ms caps the worst case —
-    # the trickle-arrival latency bound is asserted by
-    # tests/test_device_batcher.py::test_trickle_arrivals_latency.
-    device_batch_idle_ms: float = 3.0
     # shard the eval batch over an ("evals", "nodes") jax device mesh when
     # multiple accelerator devices are visible (multi-chip)
     device_mesh: bool = False
@@ -316,7 +309,6 @@ class Server:
             self.device_batcher = DeviceBatcher(
                 max_batch=self.config.device_batch,
                 window_ms=self.config.device_batch_window_ms,
-                idle_ms=getattr(self.config, "device_batch_idle_ms", 0.0),
                 mesh=mesh,
             )
 

@@ -17,11 +17,21 @@ from ..scheduler.scheduler import new_scheduler
 from ..trace import context as _xcontext
 from ..trace import lifecycle as _lifecycle
 from ..utils import metrics, phases
-from ..structs.structs import Evaluation, Plan, PlanResult
+from ..structs.structs import (
+    JOB_TYPE_BATCH,
+    JOB_TYPE_SERVICE,
+    SCHED_ALG_TPU_BINPACK,
+    SCHED_ALG_TPU_BINPACK_CHUNKED,
+    Evaluation,
+    Plan,
+    PlanResult,
+)
 from .eval_broker import NotOutstandingError, TokenMismatchError
 from .fsm import EVAL_UPDATE
 
 BUILTIN_SCHEDULERS = ["service", "batch", "system"]
+# the types whose placements ride the batcher's gather (announce_next)
+GATHERED_SCHEDULERS = [JOB_TYPE_SERVICE, JOB_TYPE_BATCH]
 CORE_SCHEDULER = "_core"
 
 
@@ -39,6 +49,11 @@ class Worker:
         # applier (nomad_tpu/pipeline): the run loop must NOT ack —
         # the applier acks after the raft commit lands
         self._handed_off = False
+        # the batcher this worker is announced to (DeviceBatcher.expect)
+        # while the token is here: from the announcement until the engine
+        # takes it over, and again from the device's answer to the next
+        # eval's when the broker has a backlog (announce_next); else None
+        self._announced = None
         # follower mode: RPC connection to the leader's broker/plan queue
         from ..rpc.transport import LeaderConn
 
@@ -130,6 +145,10 @@ class Worker:
                 remote = None
             self._active_remote = remote
             poll_t0 = _lifecycle.pipeline_now()
+            # a token carried over from the last eval (announce_next) is
+            # for an eval that is ready NOW: never park in the broker
+            # with it
+            carried = self._announced is not None
             try:
                 if remote is not None:
                     # core (GC) evals mutate raft directly and only run on
@@ -140,13 +159,18 @@ class Worker:
                     token = token or ""
                 else:
                     evaluation, token = self.server.eval_broker.dequeue(
-                        schedulers, timeout=0.25
+                        schedulers, timeout=0.0 if carried else 0.25
                     )
             except Exception:  # noqa: BLE001 — leader gone mid-poll
+                self._withdraw_announcement()
                 self._close_remote()
                 self._stop.wait(0.5)
                 continue
             if evaluation is None:
+                # another worker took the backlog
+                self._withdraw_announcement()
+                if carried:
+                    continue
                 if idle_t0 is None:
                     idle_t0 = poll_t0
                 if remote is not None:
@@ -189,6 +213,7 @@ class Worker:
                     pass
             finally:
                 _xcontext.deactivate(trace_token)
+        self._withdraw_announcement()
 
     def _ack(self, eval_id: str, token: str) -> None:
         if self._active_remote is not None:
@@ -216,12 +241,21 @@ class Worker:
         if evaluation.type == CORE_SCHEDULER:
             from .core_sched import CoreScheduler
 
+            self._withdraw_announcement()
             snapshot = self.server.fsm.state.snapshot_min_index(
                 max(evaluation.modify_index, evaluation.snapshot_index)
             )
             CoreScheduler(self.server, snapshot).process(evaluation)
             return
+        try:
+            self._schedule(evaluation)
+        finally:
+            # what nobody took (an eval with nothing to place, a raise)
+            # goes, unless the next eval is waiting for this worker
+            if self._announced is not None:
+                self.announce_next()
 
+    def _schedule(self, evaluation: Evaluation) -> None:
         from ..utils.hostwork import HOST_WORK_SEM
 
         # worker-side spans are emitted HERE, in the worker's process:
@@ -248,6 +282,9 @@ class Worker:
             # against the wave windows ("wait_min_index: 41% of makespan"
             # names this exact block)
             with _lifecycle.stage("wait_index", evaluation.id) as waited:
+                if self.server.fsm.state.latest_index < wait_index:
+                    # a carried token does not sit out a wait that blocks
+                    self._withdraw_announcement()
                 self.server.fsm.state.wait_min_index(wait_index)
             _xcontext.record_span(
                 "eval.wait_min_index",
@@ -256,6 +293,24 @@ class Worker:
                 trace_id=trace_id, parent_id=trace_parent,
                 attrs=span_attrs,
             )
+            # Announce the eval to the batcher NOW: after the index wait
+            # (it can block for seconds and must not hold a gather) and
+            # before the snapshot's permit, so a cohort dequeued together
+            # is counted whole before its first member reaches the
+            # batcher, though the permits stagger them by milliseconds.
+            # A token carried over from the last eval (announce_next)
+            # becomes this eval's. System evals (one forced pass, 23 s
+            # when it preempts) never announce: their dispatch goes out
+            # as it arrives; nor does anything under a host algorithm,
+            # which never reaches the batcher.
+            batcher = None
+            if evaluation.type in GATHERED_SCHEDULERS:
+                batcher = self._gathering_batcher()
+            if batcher is None:
+                self._withdraw_announcement()
+            elif self._announced is None:
+                self._announced = batcher
+                batcher.expect()
             with HOST_WORK_SEM:
                 with _lifecycle.stage("snapshot", evaluation.id):
                     # read-only shared view: a burst of evals at one state
@@ -308,6 +363,63 @@ class Worker:
         local; only plan submission crosses the wire)."""
         return getattr(self.server, "device_batcher", None)
 
+    def _gathering_batcher(self):
+        """The batcher service and batch evals are announced to: the
+        server's, under a tpu_binpack algorithm (generic_sched.py reads
+        the same entry from its snapshot); None under a host algorithm,
+        whose evals never reach it and would leave the token untaken
+        through a whole host-stack placement."""
+        batcher = self.device_batcher
+        if batcher is None:
+            return None
+        _, cfg = self.server.fsm.state.scheduler_config()
+        if cfg is None or cfg.scheduler_algorithm not in (
+            SCHED_ALG_TPU_BINPACK, SCHED_ALG_TPU_BINPACK_CHUNKED
+        ):
+            return None
+        return batcher
+
+    def take_announcement(self) -> bool:
+        """Hand the eval's demand token (announced in ``_schedule``) to
+        the caller, which now owes the batcher a ``run(expected=True)``
+        or a ``cancel_expected()``: engine.compute_placements, at its
+        top. False when there is none: not announced, or taken already
+        (a scheduler's second attempt with no backlog)."""
+        taken, self._announced = self._announced is not None, None
+        return taken
+
+    def announce_next(self) -> None:
+        """Announce the eval this worker is about to dequeue, if the
+        broker holds one for it (a backlog: ready evals beyond what the
+        parked workers take), else withdraw what is still here. Called
+        when the worker is done with the device for this eval: by
+        engine.compute_placements with the batcher's answer, and by
+        ``submit_plan`` and the end of ``_process`` for a token that is
+        still here then. It is what holds
+        waves together when a flood is larger than the worker pool:
+        without it the count reaches 0 between one eval's answer and the
+        same worker's next announcement, and every straggler of the last
+        wave dispatches alone. The token rides through the plan's commit,
+        the ack and a dequeue that never parks (``_run``), and becomes
+        the next eval's in ``_schedule``. With no backlog (more workers
+        than evals in flight) nothing is announced and nothing held."""
+        batcher = None
+        if (
+            self._active_remote is None
+            and self.server.eval_broker.backlog(GATHERED_SCHEDULERS) > 0
+        ):
+            batcher = self._gathering_batcher()
+        if batcher is None:
+            self._withdraw_announcement()
+        elif self._announced is None:
+            self._announced = batcher
+            batcher.expect()
+
+    def _withdraw_announcement(self) -> None:
+        batcher = self._announced
+        if self.take_announcement():
+            batcher.cancel_expected()
+
     @property
     def pipeline(self):
         """The leader-local async applier (nomad_tpu/pipeline), or None
@@ -319,6 +431,11 @@ class Worker:
         return getattr(self.server, "pipeline", None)
 
     def submit_plan(self, plan: Plan) -> Tuple[PlanResult, Optional[object]]:
+        # a plan with the token still here had nothing to place on the
+        # device (stops, updates in place): no gather waits out its
+        # commit, unless this worker's next eval is waiting behind it
+        if self._announced is not None:
+            self.announce_next()
         plan.eval_token = self._eval_token
         # stamp the snapshot the scheduler actually saw (worker.go:277), not
         # the newest index — the plan applier uses this to decide how much

@@ -21,8 +21,8 @@ exactly as with the reference's optimistically-concurrent workers.
 """
 from __future__ import annotations
 
+import collections
 import logging
-import queue
 import threading
 import weakref
 from typing import Dict, List, Optional, Tuple
@@ -42,7 +42,7 @@ from .engine import (
 from .intscore import E27_ONE as _E27_NEUTRAL
 from ..trace import lifecycle as _lifecycle
 from ..utils import phases as _phases
-from ..utils.lock_witness import witness_lock
+from ..utils.lock_witness import witness_condition, witness_lock
 from ..utils.race_witness import tracked_dict
 
 logger = logging.getLogger("nomad_tpu.tpu.batcher")
@@ -288,19 +288,39 @@ class DeviceBatcher:
     ``run(enc)`` blocks the calling worker until its eval's slice of the
     batched result is ready. The dispatcher thread starts lazily on first
     use and stops with ``stop()``.
+
+    What ends a gather is the count of announced evals still en route,
+    and nothing else. The server's workers announce (``expect()``) every
+    service and batch eval once its raft-index wait is over, BEFORE the
+    snapshot's host-work permit (server/worker.py:Worker._process), so a
+    cohort dequeued together is counted whole before its first member
+    arrives; engine.compute_placements takes the token over and either
+    arrives with it (``run(expected=True)``) or withdraws it
+    (``cancel_expected()``: the host stack, the chunked tier, a raise). A
+    planner that announces nothing (harness and test planners, a
+    scheduler's second attempt) has compute_placements announce before
+    encode. A worker that has the device's answer and finds its next
+    eval waiting in the broker (a flood larger than the worker pool)
+    announces that one at once (Worker.announce_next), so the count does
+    not fall to 0 between two evals of one worker and the flood's waves
+    stay whole. System and core evals, the redispatcher and anything
+    under a host algorithm never announce.
+    The dispatcher takes what is queued after every arrival or
+    withdrawal and dispatches the moment nothing announced is
+    outstanding: a lone eval does not wait at all. A gather waits only
+    for evals announced BEFORE it took its first request; one announced
+    later rides the next wave. Otherwise, once evals take longer from
+    announcement to arrival than they are apart (a host starved of the
+    GIL), every arrival finds a newer announcement and one gather holds a
+    whole window's evals on one snapshot. ``window_ms`` caps a hold (an
+    announced eval that stalls). A batcher that was never announced to
+    (unit tests, raw use) keeps the plain fixed window.
     """
 
     def __init__(self, max_batch: int = 8, window_ms: float = 1.0,
-                 mesh=None, idle_ms: float = 0.0,
-                 queue_max: int = 4096) -> None:
+                 mesh=None, queue_max: int = 4096) -> None:
         self.max_batch = max(1, int(max_batch))
         self.window_s = max(0.0, float(window_ms)) / 1000.0
-        # Adaptive gather: with idle_ms > 0 the batch keeps growing while
-        # requests keep ARRIVING within idle_ms of each other (encode of a
-        # burst trickles evals in), dispatching when the stream pauses;
-        # window_ms then acts as the total cap rather than a workload-
-        # tuned constant. 0 = fixed-window behavior.
-        self.idle_s = max(0.0, float(idle_ms)) / 1000.0
         self.mesh = mesh
         # Bounded request queue: the async pipeline lets encode run ahead
         # of dispatch, so the gather queue needs a ceiling — a wedged
@@ -308,9 +328,7 @@ class DeviceBatcher:
         # not unbounded growth. The default is generous (orders of
         # magnitude above worker count); queue_max <= 0 means unbounded.
         self.queue_max = int(queue_max)
-        self._queue: "queue.Queue[_Request]" = queue.Queue(
-            maxsize=max(0, self.queue_max)
-        )
+        self._pending: "collections.deque[_Request]" = collections.deque()  # guarded-by: _lock
         self._scan = None
         self._scan_lock = witness_lock("batcher.DeviceBatcher._scan_lock")  # prewarm + dispatcher race
         # (shape key, b_pad) -> WireLayout, shared with the prewarm threads
@@ -322,6 +340,11 @@ class DeviceBatcher:
         self._warmed: Dict[tuple, set] = {}
         self._warm_threads: List[threading.Thread] = []
         self._lock = witness_lock("batcher.DeviceBatcher._lock")
+        # every arrival (run), withdrawal (cancel_expected) and take (the
+        # dispatcher's) notifies it, under _lock: it wakes a gather, and a
+        # worker that waits for room in a full queue
+        self._wake = witness_condition(
+            "batcher.DeviceBatcher._wake", self._lock)
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
         self._serial = next(_SERIAL)
@@ -342,12 +365,15 @@ class DeviceBatcher:
             # on every config artifact now.
             "gathers": 0,
             "full_gathers": 0,
-            # gather-window latency (enqueue -> dispatch start), the
-            # quantity the adaptive idle gap bounds: an operator watching
-            # /v1/metrics sees directly whether batching is adding
-            # scheduling latency
+            # gather latency (enqueue -> dispatch start): an operator
+            # watching /v1/metrics sees directly whether batching is
+            # adding scheduling latency
             "gather_wait_ms_total": 0.0,
             "gather_wait_ms_max": 0.0,
+            # gathers that waited for at least one announced eval, and
+            # what those cost from their first request to the close
+            "gathers_held": 0,
+            "gather_held_ms_total": 0.0,
             # per-dispatch timing split: host pad/stack (the evals written
             # into the wire buffers) vs compute (the call, then the one
             # wait for the output on the host: H2D, launch, kernel and the
@@ -379,14 +405,15 @@ class DeviceBatcher:
             "batch_fallbacks": 0,
             "prewarm_failures": 0,
         })
-        # Demand-aware gather (guarded-by: _lock): workers announce an
-        # encode-in-flight destined for this batcher via expect(); the
-        # gather loop keeps its window open while announced encodes are
-        # still en route instead of breaking on a fixed idle gap. Armed
-        # lazily on the first expect() so raw batchers (unit tests,
-        # forced-kernel paths that never announce) keep the classic
-        # window/idle semantics.
+        # Demand-aware gather (guarded-by: _lock): the count of announced
+        # evals still en route (expect()), which is what a gather holds
+        # for. Armed lazily on the first expect() so raw batchers (unit
+        # tests, forced-kernel paths that never announce) keep the fixed
+        # window.
         self._expected = 0
+        # every announcement ever made; less _expected, those that have
+        # arrived or been withdrawn since (what a gather counts up to)
+        self._announced_n = 0
         self._demand_aware = False
         _LIVE.add(self)
 
@@ -409,6 +436,8 @@ class DeviceBatcher:
         (the multichip dryrun's clean-exit contract — a prewarm thread
         still inside the runtime at interpreter teardown segfaults)."""
         self._stop.set()
+        with self._wake:
+            self._wake.notify_all()
         t = self._thread
         if t is not None:
             t.join(timeout=timeout)
@@ -416,11 +445,11 @@ class DeviceBatcher:
         # interpreter teardown segfaults inside the runtime
         self.wait_warm(timeout=timeout)
         # release anyone still parked
-        while True:
-            try:
-                req = self._queue.get_nowait()
-            except queue.Empty:
-                break
+        with self._wake:
+            parked = list(self._pending)
+            self._pending.clear()
+            self._wake.notify_all()
+        for req in parked:
             req.error = RuntimeError("device batcher stopped")
             req.event.set()
 
@@ -430,7 +459,7 @@ class DeviceBatcher:
         """Requests gathered but not yet dispatched — the pipeline's
         dispatch-stage depth gauge (published as
         nomad.pipeline.batcher_queue_depth in the server stats sweep)."""
-        return self._queue.qsize()
+        return len(self._pending)
 
     def has_warmed(self) -> bool:
         """True once at least one batch has dispatched — i.e. compile
@@ -441,27 +470,31 @@ class DeviceBatcher:
             return self.stats["dispatches"] > 0
 
     def expect(self, n: int = 1) -> None:
-        """Announce ``n`` encodes in flight that will submit here. The
-        gather loop holds its window open (up to window_ms) while
-        announced work is still en route, so a cohort of concurrently
-        encoding evals forms ONE full wave instead of fragmenting on the
-        idle gap. Every expect() must be balanced by run(expected=True)
-        or cancel_expected() — the engine's dispatch path does this in a
-        try/finally; a leaked expectation costs at most one window_ms cap
-        per gather, never a hang."""
+        """Announce ``n`` evals en route that will submit here. A gather
+        holds (up to window_ms) while announced evals are outstanding, so
+        a cohort of concurrently snapshotting and encoding evals forms
+        ONE wave, and closes the moment none is. The server's worker
+        announces a service or batch eval after its index wait and before
+        the snapshot permit, or already with the last eval's answer in
+        hand when the broker holds its next one (Worker.announce_next);
+        engine.compute_placements announces before encode for a planner
+        that did not. Every expect() must be
+        balanced by run(expected=True) or cancel_expected() — the worker
+        and the engine each do so in a try/finally; a leaked expectation
+        holds a gather until the next arrival passes it over, or the
+        window_ms cap, never a hang."""
         with self._lock:
             self._demand_aware = True
             self._expected += n
+            self._announced_n += n
 
     def cancel_expected(self) -> None:
-        """Withdraw one expect() (encode fell back to the host path,
-        rerouted to the chunked tier, or raised)."""
-        with self._lock:
+        """Withdraw one expect() (the eval places nothing, went to the
+        host stack or the chunked tier, or raised). Wakes a holding
+        gather, which may have been waiting for this eval alone."""
+        with self._wake:
             self._expected = max(0, self._expected - 1)
-
-    def _expected_now(self) -> int:
-        with self._lock:
-            return self._expected if self._demand_aware else -1
+            self._wake.notify_all()
 
     def run(self, enc: EncodedEval, expected: bool = False):
         """Submit one encoded eval; blocks until its results are ready.
@@ -477,18 +510,29 @@ class DeviceBatcher:
         dispatcher is alive, so a request that slipped into the queue
         after stop() drained it is picked up by the restarted thread
         rather than parking its worker forever."""
-        if expected:
-            # release the demand token before anything that can raise:
+        try:
+            # chaos hook: a fault here is a failed/slow device round trip
+            # for THIS eval — the engine's dispatch guard reroutes it to
+            # the host iterator path (parity-identical placements,
+            # reference latency)
+            chaos_fire("device_dispatch", evals=enc.p)
+            self._ensure_started()
+            req = _Request(enc)
+        except BaseException:
             # a chaos-failed dispatch must not leave a phantom
             # expectation holding future gathers open
-            self.cancel_expected()
-        # chaos hook: a fault here is a failed/slow device round trip for
-        # THIS eval — the engine's dispatch guard reroutes it to the host
-        # iterator path (parity-identical placements, reference latency)
-        chaos_fire("device_dispatch", evals=enc.p)
-        self._ensure_started()
-        req = _Request(enc)
-        self._queue.put(req)
+            if expected:
+                self.cancel_expected()
+            raise
+        with self._wake:
+            while 0 < self.queue_max <= len(self._pending):
+                self._wake.wait(0.5)
+            # one step under the lock: the token becomes the queued
+            # request, so the gather this wakes finds the eval it held for
+            self._pending.append(req)
+            if expected:
+                self._expected = max(0, self._expected - 1)
+            self._wake.notify_all()
         while not req.event.wait(timeout=0.5):
             self._ensure_started()
         if req.error is not None:
@@ -497,54 +541,67 @@ class DeviceBatcher:
 
     # -- dispatcher ------------------------------------------------------
 
+    def _gather(self) -> Tuple[List[_Request], str]:
+        """One batch and what closed it (for the dispatch's record).
+        Waits for a first request (an empty batch when stopped), then
+        after every arrival or withdrawal takes what is queued, without
+        waiting, and decides: full, or past the window cap, or nothing
+        announced before the first request was taken still en route ->
+        dispatch; else sleep until the next arrival or withdrawal. The
+        evals it waits for are counted, not named: as many arrivals and
+        withdrawals as there were announcements when it began, which is
+        exact while evals arrive in the order they were announced in and
+        closes a little early when they do not (a stalled or leaked
+        announcement is passed over by the next arrival). Never
+        announced to, the batch waits out the window."""
+        batch: List[_Request] = []
+        held = False
+        deadline = None
+        due = None
+        with self._wake:
+            while not self._pending:
+                if self._stop.is_set():
+                    return batch, ""
+                self._wake.wait(0.2)
+            while True:
+                while self._pending and len(batch) < self.max_batch:
+                    batch.append(self._pending.popleft())
+                self._wake.notify_all()   # there is room in the queue
+                if len(batch) >= self.max_batch:
+                    closed_by = "full"
+                    break
+                if self.window_s <= 0:
+                    closed_by = "no_window"
+                    break
+                if deadline is None:
+                    deadline = _phases.now() + self.window_s
+                remaining = deadline - _phases.now()
+                if remaining <= 0:
+                    closed_by = "window"
+                    break
+                if self._demand_aware:
+                    if due is None:
+                        due = self._announced_n
+                    if self._announced_n - self._expected >= due:
+                        closed_by = (
+                            "demand_drained" if held else "nothing_announced")
+                        break
+                    held = True
+                self._wake.wait(remaining)
+            self.stats["gathers"] += 1
+            if len(batch) >= self.max_batch:
+                self.stats["full_gathers"] += 1
+            if held:
+                self.stats["gathers_held"] += 1
+                self.stats["gather_held_ms_total"] += (
+                    _phases.now() - batch[0].t_enqueue) * 1000.0
+        return batch, closed_by
+
     def _dispatch_loop(self) -> None:
         while not self._stop.is_set():
-            try:
-                first = self._queue.get(timeout=0.2)
-            except queue.Empty:
+            batch, closed_by = self._gather()
+            if not batch:
                 continue
-            batch = [first]
-            # what ended the gather, for the dispatch's record
-            closed_by = "full"
-            if self.window_s > 0 and self.max_batch > 1:
-                deadline = _phases.now() + self.window_s
-                while len(batch) < self.max_batch:
-                    remaining = deadline - _phases.now()
-                    if remaining <= 0:
-                        closed_by = "window"
-                        break
-                    # adaptive mode waits only as long as the arrival gap
-                    wait = min(remaining, self.idle_s) if self.idle_s else remaining
-                    # demand-aware: while announced encodes are still en
-                    # route, keep polling up to the window cap instead of
-                    # closing the wave on an arrival gap — this is what
-                    # turns a trickling 64-eval cohort into ONE dispatch
-                    demand = self._expected_now()
-                    if demand > 0:
-                        wait = min(remaining, max(wait, 0.02))
-                    try:
-                        batch.append(self._queue.get(timeout=wait))
-                    except queue.Empty:
-                        if demand > 0 and self._expected_now() > 0:
-                            continue  # encodes still en route
-                        # stream paused (or window expired)
-                        closed_by = (
-                            "demand_drained" if demand > 0
-                            else "idle_gap" if wait < remaining
-                            else "window"
-                        )
-                        break
-            else:
-                while len(batch) < self.max_batch:
-                    try:
-                        batch.append(self._queue.get_nowait())
-                    except queue.Empty:
-                        closed_by = "no_window"
-                        break
-            with self._lock:
-                self.stats["gathers"] += 1
-                if len(batch) >= self.max_batch:
-                    self.stats["full_gathers"] += 1
             # dtype-homogeneous sub-batches: co-batching must never change
             # an eval's arithmetic (f32 evals upcast could select
             # differently than they would alone). int32 = the exact

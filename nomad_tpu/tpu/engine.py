@@ -1516,47 +1516,51 @@ class TpuPlacementEngine:
         """
         from ..utils import metrics as _metrics
 
-        # Small evals don't amortize a device dispatch: the host stack
-        # places them in low milliseconds, exactly like the reference's
-        # per-placement iterators (generic_sched.go:426). Threshold 0 =
-        # always use the device (the parity harness's frame); the
-        # production server sets it.
-        n_min = getattr(sched, "device_min_placements", 0)
-        if n_min and len(destructive) + len(place) < n_min:
-            # Warm-bucket retry ride-along: a partial OCC retry (the tail
-            # of a plan-rejected eval) is usually a few placements of a
-            # job shape whose compile bucket is ALREADY warm from the
-            # first pass — padding it into that bucket costs nothing,
-            # while the host fallback re-walks the ranking iterators per
-            # placement. Only reroute when the batcher has completed at
-            # least one batch (so buckets exist) and the retry isn't
-            # trivially small.
-            batcher = getattr(sched.planner, "device_batcher", None)
-            total = len(destructive) + len(place)
-            if (
-                batcher is None
-                or total < RETRY_DEVICE_FLOOR
-                or not batcher.has_warmed()
-            ):
-                _metrics.incr_counter("nomad.tpu_engine.small_eval_host")
-                return NotImplemented
-            _metrics.incr_counter("nomad.tpu_engine.small_eval_device_retry")
-
         from ..trace import lifecycle as _tlc
 
         wave_id = sched.eval.id
         batcher = getattr(sched.planner, "device_batcher", None)
-        # Demand announcement: tell the batcher an encode destined for it
-        # is in flight BEFORE the encode starts, so the gather window
-        # stays open for this eval's cohort instead of closing on an
-        # arrival gap (the r05 wave-fragmentation bug: 328 evals over 21
-        # dispatches against a 64 cap). Balanced in the finally / by
-        # run(expected=True) on every path out of this function.
-        expected_held = False
-        if batcher is not None:
-            batcher.expect()
-            expected_held = True
+        # Demand announcement: the batcher holds a gather while announced
+        # evals are en route and closes it the moment none is, so every
+        # eval bound for it is announced ahead of its arrival (the r05
+        # wave-fragmentation bug: 328 evals over 21 dispatches against a
+        # 64 cap). The server's worker announces before its snapshot and
+        # this function takes that token over HERE, ahead of every way
+        # out, so the finally / run(expected=True) below release it
+        # before any host-stack placement starts; a planner without one
+        # (harness and test planners, a scheduler's second attempt) has
+        # it announced before encode.
+        take = getattr(sched.planner, "take_announcement", None)
+        expected_held = batcher is not None and take is not None and take()
         try:
+            # Small evals don't amortize a device dispatch: the host stack
+            # places them in low milliseconds, exactly like the reference's
+            # per-placement iterators (generic_sched.go:426). Threshold 0 =
+            # always use the device (the parity harness's frame); the
+            # production server sets it.
+            n_min = getattr(sched, "device_min_placements", 0)
+            total = len(destructive) + len(place)
+            if n_min and total < n_min:
+                # Warm-bucket retry ride-along: a partial OCC retry (the
+                # tail of a plan-rejected eval) is usually a few placements
+                # of a job shape whose compile bucket is ALREADY warm from
+                # the first pass — padding it into that bucket costs
+                # nothing, while the host fallback re-walks the ranking
+                # iterators per placement. Only reroute when the batcher
+                # has completed at least one batch (so buckets exist) and
+                # the retry isn't trivially small.
+                if (
+                    batcher is None
+                    or total < RETRY_DEVICE_FLOOR
+                    or not batcher.has_warmed()
+                ):
+                    _metrics.incr_counter("nomad.tpu_engine.small_eval_host")
+                    return NotImplemented
+                _metrics.incr_counter(
+                    "nomad.tpu_engine.small_eval_device_retry")
+            if batcher is not None and not expected_held:
+                batcher.expect()
+                expected_held = True
             t0 = _metrics.now()
             with _HOST_WORK_SEM:
                 t1 = _metrics.now()
@@ -1596,6 +1600,11 @@ class TpuPlacementEngine:
                         expected_held = False  # run() consumes the token
                         chosen, scores, pulls, skipped_steps, evict = batcher.run(
                             enc, expected=True)
+                        # a worker with a backlog is en route again from
+                        # here: to its next eval's arrival
+                        again = getattr(sched.planner, "announce_next", None)
+                        if again is not None:
+                            again()
                     else:
                         chosen, scores, pulls, skipped_steps, evict = self.run_scan_single(enc)
             except Exception:  # noqa: BLE001 — device dispatch failed

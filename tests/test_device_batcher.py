@@ -241,7 +241,7 @@ class TestBatchedScanParity:
         batcher = DeviceBatcher(max_batch=4, window_ms=50.0)
         # park a request WITHOUT a dispatcher thread running
         req = _Request(synthetic_enc(8, 1, 1, seed=0))
-        batcher._queue.put(req)
+        batcher._pending.append(req)
         batcher.stop()
         assert req.event.is_set()
         assert isinstance(req.error, RuntimeError)
@@ -442,63 +442,229 @@ class TestServerBatchedScheduling:
             server.stop()
 
 
-class TestAdaptiveGatherLatency:
-    def test_trickle_arrivals_latency(self):
-        """When evals arrive at gaps LARGER
-        than the idle gap, dispatch latency is bounded by idle_ms — the
-        window cap must never hold a lone eval hostage. Each trickled
-        eval dispatches alone (stream paused > idle gap), so its gather
-        wait stays ~idle_ms even with a 10s window."""
-        batcher = DeviceBatcher(max_batch=8, window_ms=10_000.0, idle_ms=30.0)
+def _warm(batcher, announce=True):
+    """Compile outside what a case times; arm the demand-aware gather
+    the way a server's first eval does."""
+    if announce:
+        batcher.expect()
+    batcher.run(synthetic_enc(32, 1, 4, seed=0), expected=announce)
+
+
+def _submit_later(batcher, enc, delay_s, hold=None, errors=None):
+    """A worker's thread: announced already, it arrives after ``delay_s``
+    (under ``hold``, a permit, when given)."""
+    def go():
         try:
-            # warm the compile outside the timed phase
-            batcher.run(synthetic_enc(32, 1, 4, seed=0))
-            waits = []
-            for i in range(4):
-                enc = synthetic_enc(32, 1, 4, seed=i + 1)
-                t0 = time.monotonic()
-                batcher.run(enc)
-                waits.append(time.monotonic() - t0)
-                time.sleep(0.12)  # arrival gap >> idle gap: stream paused
-            # each request: one idle-gap wait (~30ms) + dispatch; far
-            # below the 10s window. Generous bound for CI jitter, but
-            # an order of magnitude under the window cap.
-            assert max(waits) < 2.0, waits
-            assert batcher.stats["dispatches"] >= 4
-            # the latency gauge recorded the gather waits
-            assert batcher.stats["gather_wait_ms_max"] >= 0.0
-            assert batcher.stats["gather_wait_ms_max"] < 1000.0
-        finally:
-            batcher.stop()
+            if hold is not None:
+                with hold:
+                    time.sleep(delay_s)
+            else:
+                time.sleep(delay_s)
+            batcher.run(enc, expected=True)
+        except BaseException as e:  # noqa: BLE001
+            if errors is not None:
+                errors.append(e)
+            raise
 
-    def test_burst_gathers_within_idle_gap(self):
-        """The complementary direction: requests arriving with gaps
-        SMALLER than the idle gap ride one dispatch."""
-        batcher = DeviceBatcher(max_batch=8, window_ms=10_000.0, idle_ms=500.0)
+    t = threading.Thread(target=go)
+    t.start()
+    return t
+
+
+def _join_all(threads, timeout=60.0):
+    for t in threads:
+        t.join(timeout=timeout)
+    assert not any(t.is_alive() for t in threads)
+
+
+def _case_lone_eval_with_nothing_announced_goes_at_once():
+    batcher = DeviceBatcher(max_batch=8, window_ms=10_000.0)
+    try:
+        _warm(batcher)
+        batcher.run(synthetic_enc(32, 1, 4, seed=1))   # e.g. the redispatcher
+        batcher.expect()
+        batcher.run(synthetic_enc(32, 1, 4, seed=2), expected=True)
+        for d in _dispatches_of(batcher)[-2:]:
+            assert (d["closed_by"], d["b"]) == ("nothing_announced", 1)
+            # no wait at all: an order of magnitude under the window
+            assert d["t_start"] - d["t_first_enqueue"] < 1.0
+        with batcher._lock:
+            assert batcher._expected == 0
+            assert batcher.stats["gathers_held"] == 0
+            assert batcher.stats["gather_held_ms_total"] == 0.0
+    finally:
+        batcher.stop()
+
+
+def _first_of_a_held_gather(batcher, enc, left):
+    """Submit ``enc`` (announced) from a thread and return once the
+    dispatcher has taken it and holds the gather for the ``left`` evals
+    still announced."""
+    t = _submit_later(batcher, enc, 0.0)
+    deadline = time.monotonic() + 30.0
+    while time.monotonic() < deadline:
+        with batcher._lock:
+            if batcher._expected == left and not batcher._pending:
+                return t
+        time.sleep(0.001)
+    raise AssertionError("the first eval never reached the gather")
+
+
+def _case_two_announced_50ms_apart_share_one_dispatch():
+    batcher = DeviceBatcher(max_batch=8, window_ms=10_000.0)
+    try:
+        _warm(batcher)
+        d0 = len(_dispatches_of(batcher))
+        batcher.expect(2)
+        first = _first_of_a_held_gather(
+            batcher, synthetic_enc(32, 1, 4, seed=3), left=1)
+        time.sleep(0.05)
+        t0 = time.monotonic()
+        batcher.run(synthetic_enc(32, 1, 4, seed=4), expected=True)
+        waited = time.monotonic() - t0
+        _join_all([first])
+        (d,) = _dispatches_of(batcher)[d0:]
+        assert (d["closed_by"], d["b"]) == ("demand_drained", 2)
+        # held for the second eval, and not a moment after it arrived
+        assert 0.04 <= d["t_start"] - d["t_first_enqueue"] < 5.0
+        assert waited < 5.0                     # the window is 10 s
+        with batcher._lock:
+            assert batcher._expected == 0
+            assert batcher.stats["gathers_held"] == 1
+            assert 40.0 <= batcher.stats["gather_held_ms_total"] < 5000.0
+    finally:
+        batcher.stop()
+
+
+def _case_cohort_staggered_by_a_permit_is_one_dispatch():
+    """Eight workers announce together, then pass one permit holding it
+    20 ms each (the snapshot's host-work permit, exaggerated): the first
+    arrives 140 ms before the last and the gather holds for all."""
+    batcher = DeviceBatcher(max_batch=8, window_ms=10_000.0)
+    try:
+        _warm(batcher)
+        d0 = len(_dispatches_of(batcher))
+        permit = threading.Semaphore(1)
+        errors = []
+        batcher.expect(8)
+        _join_all([
+            _submit_later(batcher, synthetic_enc(32, 1, 4, seed=10 + i), 0.02,
+                          hold=permit, errors=errors)
+            for i in range(8)
+        ])
+        assert not errors, errors
+        (d,) = _dispatches_of(batcher)[d0:]
+        assert (d["closed_by"], d["b"]) == ("full", 8)
+        assert d["t_start"] - d["t_first_enqueue"] >= 0.1
+        with batcher._lock:
+            assert batcher._expected == 0
+    finally:
+        batcher.stop()
+
+
+def _case_withdrawal_wakes_a_holding_gather():
+    batcher = DeviceBatcher(max_batch=8, window_ms=10_000.0)
+    try:
+        _warm(batcher)
+        batcher.expect(2)
+        first = _first_of_a_held_gather(
+            batcher, synthetic_enc(32, 1, 4, seed=5), left=1)
+        time.sleep(0.05)
+        t0 = time.monotonic()
+        batcher.cancel_expected()
+        _join_all([first])
+        # closed by the wake: a small multiple of it, not a poll's or the
+        # window's end (10 s)
+        assert time.monotonic() - t0 < 5.0
+        d = _dispatches_of(batcher)[-1]
+        assert (d["closed_by"], d["b"]) == ("demand_drained", 1)
+        assert 0.04 <= d["t_start"] - d["t_first_enqueue"] < 5.0
+        with batcher._lock:
+            assert batcher._expected == 0
+    finally:
+        batcher.stop()
+
+
+def _case_a_later_announcement_rides_the_next_wave():
+    """A gather waits for the evals announced before it took its first
+    request, not for one announced since: otherwise, once evals take
+    longer to arrive than they are apart, every arrival finds a newer
+    announcement and one gather holds until the window's end."""
+    batcher = DeviceBatcher(max_batch=8, window_ms=10_000.0)
+    try:
+        _warm(batcher)
+        d0 = len(_dispatches_of(batcher))
+        batcher.expect(2)
+        first = _first_of_a_held_gather(
+            batcher, synthetic_enc(32, 1, 4, seed=7), left=1)
+        batcher.expect()                        # announced while it holds
+        batcher.run(synthetic_enc(32, 1, 4, seed=8), expected=True)
+        _join_all([first], timeout=5.0)         # the window is 10 s
+        (d,) = _dispatches_of(batcher)[d0:]
+        assert (d["closed_by"], d["b"]) == ("demand_drained", 2)
+        with batcher._lock:
+            assert batcher._expected == 1
+        batcher.run(synthetic_enc(32, 1, 4, seed=9), expected=True)
+        d = _dispatches_of(batcher)[-1]
+        assert (d["closed_by"], d["b"]) == ("nothing_announced", 1)
+        with batcher._lock:
+            assert batcher._expected == 0
+    finally:
+        batcher.stop()
+
+
+def _case_never_announced_to_keeps_the_fixed_window():
+    batcher = DeviceBatcher(max_batch=8, window_ms=1000.0)
+    try:
+        _warm(batcher, announce=False)
+        d0 = len(_dispatches_of(batcher))
+        encs = [synthetic_enc(32, 1, 4, seed=20 + i) for i in range(3)]
+        late = threading.Thread(
+            target=lambda: (time.sleep(0.1), batcher.run(encs[2])))
+        late.start()
+        run_concurrent(batcher, encs[:2])
+        _join_all([late])
+        (d,) = _dispatches_of(batcher)[d0:]
+        assert (d["closed_by"], d["b"]) == ("window", 3)
+        assert d["t_start"] - d["t_first_enqueue"] >= 0.9
+    finally:
+        batcher.stop()
+
+
+def _case_a_raise_in_run_releases_the_token():
+    from nomad_tpu.chaos.injector import ChaosFault, ChaosInjector
+
+    batcher = DeviceBatcher(max_batch=8, window_ms=10_000.0)
+    try:
+        _warm(batcher)
+        batcher.expect()
+        inj = ChaosInjector(seed=1)
+        inj.arm("device_dispatch", mode="fail", prob=1.0)
         try:
-            batcher.run(synthetic_enc(32, 1, 4, seed=0))  # warm
-            d0 = batcher.stats["dispatches"]
-            encs = [synthetic_enc(32, 1, 4, seed=10 + i) for i in range(4)]
-            run_concurrent(batcher, encs)
-            assert batcher.stats["dispatches"] == d0 + 1, (
-                "a concurrent burst must share one dispatch"
-            )
-            assert batcher.stats["max_batch_seen"] >= 4
+            with pytest.raises(ChaosFault):
+                batcher.run(synthetic_enc(32, 1, 4, seed=6), expected=True)
         finally:
-            batcher.stop()
+            inj.disarm_all()
+        with batcher._lock:
+            assert batcher._expected == 0
+    finally:
+        batcher.stop()
 
-    def test_production_defaults_enable_adaptive_gather(self):
-        """ServerConfig defaults must exercise the adaptive path
-        (idle_ms > 0) with window_ms as a cap, not a tuned constant."""
-        from nomad_tpu.server.server import ServerConfig
 
-        cfg = ServerConfig()
-        assert cfg.device_batch_idle_ms > 0.0
-        assert cfg.device_batch_window_ms >= cfg.device_batch_idle_ms
-        # a lone eval's worst-case added latency stays well under one
-        # device dispatch (~tens of ms)
-        assert cfg.device_batch_idle_ms <= 10.0
+@pytest.mark.parametrize("case", [
+    _case_lone_eval_with_nothing_announced_goes_at_once,
+    _case_two_announced_50ms_apart_share_one_dispatch,
+    _case_cohort_staggered_by_a_permit_is_one_dispatch,
+    _case_withdrawal_wakes_a_holding_gather,
+    _case_a_later_announcement_rides_the_next_wave,
+    _case_never_announced_to_keeps_the_fixed_window,
+    _case_a_raise_in_run_releases_the_token,
+], ids=lambda f: f.__name__.replace("_case_", ""))
+def test_gather_closes_on_announced_demand(case):
+    case()
 
+
+class TestGatherGauges:
     def test_gather_wait_gauge_published(self):
         """The gather-wait latency gauge reaches /v1/metrics via the
         server's stats sweep (nomad.device_batcher.* namespace)."""
@@ -555,7 +721,7 @@ def _case_record_stamps_monotone_and_steps_padded():
             assert 0 < d["steps"] <= d["padded_steps"] == d["b_pad"] * d["p_pad"]
             assert d["b"] <= d["b_pad"] and d["n_pad"] >= 64
             assert d["d2h_bytes"] > 0 and d["closed_by"] in (
-                "full", "window", "idle_gap", "demand_drained")
+                "full", "window", "nothing_announced", "demand_drained")
         assert sum(d["steps"] for d in recs) == 5 + 20 + 9
         with batcher._lock:
             stats = dict(batcher.stats)

@@ -65,10 +65,11 @@ def placed_count(server, jobs):
 def test_mini_c1m_wave_fill_and_idle_coverage():
     """2K placements (40 jobs x 50) flood a server with 8 workers and a
     4-eval device batch. Asserts full wave formation (mean eval batch >=
-    half the cap) and that the bottleneck ledger covers >=90% of the
-    window with the instrumented ``idle`` component present — workers
-    idled between server start and the flood, and that time must be a
-    named span, not an attribution hole."""
+    half the cap, no gather cut by the window) and that the bottleneck
+    ledger covers >=90% of the window with the instrumented ``idle``
+    component present — workers idled between server start and the
+    flood, and that time must be a named span, not an attribution
+    hole."""
     lifecycle.reset()
     server = Server(ServerConfig(
         num_schedulers=8, deterministic=True, device_batch=4,
@@ -97,7 +98,13 @@ def test_mini_c1m_wave_fill_and_idle_coverage():
 
         # (1) wave formation: dispatches filled at least half the batch
         # on average — 40 concurrent evals against a 4-eval cap must not
-        # degenerate into single-eval waves
+        # degenerate into single-eval waves. The flood is larger than the
+        # worker pool, so what holds its waves together is the token a
+        # worker carries from one eval's answer to its next eval's
+        # arrival (Worker.announce_next): without it the announced count
+        # reaches 0 between the two and stragglers dispatch alone (24-33
+        # dispatches here). No gather is cut by the window cap with
+        # announced evals still en route — r05's amputation.
         stats = server.device_batcher.stats
         assert stats["dispatches"] > 0, stats
         mean_batch = stats["evals"] / stats["dispatches"]
@@ -107,6 +114,12 @@ def test_mini_c1m_wave_fill_and_idle_coverage():
             f"cap 4) — gather cadence regression"
         )
         assert stats["gathers"] > 0, stats
+        assert stats["full_gathers"] >= 1, dict(stats)
+        closed = [d["closed_by"] for d in lifecycle.dispatch_records()
+                  if d.get("source") == "batcher"]
+        assert closed and "window" not in closed, closed
+        with server.device_batcher._lock:
+            assert server.device_batcher._expected == 0
 
         # (2) coverage: the ledger explains the window, idle included
         report = attribution.bottleneck_report()
