@@ -10,9 +10,9 @@ It refuses at once on anything that is not a TPU (``JAX_PLATFORMS=cpu
 python chip_smoke.py`` exits 2 and prints no result). On the chip it drives
 ``Server(ServerConfig(...))`` -> ``register_job`` -> eval broker -> ``Worker``
 -> ``TpuPlacementEngine`` -> ``DeviceBatcher`` -> plan queue -> raft/FSM ->
-state store at the width of the C1M headline (bench.py ``bench_c1m_system``:
-5,000 heterogeneous nodes, ``deterministic=True``, ``device_batch=64``, the
-900-1,000-task job mix of ``c1m_mixed_jobs``), depth cut to two 64-job waves,
+state store at the width of the C1M headline (BASELINE.md config 5: 5,000
+heterogeneous nodes, ``deterministic=True``, ``device_batch=64``, the
+900-1,000-task job mix of ``mock.C1M_TEMPLATES``), depth cut to two 64-job waves,
 plus the two other compiled programs the benchmark's cells use on the same
 cluster: a system job over every eligible node (the scan-free forced kernel)
 and a preempting system eval whose encode carries preemption tables
@@ -59,12 +59,12 @@ BATCHER_FALLBACK_STATS = ("batch_fallbacks", "prewarm_failures")
 
 @dataclasses.dataclass
 class Sizes:
-    """Everything the CPU dry run (tests/test_chip_smoke.py) shrinks. The
+    """Everything the CPU dry run (tests/test_chip_bringup.py) shrinks. The
     defaults are the C1M headline's widths; only depth is cut."""
 
     n_nodes: int = 5000
     device_batch: int = 64
-    workers: int = 128            # 2x the batch, as bench_c1m_system
+    workers: int = 128            # 2x the batch
     jobs_per_tranche: int = 64    # one full wave
     tranches: int = 2
     count_scale: float = 1.0      # multiplies the templates' 900-1,000 counts
@@ -185,9 +185,9 @@ SMALL_DISK = 50 * 1024
 
 
 def make_nodes(n: int, seed: int):
-    """The headline's heterogeneous fleet (cpu/mem classes of
-    bench.bench_system) with two more axes so feasibility and capacity
-    are not trivial: ~2% windows nodes that linux-constrained jobs skip,
+    """The headline's heterogeneous fleet (three cpu and three memory
+    classes) with two more axes so feasibility and capacity are not
+    trivial: ~2% windows nodes that linux-constrained jobs skip,
     and three disk classes — the small one is what the preempting system
     job cannot fit on without evicting."""
     import numpy as np
@@ -212,17 +212,16 @@ def make_nodes(n: int, seed: int):
 
 
 def c1m_job(sizes: Sizes, i: int, job_id: str, count: int = 0):
-    """Job ``i`` of the benchmark's own C1M mix (bench.c1m_mixed_jobs: 40
-    templates round-robin — 0-9 service with spread+affinity stanzas,
-    10-27 plain service, 28-39 batch; 900-1,000 tasks each, all in the
-    p=1024 scan bucket). ``count`` overrides the template's task count."""
-    import bench
+    """Job ``i`` of the C1M mix (mock.C1M_TEMPLATES: 40 templates
+    round-robin — 0-9 service with spread+affinity stanzas, 10-27 plain
+    service, 28-39 batch; 900-1,000 tasks each, all in the p=1024 scan
+    bucket). ``count`` overrides the template's task count."""
+    from nomad_tpu import mock
 
-    _, templates, mk_job = bench.c1m_mixed_jobs(total=0)
-    tpl = templates[i % len(templates)]
+    tpl = mock.C1M_TEMPLATES[i % len(mock.C1M_TEMPLATES)]
     if not count:
         count = max(1, int(round(tpl["count"] * sizes.count_scale)))
-    return mk_job(tpl, job_id, count)
+    return mock.c1m_job(tpl, job_id, count)
 
 
 def fill_jobs(sizes: Sizes):
@@ -741,7 +740,6 @@ def main(argv=None) -> int:
     import jaxlib
     from importlib import metadata
 
-    import bench  # noqa: F401
     import nomad_tpu  # noqa: F401
 
     log(f"device: {device}; jax {jax.__version__}, jaxlib "

@@ -40,10 +40,6 @@ class AgentConfig:
     bootstrap_expect: int = 1
     num_schedulers: int = 2
     scheduler_algorithm: str = "tpu_binpack"
-    # chunked-tier knobs (default_scheduler_config stanza); only read
-    # when scheduler_algorithm = "tpu_binpack_chunked"
-    chunk_k: int = 128
-    parity_sample_rate: float = 0.05
     acl_enabled: bool = False
     # gossip encryption key (reference agent `encrypt` option): base64 of
     # 16/24/32 bytes; all servers must share it — plaintext packets drop
@@ -236,8 +232,6 @@ class Agent:
                 ServerConfig(
                     num_schedulers=self.config.num_schedulers,
                     scheduler_algorithm=self.config.scheduler_algorithm,
-                    chunk_k=self.config.chunk_k,
-                    parity_sample_rate=self.config.parity_sample_rate,
                     region=self.config.region,
                     authoritative_region=self.config.authoritative_region,
                     replication_token=self.config.replication_token,

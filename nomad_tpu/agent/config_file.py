@@ -13,8 +13,7 @@ Nomad config files map over:
     advertise { rpc }
     server { enabled bootstrap_expect num_schedulers encrypt
              authoritative_region raft_protocol(ignored)
-             default_scheduler_config { scheduler_algorithm chunk_k
-                                        parity_sample_rate } }
+             default_scheduler_config { scheduler_algorithm } }
     client { enabled node_class servers meta {} host_volume "n" { path } }
     acl { enabled replication_token }
     telemetry { statsd_address statsite_address datadog_address
@@ -35,6 +34,7 @@ import os
 from typing import Any, Dict, List
 
 from ..jobspec import HCLError, parse_hcl
+from ..structs.structs import SchedulerConfiguration
 from .agent import AgentConfig
 
 
@@ -198,12 +198,15 @@ def apply_file_config(cfg: AgentConfig, data: Dict[str, Any]) -> AgentConfig:
     if "wire_raft" in srv:
         cfg.wire_raft = _as_bool(srv["wire_raft"], "server.wire_raft")
     dsc = srv.get("default_scheduler_config") or {}
+    _check_keys(dsc, {"scheduler_algorithm"}, "server.default_scheduler_config")
     if "scheduler_algorithm" in dsc:
         cfg.scheduler_algorithm = str(dsc["scheduler_algorithm"])
-    if "chunk_k" in dsc:
-        cfg.chunk_k = int(dsc["chunk_k"])
-    if "parity_sample_rate" in dsc:
-        cfg.parity_sample_rate = float(dsc["parity_sample_rate"])
+        try:
+            SchedulerConfiguration(
+                scheduler_algorithm=cfg.scheduler_algorithm
+            ).validate()
+        except ValueError as e:
+            raise ConfigError(f"server.default_scheduler_config: {e}") from e
 
     cli = data.get("client") or {}
     _check_keys(cli, _CLIENT_KEYS, "client")

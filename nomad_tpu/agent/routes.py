@@ -571,6 +571,10 @@ class Routes:
             self._authorize(req, "operator:write")
             body = req.json() or {}
             config = jsonapi.from_json_obj(SchedulerConfiguration, body)
+            try:
+                config.validate()
+            except ValueError as e:
+                raise HTTPError(400, str(e))
             self.server.raft_apply("scheduler-config", config)
             return {"Updated": True, "Index": self.state.latest_index}
         raise HTTPError(405, "method not allowed")

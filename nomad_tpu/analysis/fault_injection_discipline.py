@@ -24,8 +24,8 @@ production side of it stays inert and uniform:
 Scope: rule 1 applies to production modules (``nomad_tpu/`` excluding
 ``nomad_tpu/chaos/`` and test files); rules 2-3 apply everywhere outside
 ``nomad_tpu/chaos/`` itself (the harness package owns its documented
-driver-level ``finally``; consumers — tests, benches — are exactly where
-a leaked arm does damage).
+driver-level ``finally``; consumers — tests — are exactly where a leaked
+arm does damage).
 """
 from __future__ import annotations
 
@@ -66,22 +66,12 @@ def _is_test_file(rel: str) -> bool:
     return "tests/" in rel or base.startswith("test_") or base == "conftest.py"
 
 
-# Harness modules living OUTSIDE nomad_tpu/chaos/: replay drivers that
-# legitimately build on the chaos harness (subclass CrashReplay, spawn
-# ServerProcess fleets) but ship next to the subsystem they exercise.
-_HARNESS_MODULES = (
-    "nomad_tpu/watch/serve.py",  # ServeReplay — the serve-100Kwatch bench
-)
-
-
 def _production_scope(rel: str) -> bool:
     rel = _norm(rel)
     if "nomad_tpu/analysis/" in rel or rel.startswith("analysis/"):
         return False  # the linter itself names chaos in its rules
-    if any(rel.endswith(h) for h in _HARNESS_MODULES):
-        return False
     return (
-        ("nomad_tpu/" in rel or not rel.startswith(("tests/", "bench")))
+        ("nomad_tpu/" in rel or not rel.startswith("tests/"))
         and not _in_chaos_pkg(rel)
         and not _is_test_file(rel)
     )

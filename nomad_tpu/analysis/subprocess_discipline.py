@@ -6,7 +6,7 @@ will not collect: an un-reaped ``Popen`` is a zombie holding its data
 dir, an unbounded ``wait()`` on an unkillable child wedges the whole
 test run, and a ``subprocess.run`` without a timeout turns one stuck
 server into a hung CI job. Three rules, enforced over the code that
-spawns processes (the chaos package, tests, and bench drivers):
+spawns processes (the chaos package and tests):
 
 1. **Blocking one-shot helpers carry an explicit ``timeout=``** —
    ``subprocess.run`` / ``call`` / ``check_call`` / ``check_output``
@@ -21,9 +21,8 @@ spawns processes (the chaos package, tests, and bench drivers):
    (``terminate``/``kill``/``wait``). A bare local ``Popen`` leaks the
    child on the first exception between spawn and reap.
 
-Scope: ``nomad_tpu/chaos/``, test files, and bench drivers — harness
-code, where a leaked child outlives the scenario and poisons the next
-one. Client task drivers (``client/drivers/``, logmon, plugin
+Scope: ``nomad_tpu/chaos/`` and test files — harness code, where a
+leaked child outlives the scenario and poisons the next one. Client task drivers (``client/drivers/``, logmon, plugin
 transports) spawn workloads as their actual job and manage lifecycles
 through their own handle/recover machinery; they are out of scope here.
 """
@@ -62,12 +61,10 @@ def _is_test_file(rel: str) -> bool:
 def _spawn_scope(rel: str) -> bool:
     """Files allowed to spawn processes (and held to rules 1-3)."""
     rel = _norm(rel)
-    base = rel.rsplit("/", 1)[-1]
     return (
         "nomad_tpu/chaos/" in rel
         or rel.startswith("chaos/")
         or _is_test_file(rel)
-        or base.startswith("bench")
     )
 
 

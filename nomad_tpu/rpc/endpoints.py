@@ -256,11 +256,12 @@ def bind_server(server, rpc: RPCServer) -> None:
         index, config = state().scheduler_config()
         return [index, config]
 
+    def scheduler_set_config(config):
+        config.validate()
+        return server.raft_apply("scheduler-config", config)[0]
+
     rpc.register("Operator.SchedulerGetConfiguration", scheduler_get_config)
-    rpc.register(
-        "Operator.SchedulerSetConfiguration",
-        lambda config: server.raft_apply("scheduler-config", config)[0],
-    )
+    rpc.register("Operator.SchedulerSetConfiguration", scheduler_set_config)
     # raft introspection + snapshot trigger (operator_endpoint.go
     # RaftGetConfiguration / the `nomad operator snapshot save` surface).
     # Callers probing a SPECIFIC replica (the chaos crash harness polling

@@ -33,7 +33,6 @@ from ..structs.structs import (
     EVAL_TRIGGER_RETRY_FAILED_ALLOC,
     EVAL_TRIGGER_ROLLING_UPDATE,
     SCHED_ALG_TPU_BINPACK,
-    SCHED_ALG_TPU_BINPACK_CHUNKED,
     AllocMetric,
     AllocatedResources,
     AllocatedSharedResources,
@@ -330,24 +329,13 @@ class GenericScheduler:
         self._nodes_by_dc = by_dc
 
         # tpu_binpack: batch the whole placement list through one device scan.
-        # tpu_binpack_chunked: same entry, but the engine may run the eval
-        # through the chunked top-K throughput tier (sampled parity) when
-        # it is chunk-eligible; ineligible evals — preempting, destructive,
-        # int-mode — take the bit-parity scan exactly as tpu_binpack.
         _, sched_config = self.state.scheduler_config()
-        if sched_config is not None and sched_config.scheduler_algorithm in (
-            SCHED_ALG_TPU_BINPACK,
-            SCHED_ALG_TPU_BINPACK_CHUNKED,
+        if (
+            sched_config is not None
+            and sched_config.scheduler_algorithm == SCHED_ALG_TPU_BINPACK
         ):
             from ..tpu.integration import compute_placements_with_engine
 
-            self.chunked_tier = (
-                sched_config.scheduler_algorithm == SCHED_ALG_TPU_BINPACK_CHUNKED
-            )
-            self.chunk_k = getattr(sched_config, "chunk_k", 128)
-            self.parity_sample_rate = getattr(
-                sched_config, "parity_sample_rate", 0.0
-            )
             if compute_placements_with_engine(self, destructive, place) is True:
                 _trace_lc.set_path(self.eval.id, "device")
                 # device-built plan: eligible for the async eval-lifecycle

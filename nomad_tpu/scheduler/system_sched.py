@@ -211,18 +211,12 @@ class SystemScheduler:
         # list (the system analog of the generic engine path). The host
         # loop below remains the semantically complete fallback (and the
         # preemption path).
-        from ..structs.structs import (
-            SCHED_ALG_TPU_BINPACK,
-            SCHED_ALG_TPU_BINPACK_CHUNKED,
-        )
+        from ..structs.structs import SCHED_ALG_TPU_BINPACK
 
         _, sched_config = self.state.scheduler_config()
-        # the chunked tier only changes the generic scheduler's scan; the
-        # system forced-node pass is already one dense dispatch and stays
-        # on the bit-parity kernel under either algorithm
-        if sched_config is not None and sched_config.scheduler_algorithm in (
-            SCHED_ALG_TPU_BINPACK,
-            SCHED_ALG_TPU_BINPACK_CHUNKED,
+        if (
+            sched_config is not None
+            and sched_config.scheduler_algorithm == SCHED_ALG_TPU_BINPACK
         ):
             from ..tpu.integration import compute_system_placements_with_engine
 

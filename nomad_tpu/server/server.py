@@ -137,11 +137,6 @@ class ServerConfig:
     flight_retain: int = 1024
     flight_spill_dir: str = ""
     scheduler_algorithm: str = "tpu_binpack"
-    # chunked throughput tier (scheduler_algorithm = "tpu_binpack_chunked"):
-    # top-K chunk size per scan step, and the fraction of chunk-placed
-    # evals re-run through the bit-parity scan as a divergence spot-check
-    chunk_k: int = 128
-    parity_sample_rate: float = 0.05
     vault: Optional[object] = None  # integrations.vault.VaultConfig
     # Eval-batched device scheduling (SURVEY §2.6 row 1): up to this many
     # concurrently-scheduling evals share ONE device dispatch of the
@@ -213,6 +208,9 @@ class Server:
         name: str = "server-1",
     ) -> None:
         self.config = config or ServerConfig()
+        SchedulerConfiguration(
+            scheduler_algorithm=self.config.scheduler_algorithm
+        ).validate()
         self.name = name
         self.logger = logging.getLogger(f"nomad_tpu.server.{name}")
 
@@ -459,8 +457,6 @@ class Server:
                 SCHEDULER_CONFIG,
                 SchedulerConfiguration(
                     scheduler_algorithm=self.config.scheduler_algorithm,
-                    chunk_k=self.config.chunk_k,
-                    parity_sample_rate=self.config.parity_sample_rate,
                 ),
             )
         self._leader_generation += 1  # race-ok: leadership transitions run on the single raft notify thread

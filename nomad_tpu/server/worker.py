@@ -21,7 +21,6 @@ from ..structs.structs import (
     JOB_TYPE_BATCH,
     JOB_TYPE_SERVICE,
     SCHED_ALG_TPU_BINPACK,
-    SCHED_ALG_TPU_BINPACK_CHUNKED,
     Evaluation,
     Plan,
     PlanResult,
@@ -373,9 +372,7 @@ class Worker:
         if batcher is None:
             return None
         _, cfg = self.server.fsm.state.scheduler_config()
-        if cfg is None or cfg.scheduler_algorithm not in (
-            SCHED_ALG_TPU_BINPACK, SCHED_ALG_TPU_BINPACK_CHUNKED
-        ):
+        if cfg is None or cfg.scheduler_algorithm != SCHED_ALG_TPU_BINPACK:
             return None
         return batcher
 

@@ -1689,7 +1689,7 @@ class PlanResult:
 
 SCHED_ALG_BINPACK = "binpack"
 SCHED_ALG_TPU_BINPACK = "tpu_binpack"
-SCHED_ALG_TPU_BINPACK_CHUNKED = "tpu_binpack_chunked"
+SCHED_ALGORITHMS = (SCHED_ALG_BINPACK, SCHED_ALG_TPU_BINPACK)
 
 
 @dataclass
@@ -1706,20 +1706,23 @@ class SchedulerConfiguration:
     ``scheduler_algorithm`` selects the placement backend:
     ``binpack`` = host iterator pipeline (parity oracle),
     ``tpu_binpack`` = batched JAX engine (the default, bit-identical
-    to the host oracle),
-    ``tpu_binpack_chunked`` = chunked top-K throughput tier: up to
-    ``chunk_k`` placements of one task group per scan step, validated
-    by sampled parity (``parity_sample_rate``) instead of bit parity.
-    Preempting and otherwise chunk-ineligible evals silently fall back
-    to the bit-parity scan.
+    to the host oracle). Every boundary a configuration enters by (the
+    agent's config file, ``Operator.SchedulerSetConfiguration``, the HTTP
+    ``PUT``, ``Server.__init__``) calls ``validate()``: any other name
+    would send every eval to the host stack in silence.
     """
 
     scheduler_algorithm: str = SCHED_ALG_TPU_BINPACK
-    chunk_k: int = 128
-    parity_sample_rate: float = 0.05
     preemption_config: PreemptionConfig = field(default_factory=PreemptionConfig)
     create_index: int = 0
     modify_index: int = 0
+
+    def validate(self) -> None:
+        if self.scheduler_algorithm not in SCHED_ALGORITHMS:
+            raise ValueError(
+                f"scheduler_algorithm {self.scheduler_algorithm!r}: "
+                f"expected one of {', '.join(SCHED_ALGORITHMS)}"
+            )
 
 
 @dataclass

@@ -77,27 +77,6 @@ def _pow2ceil(x: int) -> int:
     return p
 
 
-def assert_chunk_gate(enc: EncodedEval) -> None:
-    """Dispatch-side re-assertion of the chunked tier's eligibility gate
-    (engine._chunk_eligible decides routing; this catches a bypass).
-
-    The chunk step has NO eviction scoring and passes the preemption
-    carry through untouched, so a preempting or destructive eval reaching
-    chunked dispatch would silently drop its evictions — the
-    deficit-carry would then re-ask for capacity the preemption was
-    supposed to free, over-placing on retry rounds. Such evals must fall
-    back to the bit-parity scan.
-    """
-    assert enc.pre_allocs is None, (
-        "chunked tier dispatched a preempting eval (pre_allocs present); "
-        "preemption must take the bit-parity scan"
-    )
-    assert not (np.asarray(enc.xs[2]) >= 0).any(), (
-        "chunked tier dispatched an eval with eviction steps; "
-        "destructive updates must take the bit-parity scan"
-    )
-
-
 def pad_encoded(enc: EncodedEval, n_pad: int, g_pad: int, s_pad: int,
                 v_pad: int, p_pad: int, dtype,
                 d_pad: int = 0, k_pad: Optional[int] = None,
@@ -296,7 +275,7 @@ class DeviceBatcher:
     cohort dequeued together is counted whole before its first member
     arrives; engine.compute_placements takes the token over and either
     arrives with it (``run(expected=True)``) or withdraws it
-    (``cancel_expected()``: the host stack, the chunked tier, a raise). A
+    (``cancel_expected()``: the host stack, a raise). A
     planner that announces nothing (harness and test planners, a
     scheduler's second attempt) has compute_placements announce before
     encode. A worker that has the device's answer and finds its next
@@ -490,7 +469,7 @@ class DeviceBatcher:
 
     def cancel_expected(self) -> None:
         """Withdraw one expect() (the eval places nothing, went to the
-        host stack or the chunked tier, or raised). Wakes a holding
+        host stack, or raised). Wakes a holding
         gather, which may have been waiting for this eval alone."""
         with self._wake:
             self._expected = max(0, self._expected - 1)

@@ -55,19 +55,10 @@ from .encode import (
     build_tg_spec,
     job_device_dims,
 )
-from ..utils.lock_witness import witness_lock
 
 logger = logging.getLogger("nomad_tpu.tpu.engine")
 
 MAX_SKIP = 3
-
-# Deterministic sampler for the chunked tier's parity spot checks: tests
-# reseed it to make the sampling decision reproducible. The chunked tier
-# only runs on float-mode (non-deterministic) encodes, so the RNG never
-# influences a deterministic-mode plan.
-import random as _random
-
-_PARITY_SAMPLE_RNG = _random.Random(0xC47A)
 
 # Partial OCC retries below device_min_placements still ride the device
 # when their compile bucket is already warm (see compute_placements) —
@@ -1056,7 +1047,7 @@ def _build_batched_scan(in_shardings=None):
     ``in_shardings``: optional (static, carry, xs) NamedSharding tuples
     (parallel.sharding.batched_scan_shardings) to shard the dispatch over
     an ("evals", "nodes") mesh. This entry takes the 48 stacked arrays one
-    by one: the mesh path's, bench.py's and chip_smoke.py's. The unsharded
+    by one: the mesh path's and chip_smoke.py's. The unsharded
     batcher dispatches ``_build_wire_scan``'s program over the same body."""
     import jax
 
@@ -1239,18 +1230,6 @@ class TpuPlacementEngine:
     def __init__(self) -> None:
         self._place_scan = None
         self._forced_kernel = None
-        # chunked throughput tier: compiled chunk scans keyed by chunk
-        # size, plus the sampled-parity divergence tally every bench /
-        # server artifact reads (parity_sample_stats)
-        self._chunk_scans: Dict[int, object] = {}
-        import threading as _threading
-
-        self._parity_lock = witness_lock("engine.TpuPlacementEngine._parity_lock")
-        self._parity_samples = {
-            "evals_sampled": 0,
-            "placements_checked": 0,
-            "placements_diverged": 0,
-        }
 
     @classmethod
     def shared(cls) -> "TpuPlacementEngine":
@@ -1281,16 +1260,15 @@ class TpuPlacementEngine:
         if eng is not None:
             eng._place_scan = None
             eng._forced_kernel = None
-            eng._chunk_scans.clear()
 
     def _scan_fn(self):
         if self._place_scan is None:
-            self._place_scan = _build_place_scan()  # race-ok: idempotent compile cache; duplicate builds are equal, ref swap atomic
+            self._place_scan = _build_place_scan()
         return self._place_scan
 
     def _forced_fn(self):
         if self._forced_kernel is None:
-            self._forced_kernel = _build_forced_kernel()  # race-ok: idempotent compile cache; duplicate builds are equal, ref swap atomic
+            self._forced_kernel = _build_forced_kernel()
         return self._forced_kernel
 
     def run_forced(self, enc: "EncodedEval"):
@@ -1335,167 +1313,6 @@ class TpuPlacementEngine:
         )
         _record_lone_dispatch("forced", enc, p_pad, t_stack, t_called)
         return out
-
-    # -- chunked throughput tier ---------------------------------------
-
-    @staticmethod
-    def _chunk_eligible(enc: "EncodedEval") -> Optional[str]:
-        """None when the encode may run on the chunked top-K tier; else
-        the reason it must take the bit-parity scan. The chunk step
-        models fresh, non-destructive, float-mode placements only — in
-        particular it has NO eviction scoring, so preempting evals (the
-        deficit-carry / preemption interaction) are hard-gated here and
-        re-asserted at dispatch (batcher.assert_chunk_gate)."""
-        if np.dtype(enc.dtype).kind != "f":
-            return "int mode"  # deterministic encodes carry score60s
-        if not enc.dense_ok:
-            return "not dense"
-        if enc.pre_allocs is not None:
-            return "preemption tables"
-        if enc.static[1].shape[0] != enc.n_pad:
-            return "folded reserved"  # chunk util needs full-height reserved
-        if enc.xs[1].shape[1] > 0 and bool((np.asarray(enc.xs[1]) >= 0).any()):
-            return "reschedule penalties"
-        if bool((np.asarray(enc.xs[2]) >= 0).any()):
-            return "eviction axis"
-        if enc.xs[9].ndim == 2 and enc.xs[9].shape[1] > 0:
-            return "forced nodes"
-        return None
-
-    def _chunk_fn(self, chunk: int):
-        fn = self._chunk_scans.get(chunk)
-        if fn is None:
-            fn = _build_chunk_scan(chunk)
-            self._chunk_scans[chunk] = fn  # race-ok: idempotent compile cache; duplicate builds are equal, ref swap atomic
-        return fn
-
-    def run_chunked(self, enc: "EncodedEval", chunk_k: int = 128,
-                    retry_rounds: int = 2):
-        """Run one chunk-eligible eval through the top-K throughput scan
-        and expand the per-chunk outputs back to per-placement arrays of
-        the parity scan's result shape (chosen, scores, pulls, skipped,
-        evict) so both tiers share the apply path.
-
-        Placements of one task group are interchangeable here — the
-        eligibility gate rejects every per-row feature (penalties,
-        evictions, forced nodes) — so each TG's rows fill in order from
-        its chunks' valid picks; rows left unfilled after the retry
-        rounds come back as chosen = -1 (recorded as failed placements,
-        never silently dropped).
-        """
-        from .batcher import assert_chunk_gate
-
-        assert_chunk_gate(enc)
-        import jax.numpy as jnp
-
-        tg_idx_p = np.asarray(enc.xs[0])[: enc.p]
-        counts: Dict[int, int] = {}
-        for gi in tg_idx_p.tolist():
-            counts[int(gi)] = counts.get(int(gi), 0) + 1
-        counts_by_tg = list(counts.items())
-        chunk = int(max(1, min(chunk_k, enc.n_pad)))
-        steps_tg, want = chunk_schedule(counts_by_tg, chunk,
-                                        retry_rounds=retry_rounds)
-        fn = self._chunk_fn(chunk)
-        static = tuple(jnp.asarray(a) for a in enc.static)
-        carry = tuple(jnp.asarray(a) for a in enc.carry)
-        xs = (jnp.asarray(steps_tg), jnp.asarray(want))
-        with _phases.track("device"):
-            _carry, _deficit, (top_idx, top_scores, valid, _placed) = fn(
-                enc.n_pad, static, carry, xs)
-            top_idx = np.asarray(top_idx)
-        top_scores = np.asarray(top_scores)
-        valid = np.asarray(valid)
-
-        # per-TG FIFO of the picked (node, score) pairs, chunk order
-        picked: Dict[int, list] = {gi: [] for gi, _ in counts_by_tg}
-        for si in range(steps_tg.shape[0]):
-            vs = np.nonzero(valid[si])[0]
-            if vs.size:
-                picked[int(steps_tg[si])].append(
-                    (top_idx[si, vs], top_scores[si, vs]))
-        p = enc.p
-        chosen = np.full(p, -1, np.int32)
-        scores = np.zeros(p, np.float32)
-        heads = {gi: 0 for gi in picked}
-        queues = {
-            gi: (
-                np.concatenate([n for n, _ in lst])
-                if lst else np.empty(0, np.int32),
-                np.concatenate([s for _, s in lst])
-                if lst else np.empty(0, np.float32),
-            )
-            for gi, lst in picked.items()
-        }
-        for pi in range(p):
-            gi = int(tg_idx_p[pi])
-            nodes_q, scores_q = queues[gi]
-            h = heads[gi]
-            if h < nodes_q.shape[0]:
-                chosen[pi] = nodes_q[h]
-                scores[pi] = scores_q[h]
-                heads[gi] = h + 1
-        # every chunk scores the full real node axis — report it, unlike
-        # the ring-limited parity scan's per-placement pull counts
-        pulls = np.full(p, int(enc.n_real), np.int32)
-        skipped = np.zeros(p, bool)
-        evict = np.zeros((p, 0), np.int32)
-        return chosen, scores, pulls, skipped, evict
-
-    def _maybe_sample_parity(self, enc: "EncodedEval", chosen,
-                             rate: float) -> None:
-        """Sampled-parity spot check for the chunked tier: with
-        probability ``rate`` re-run the eval through the bit-parity scan
-        and tally per-TG multiset divergence of the chosen nodes. The
-        chunked tier is NOT bit-identical by design; this bounds the
-        drift and surfaces regressions in every bench/server artifact
-        (parity_sample_stats)."""
-        from ..utils import metrics as _metrics
-
-        if rate <= 0.0 or _PARITY_SAMPLE_RNG.random() >= rate:
-            return
-        try:
-            ref_chosen = np.asarray(self.run_scan_single(enc)[0])[: enc.p]
-        except Exception:  # noqa: BLE001 — a failed spot check never
-            # fails the eval; the chunked plan already applied
-            logger.exception("sampled-parity reference scan failed")
-            return
-        got = np.asarray(chosen)[: enc.p]
-        tg_idx = np.asarray(enc.xs[0])[: enc.p]
-        from collections import Counter
-
-        diverged = 0
-        for gi in np.unique(tg_idx):
-            sel = tg_idx == gi
-            diverged += sum(
-                (Counter(got[sel].tolist())
-                 - Counter(ref_chosen[sel].tolist())).values()
-            )
-        with self._parity_lock:
-            self._parity_samples["evals_sampled"] += 1
-            self._parity_samples["placements_checked"] += int(enc.p)
-            self._parity_samples["placements_diverged"] += int(diverged)
-        _metrics.incr_counter("nomad.tpu_engine.parity_sampled")
-        if diverged:
-            _metrics.incr_counter("nomad.tpu_engine.parity_diverged",
-                                  float(diverged))
-
-    def parity_sample_stats(self) -> Dict[str, float]:
-        """Snapshot of the chunked tier's sampled-parity tally, with the
-        derived divergence rate. Recorded into every bench artifact that
-        exercises the chunked tier."""
-        with self._parity_lock:
-            out = dict(self._parity_samples)
-        checked = out["placements_checked"]
-        out["divergence_rate"] = (
-            out["placements_diverged"] / checked if checked else 0.0
-        )
-        return out
-
-    def reset_parity_samples(self) -> None:
-        with self._parity_lock:
-            for k in self._parity_samples:
-                self._parity_samples[k] = 0
 
     # ------------------------------------------------------------------
 
@@ -1574,29 +1391,9 @@ class TpuPlacementEngine:
                 return True
             self._pipeline_remember(sched, enc)
             t0 = _metrics.now()
-            # tpu_binpack_chunked: chunk-eligible evals take the top-K
-            # throughput scan; everything else — preempting, destructive,
-            # int-mode, penalized — falls back to the bit-parity dispatch
-            # below exactly as under tpu_binpack
-            use_chunked = False
-            if getattr(sched, "chunked_tier", False):
-                chunk_reason = self._chunk_eligible(enc)
-                use_chunked = chunk_reason is None
-                if not use_chunked:
-                    _metrics.incr_counter("nomad.tpu_engine.chunk_fallback")
-                    logger.debug("chunked tier ineligible (%s): %s",
-                                 wave_id[:8], chunk_reason)
-            if use_chunked and expected_held:
-                # withdraw BEFORE the long chunked scan: a phantom
-                # expectation would hold concurrent gathers open for it
-                batcher.cancel_expected()
-                expected_held = False
             try:
                 with _tlc.stage("device_wait", wave_id):
-                    if use_chunked:
-                        chosen, scores, pulls, skipped_steps, evict = self.run_chunked(
-                            enc, chunk_k=int(getattr(sched, "chunk_k", 128)))
-                    elif batcher is not None:
+                    if batcher is not None:
                         expected_held = False  # run() consumes the token
                         chosen, scores, pulls, skipped_steps, evict = batcher.run(
                             enc, expected=True)
@@ -1621,12 +1418,6 @@ class TpuPlacementEngine:
             if expected_held:
                 batcher.cancel_expected()
         _metrics.measure_since("nomad.tpu_engine.device_wait", t0)
-        if use_chunked:
-            _metrics.incr_counter("nomad.tpu_engine.chunk_dispatch")
-            self._maybe_sample_parity(
-                enc, chosen,
-                float(getattr(sched, "parity_sample_rate", 0.0)),
-            )
         t0 = _metrics.now()
         with _HOST_WORK_SEM:
             t1 = _metrics.now()
@@ -3111,217 +2902,3 @@ def example_scan_inputs(n_nodes: int = 64, n_tgs: int = 2, n_placements: int = 1
           np.zeros((n_placements, 0), np.int32),
           np.zeros((n_placements, 0), np.int32))  # forced_node: unrestricted
     return n_pad, static, init_carry, xs
-
-
-# ---------------------------------------------------------------------------
-# Chunked throughput scan: K placements of one task group per step
-# ---------------------------------------------------------------------------
-
-CHUNK_K = 128
-
-
-def _build_chunk_scan(chunk_k: int = CHUNK_K):
-    """Throughput-mode scan: each step places up to K instances of one task
-    group on the top-K scoring distinct feasible nodes.
-
-    Every chosen node is individually capacity-checked for one ask, so the
-    resulting plan is valid; scores refresh between chunks rather than
-    between single placements. This trades the reference's exact sequential
-    semantics (kept in the parity scan) for ~K x fewer sequential device
-    steps — the reference itself already subsamples candidates per placement
-    (log2 N window), so chunked top-K dominates it on both quality and speed.
-
-    A per-TG DEFICIT rides an internal carry: a chunk that places fewer
-    than asked (feasible set momentarily smaller than K) rolls the
-    shortfall into that TG's later chunks — including want=0 retry steps
-    appended by ``chunk_schedule(retry_rounds=...)`` — so large chunk
-    sizes keep exact placement counts instead of dropping the tail.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    from .intscore import (
-        FEAT_AFF_BIT,
-        FEAT_FEAS_BIT,
-        pack_presence_lanes,
-        unpack_feat_lane,
-    )
-
-    jax.config.update("jax_enable_x64", True)
-    _enable_persistent_compile_cache()
-    CHUNK = int(chunk_k)
-
-    def step(static, carry_and_deficit, x):
-        # Gather-free like the parity step (_make_step): dynamic row-selects
-        # become one-hot where+sum picks; the top-K scatter-adds become
-        # one-hot [K, N] membership sums. Exact (single non-zero term per
-        # select; top_k indices are distinct) and ~10x faster on this
-        # backend than dynamic-index gathers/scatters in a scan body.
-        carry, deficit = carry_and_deficit
-        (totals, reserved, asks, feat_packed, aff_score, desired_counts,
-         dh_job, dh_tg, limits, spread_vids, spread_desired, spread_weights,
-         spread_has_targets, spread_active, sum_spread_weights, n_real,
-         *_extra) = static
-        (used, tg_counts, job_counts, spread_counts, spread_entry, offset,
-         failed, *_cextra) = carry
-        tg_idx, want = x
-
-        n_pad = totals.shape[0]
-        g_count = asks.shape[0]
-        g = tg_idx
-        fdt = totals.dtype
-
-        iota_g = jnp.arange(g_count, dtype=jnp.int32)
-        sel_g = (iota_g == g)  # [G] one-hot of the TG
-        iota = jnp.arange(n_pad, dtype=jnp.int32)
-
-        def pick_g(arr, fill=0):
-            shape = (g_count,) + (1,) * (arr.ndim - 1)
-            return jnp.sum(jnp.where(sel_g.reshape(shape), arr, fill), axis=0)
-
-        ask = pick_g(asks)                               # [D]
-        # one packed uint8 plane carries feasibility + affinity presence
-        # (intscore.pack_feat_planes), same layout as the parity step
-        feat_g = pick_g(feat_packed)                     # [N] uint8
-        feas_g = unpack_feat_lane(feat_g, FEAT_FEAS_BIT)
-        tg_counts_g = pick_g(tg_counts)                  # [N]
-        dh_job_g = jnp.any(sel_g & dh_job)
-        dh_tg_g = jnp.any(sel_g & dh_tg)
-        desired_g = pick_g(desired_counts).astype(fdt)
-
-        util = used + reserved + ask[None, :]
-        fits = jnp.all(util <= totals, axis=-1)
-        dh_mask = jnp.where(
-            dh_job_g,
-            job_counts == 0,
-            jnp.where(dh_tg_g, ~((tg_counts_g > 0) & (job_counts > 0)), True),
-        )
-        feasible = feas_g & fits & dh_mask & (iota < n_real)
-
-        node_cpu = totals[:, DIM_CPU] - reserved[:, DIM_CPU]
-        node_mem = totals[:, DIM_MEM] - reserved[:, DIM_MEM]
-        free_cpu = 1.0 - util[:, DIM_CPU] / jnp.maximum(node_cpu, 1e-9)
-        free_mem = 1.0 - util[:, DIM_MEM] / jnp.maximum(node_mem, 1e-9)
-        binpack = jnp.clip(20.0 - (jnp.power(10.0, free_cpu) + jnp.power(10.0, free_mem)), 0.0, 18.0) / 18.0
-
-        collisions = tg_counts_g.astype(fdt)
-        anti_present = collisions > 0
-        anti = jnp.where(anti_present, -(collisions + 1.0) / desired_g, 0.0)
-
-        # shape specialization (compile-time): affinity-free workloads
-        # encode a ZERO G axis (engine.encode_eval / example_scan_inputs)
-        # and the term vanishes from the compiled step
-        if aff_score.shape[0] == 0:
-            aff = jnp.zeros(n_pad, fdt)
-            aff_p = jnp.zeros(n_pad, bool)
-        else:
-            aff = pick_g(aff_score)
-            aff_p = unpack_feat_lane(feat_g, FEAT_AFF_BIT)
-
-        vids = pick_g(spread_vids)                       # [S, N]
-        s_counts = pick_g(spread_counts)                 # [S, V]
-        desired_sv = pick_g(spread_desired)              # [S, V]
-        weights_s = pick_g(spread_weights)               # [S]
-        active_s = pick_g(spread_active, False)          # [S]
-        sum_sw_g = pick_g(sum_spread_weights)
-        v_plus = s_counts.shape[-1]
-        iota_v = jnp.arange(v_plus, dtype=jnp.int32)
-        big = jnp.finfo(fdt).max / 16.0
-        # value-id lookups as one-hot sums over V (no take_along_axis)
-        oh_vids = vids[:, None, :] == iota_v[None, :, None]  # [S, V, N]
-        used_count = jnp.sum(jnp.where(oh_vids, s_counts[:, :, None], 0.0), axis=1) + 1.0
-        d = jnp.sum(jnp.where(oh_vids, desired_sv[:, :, None], 0.0), axis=1)
-        missing = vids == v_plus - 1
-        weight_frac = weights_s[:, None] / jnp.maximum(sum_sw_g, 1e-9)
-        targeted = jnp.where(
-            d > 0.0,
-            (d - used_count) / jnp.where(d > 0.0, d, 1.0) * weight_frac,
-            jnp.where(d == 0.0, -big, -1.0),
-        )
-        per_spread = jnp.where(missing, -1.0, targeted)
-        per_spread = jnp.where(active_s[:, None], per_spread, 0.0)
-        spread_total = jnp.sum(per_spread, axis=0)
-        spread_p = spread_total != 0.0
-
-        # popcount num_terms over one packed presence plane (no reschedule
-        # penalties in chunked mode: that lane rides constant-false)
-        presence = pack_presence_lanes(
-            anti_present, jnp.zeros(n_pad, bool), aff_p, spread_p
-        )
-        num_terms = (1 + jax.lax.population_count(presence)).astype(fdt)
-        final = (binpack + anti + jnp.where(aff_p, aff, 0.0) + spread_total) / num_terms
-
-        neg_inf = -jnp.inf
-        masked = jnp.where(feasible, final, neg_inf)
-        top_scores, top_idx = jax.lax.top_k(masked, CHUNK)
-        # int sums promote to int64 under x64 — cast back to keep the
-        # carry dtypes fixed
-        want_total = (want + pick_g(deficit)).astype(jnp.int32)
-        want_eff = jnp.minimum(want_total, CHUNK)
-        valid = (jnp.arange(CHUNK, dtype=jnp.int32) < want_eff) & (top_scores > neg_inf)
-        placed = jnp.sum(valid.astype(jnp.int32)).astype(jnp.int32)
-        deficit = jnp.where(sel_g, want_total - placed, deficit).astype(jnp.int32)
-
-        # one-hot membership of the chosen nodes: top_k indices are
-        # distinct, so sel_nodes is 0/1 and the adds are exact
-        oh_sel = (iota[None, :] == top_idx[:, None]) & valid[:, None]  # [K, N]
-        sel_nodes = jnp.sum(oh_sel.astype(jnp.int32), axis=0).astype(jnp.int32)  # [N]
-        sel_nodes_f = sel_nodes.astype(fdt)
-        used = used + sel_nodes_f[:, None] * ask[None, :]
-        tg_counts = tg_counts + sel_g[:, None] * sel_nodes[None, :]
-        job_counts = job_counts + sel_nodes
-        # spread count add: per (s, v), how many chosen nodes carry value v
-        add_sv = jnp.sum(
-            jnp.where(oh_vids, sel_nodes_f[None, None, :], 0.0), axis=2
-        ) * active_s[:, None].astype(fdt)                              # [S, V]
-        spread_counts = spread_counts + jnp.where(
-            sel_g[:, None, None], add_sv[None, :, :], 0.0
-        )
-
-        new_carry = (used, tg_counts, job_counts, spread_counts, spread_entry,
-                     offset, failed, *_cextra)
-        out = (top_idx, jnp.where(valid, top_scores, 0.0), valid, placed)
-        return (new_carry, deficit), out
-
-    @partial(jax.jit, static_argnames=("n_pad",))
-    def chunk_scan(n_pad, static, init_carry, xs, deficit=None):
-        import jax.lax as lax
-
-        n_tgs = static[2].shape[0]
-        if deficit is None:
-            deficit = jnp.zeros(n_tgs, jnp.int32)
-        (carry, deficit_out), ys = lax.scan(
-            lambda c, x: step(static, c, x), (init_carry, deficit), xs
-        )
-        # deficit_out rides along so multi-phase schedules (bulk chunks →
-        # tail chunks) hand unfilled counts to the next phase
-        return carry, deficit_out, ys
-
-    return chunk_scan
-
-
-def chunk_schedule(counts_by_tg, chunk: int = CHUNK_K, retry_rounds: int = 0):
-    """Expand per-TG placement counts into (tg_idx, want) step arrays.
-
-    ``retry_rounds`` appends want=0 sweeps per TG: the scan's deficit
-    carry drains any shortfall through them (capacity freed or discovered
-    after a TG's main chunks have passed), never over-placing — a want=0
-    step with zero deficit is a no-op."""
-    # round-robin across TGs: scheduling one TG to completion before the
-    # next starves the last TGs of capacity and piles the whole deficit on
-    # them; interleaving spreads both load and shortfall evenly
-    remaining = {gi: count for gi, count in counts_by_tg}
-    tg_steps = []
-    while any(v > 0 for v in remaining.values()):
-        for gi, _count in counts_by_tg:
-            if remaining[gi] <= 0:
-                continue
-            take = min(remaining[gi], chunk)
-            tg_steps.append((gi, take))
-            remaining[gi] -= take
-    for _ in range(max(0, retry_rounds)):
-        for gi, _count in counts_by_tg:
-            tg_steps.append((gi, 0))
-    tg_idx = np.asarray([s[0] for s in tg_steps], np.int32)
-    want = np.asarray([s[1] for s in tg_steps], np.int32)
-    return tg_idx, want

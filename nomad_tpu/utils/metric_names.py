@@ -53,7 +53,7 @@ FAMILIES: Dict[str, str] = {
                       "redispatch*/slots_exhausted/backpressure/... "
                       "counters",
     "nomad.tpu_engine": "placement kernel engine: handled/fallback/"
-                        "chunk/parity/encode_cache counters + "
+                        "encode_cache counters + "
                         "encode/apply/device_wait samples",
     "nomad.trace": "eval-lifecycle trace gauges: eval_ms percentiles, "
                    "inflight, slowest_inflight_ms, "

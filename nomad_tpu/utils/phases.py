@@ -14,8 +14,8 @@ Zero overhead unless enabled; the bench enables it around its timed
 window. Phases tracked across the system path:
 
   encode         per-eval problem encoding (engine.encode_eval, GIL)
-  device         the engine's own forced-kernel / chunked-scan round
-                 trip (H2D, kernel, D2H in one bracket)
+  device         the engine's own forced-kernel round trip (H2D,
+                 kernel, D2H in one bracket)
   pad_stack      batch padding/stacking before dispatch (host)
   h2d_launch     batched dispatch: the scan call (H2D of the stacked
                  planes + launch) until it returns
@@ -37,9 +37,9 @@ window. Phases tracked across the system path:
                  decision (tpu/integration.py; engine phases nest inside)
   device_wait    worker parked in the device dispatch block — the
                  batcher's gather window + queue + device round trip
-                 (or the chunked-tier scan) until its wave's results
-                 land. r05's ~500s busy-vs-window gap lived here,
-                 untracked; device/pad_stack nest inside its union.
+                 until its wave's results land. r05's ~500s
+                 busy-vs-window gap lived here, untracked;
+                 device/pad_stack nest inside its union.
   plan_submit    worker parked on the plan queue future (worker)
   wait_index     worker parked on raft replication before snapshotting
   raft_fsm       raft log append -> FSM -> state store commit (every

@@ -69,44 +69,119 @@ def test_hcl_file_maps_reference_keys(tmp_path):
     assert cfg.telemetry_prefix == "np"
 
 
-def test_chunked_tier_config_keys(tmp_path):
-    f = tmp_path / "agent.hcl"
-    f.write_text(
-        """
+ALG_FILE = """
 server {
   enabled = true
   default_scheduler_config {
-    scheduler_algorithm = "tpu_binpack_chunked"
-    chunk_k             = 256
-    parity_sample_rate  = 0.25
+    scheduler_algorithm = "%s"
   }
 }
 """
-    )
-    cfg = load_agent_config([str(f)])
-    assert cfg.scheduler_algorithm == "tpu_binpack_chunked"
-    assert cfg.chunk_k == 256
-    assert cfg.parity_sample_rate == 0.25
 
 
-def test_chunked_tier_knobs_reach_scheduler_config():
-    # ServerConfig -> leader-seeded SchedulerConfiguration plumbing
+@pytest.fixture(scope="module")
+def alg_agent():
+    a = Agent(AgentConfig(dev_mode=True, num_schedulers=0,
+                          scheduler_algorithm="binpack", name="alg-dev"))
+    a.start()
+    yield a
+    a.shutdown()
+
+
+def _set_algorithm(agent, boundary, alg):
+    """Write ``alg`` through one of the two run-time boundaries."""
+    if boundary == "rpc":
+        from nomad_tpu.rpc.transport import RPCClient
+        from nomad_tpu.structs.structs import SchedulerConfiguration
+
+        c = RPCClient(*agent.rpc.addr)
+        try:
+            return c.call("Operator.SchedulerSetConfiguration",
+                          SchedulerConfiguration(scheduler_algorithm=alg))
+        finally:
+            c.close()
+    import urllib.request
+
+    req = urllib.request.Request(
+        agent.http_addr + "/v1/operator/scheduler/configuration",
+        data=json.dumps({"SchedulerAlgorithm": alg}).encode(), method="PUT")
+    with urllib.request.urlopen(req, timeout=30) as resp:
+        return json.loads(resp.read().decode())
+
+
+@pytest.mark.parametrize("alg", ["binpack", "tpu_binpack"])
+def test_scheduler_algorithm_accepted(alg, tmp_path, alg_agent):
+    """Both algorithms pass every boundary and reach the stored
+    configuration: file -> AgentConfig, ServerConfig -> the leader-seeded
+    SchedulerConfiguration, and the RPC and HTTP writes."""
     from nomad_tpu.server.server import Server, ServerConfig
 
-    srv = Server(ServerConfig(
-        scheduler_algorithm="tpu_binpack_chunked",
-        chunk_k=64,
-        parity_sample_rate=0.5,
-        num_schedulers=0,
-    ))
+    f = tmp_path / "agent.hcl"
+    f.write_text(ALG_FILE % alg)
+    cfg = load_agent_config([str(f)])
+    assert cfg.scheduler_algorithm == alg
+
+    srv = Server(ServerConfig(scheduler_algorithm=cfg.scheduler_algorithm,
+                              num_schedulers=0))
     try:
         srv.start()
-        _, sc = srv.fsm.state.scheduler_config()
-        assert sc.scheduler_algorithm == "tpu_binpack_chunked"
-        assert sc.chunk_k == 64
-        assert sc.parity_sample_rate == 0.5
+        assert srv.fsm.state.scheduler_config()[1].scheduler_algorithm == alg
     finally:
         srv.stop()
+
+    state = alg_agent.server.fsm.state
+    for boundary in ("rpc", "http"):
+        other = "binpack" if alg == "tpu_binpack" else "tpu_binpack"
+        _set_algorithm(alg_agent, boundary, other)
+        _set_algorithm(alg_agent, boundary, alg)
+        assert state.scheduler_config()[1].scheduler_algorithm == alg
+
+
+@pytest.mark.parametrize("alg", ["tpu_binpack_chunked", "", "spread"])
+@pytest.mark.parametrize("boundary", ["file", "rpc", "http"])
+def test_scheduler_algorithm_refused(boundary, alg, tmp_path, alg_agent):
+    """A removed or unknown algorithm is refused where it enters, with an
+    error that names the accepted values, and the stored configuration
+    stays as it was (any other string would send every eval to the host
+    stack in silence)."""
+    accepted = "binpack, tpu_binpack"
+    if boundary == "file":
+        from nomad_tpu.server.server import Server, ServerConfig
+
+        f = tmp_path / "agent.hcl"
+        f.write_text(ALG_FILE % alg)
+        with pytest.raises(ConfigError, match=accepted):
+            load_agent_config([str(f)])
+        with pytest.raises(ValueError, match=accepted):
+            Server(ServerConfig(scheduler_algorithm=alg, num_schedulers=0))
+        return
+    state = alg_agent.server.fsm.state
+    before_index, before = state.scheduler_config()
+    before_alg = before.scheduler_algorithm
+    if boundary == "rpc":
+        from nomad_tpu.rpc.transport import RPCError
+
+        with pytest.raises(RPCError, match=accepted):
+            _set_algorithm(alg_agent, "rpc", alg)
+    else:
+        import urllib.error
+
+        with pytest.raises(urllib.error.HTTPError) as exc:
+            _set_algorithm(alg_agent, "http", alg)
+        assert exc.value.code == 400
+        assert accepted in exc.value.read().decode()
+    index, after = state.scheduler_config()
+    assert (index, after.scheduler_algorithm) == (before_index, before_alg)
+
+
+def test_removed_tier_knob_is_an_unknown_key(tmp_path):
+    """default_scheduler_config is checked like every other block: the
+    removed tier's knob fails as a misspelt key does."""
+    f = tmp_path / "agent.hcl"
+    f.write_text(
+        'server {\n  default_scheduler_config {\n    chunk_k = 256\n  }\n}\n')
+    with pytest.raises(ConfigError, match="chunk_k"):
+        load_agent_config([str(f)])
 
 
 def test_json_file_and_directory_merge_order(tmp_path):

@@ -47,6 +47,90 @@ def test_cache_dir_defaults_to_fixed_path_in_checkout(monkeypatch):
     assert os.path.isdir(engine.DEFAULT_COMPILE_CACHE_DIR)
 
 
+# -- the C1M job mix (mock.C1M_TEMPLATES, read by chip_smoke.c1m_job) ------
+
+# (kind, cpu, mem, count, stanzas) of the 40 templates, in order, as the
+# parent's bench.c1m_mixed_jobs built them
+C1M_TABLE = [
+    ("service", 8, 16, 900, True), ("service", 12, 16, 950, True),
+    ("service", 16, 16, 1000, True), ("service", 20, 16, 900, True),
+    ("service", 8, 24, 950, True), ("service", 12, 24, 1000, True),
+    ("service", 16, 24, 900, True), ("service", 20, 24, 950, True),
+    ("service", 8, 32, 1000, True), ("service", 12, 32, 900, True),
+    ("service", 16, 32, 950, False), ("service", 20, 32, 1000, False),
+    ("service", 8, 48, 900, False), ("service", 12, 48, 950, False),
+    ("service", 16, 48, 1000, False), ("service", 20, 48, 900, False),
+    ("service", 8, 16, 950, False), ("service", 12, 16, 1000, False),
+    ("service", 16, 16, 900, False), ("service", 20, 16, 950, False),
+    ("service", 8, 24, 1000, False), ("service", 12, 24, 900, False),
+    ("service", 16, 24, 950, False), ("service", 20, 24, 1000, False),
+    ("service", 8, 32, 900, False), ("service", 12, 32, 950, False),
+    ("service", 16, 32, 1000, False), ("service", 20, 32, 900, False),
+    ("batch", 8, 16, 950, False), ("batch", 12, 24, 1000, False),
+    ("batch", 16, 32, 950, False), ("batch", 20, 48, 1000, False),
+    ("batch", 8, 16, 950, False), ("batch", 12, 24, 1000, False),
+    ("batch", 16, 32, 950, False), ("batch", 20, 48, 1000, False),
+    ("batch", 8, 16, 950, False), ("batch", 12, 24, 1000, False),
+    ("batch", 16, 32, 950, False), ("batch", 20, 48, 1000, False),
+]
+
+
+def test_c1m_templates_are_the_parents_forty_in_order():
+    from nomad_tpu import mock
+
+    got = [(t["kind"], t["cpu"], t["mem"], t["count"], t["stanzas"])
+           for t in mock.C1M_TEMPLATES]
+    assert got == C1M_TABLE
+    assert [k for k, *_ in got] == ["service"] * 28 + ["batch"] * 12
+    assert [st for *_, st in got] == [True] * 10 + [False] * 30
+    # every job pads into the p=1024 scan bucket (batcher._batch_dims)
+    assert all(256 < c <= 1024 and c in (900, 950, 1000)
+               for _, _, _, c, _ in got)
+
+
+def test_c1m_job_carries_its_template():
+    from nomad_tpu import mock
+
+    for tpl in mock.C1M_TEMPLATES:
+        j = mock.c1m_job(tpl, "c1m-x", tpl["count"])
+        tg = j.task_groups[0]
+        res = tg.tasks[0].resources
+        assert (j.type, res.cpu, res.memory_mb, tg.count) == (
+            tpl["kind"], tpl["cpu"], tpl["mem"], tpl["count"])
+        assert (j.id, tg.ephemeral_disk.size_mb) == ("c1m-x", 50)
+        if tpl["stanzas"]:
+            (sp,), (af,) = tg.spreads, tg.affinities
+            assert (sp.attribute, sp.weight) == ("${node.datacenter}", 50)
+            assert [(t.value, t.percent) for t in sp.spread_target] == [
+                ("dc1", 100)]
+            assert (af.ltarget, af.rtarget, af.operand, af.weight) == (
+                "${attr.kernel.name}", "linux", "=", 50)
+        else:
+            assert not tg.spreads and not tg.affinities
+
+
+def test_chip_smoke_jobs_equal_the_parents_field_for_field():
+    """sha256 over the canonical JSON of chip_smoke.c1m_job(i), i in
+    0..79 (two rounds of the table), taken at the parent commit where the
+    jobs came from bench.c1m_mixed_jobs."""
+    import hashlib
+
+    sys.path.insert(0, REPO)
+    import chip_smoke
+    from nomad_tpu.agent import jsonapi
+
+    sizes = chip_smoke.Sizes()
+    h = hashlib.sha256()
+    for i in range(80):
+        job = chip_smoke.c1m_job(sizes, i, f"c1m-{i}")
+        h.update(json.dumps(jsonapi.to_json_obj(job), sort_keys=True).encode())
+    assert h.hexdigest() == (
+        "dba6de9d3fa41b07c579c31fea5f5d70bea665d2f1716c7423e9385eea8912c6")
+    small = chip_smoke.Sizes(count_scale=0.05)
+    assert chip_smoke.c1m_job(small, 3, "x").task_groups[0].count == 45
+    assert chip_smoke.c1m_job(small, 3, "x", 7).task_groups[0].count == 7
+
+
 # -- chip_smoke.py --------------------------------------------------------
 
 def test_chip_smoke_refuses_cpu():

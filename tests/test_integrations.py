@@ -376,10 +376,12 @@ class TestScriptChecks:
                        and check("ok-check")["Status"] == "passing",
                        msg="passing script check")
             assert "healthy" in check("ok-check")["Output"]
+            # a TTL check is born critical with no output: wait for the
+            # script's own report, not for the initial state
             wait_until(lambda: check("bad-check") is not None
-                       and check("bad-check")["Status"] == "critical",
-                       msg="critical script check")
-            assert "broken" in check("bad-check")["Output"]
+                       and check("bad-check")["Status"] == "critical"
+                       and "broken" in check("bad-check")["Output"],
+                       msg="critical script check with its output")
             # script checks registered against the service, TTL-style
             cid = next(c for c, v in consul.checks.items()
                        if v["Name"] == "ok-check")

@@ -816,8 +816,7 @@ def test_parity_epoch_patched_with_spread_affinity(monkeypatch):
 
 # ---------------------------------------------------------------------------
 # Packed-mask layout (intscore packed lanes): fuzz the lane algebra the
-# fused scan step relies on, and the chunked algorithm's deterministic
-# fallback (bit-identical plans when every eval is chunk-ineligible).
+# fused scan step relies on.
 # ---------------------------------------------------------------------------
 
 
@@ -899,43 +898,3 @@ def test_packed_feat_plane_roundtrip_fuzz():
         ).reshape(presence.shape)
         expected = sum(m.astype(np.int32) for m in masks)
         assert (popcounts == expected).all()
-
-
-def test_parity_chunked_algorithm_deterministic_fallback():
-    """tpu_binpack_chunked on the deterministic harness: every eval is
-    chunk-INELIGIBLE (int-mode encode), so the tier must fall back to
-    the bit-parity scan and produce plans identical to the host oracle
-    — the preemption/deficit-carry gate exercised end to end."""
-    nodes = make_nodes(25, seed=21)
-    jobs = []
-    for i in range(3):
-        j = mock.job()
-        j.id = f"chunked-fb-{i}"
-        j.task_groups[0].count = 10
-        jobs.append(j)
-
-    plans = {}
-    for alg in ("binpack", "tpu_binpack_chunked"):
-        h = Harness()
-        h.state.scheduler_set_config(
-            h.next_index(), SchedulerConfiguration(scheduler_algorithm=alg)
-        )
-        for n in nodes:
-            h.state.upsert_node(h.next_index(), copy.deepcopy(n))
-        for job in jobs:
-            h.state.upsert_job(h.next_index(), copy.deepcopy(job))
-        for job in jobs:
-            ev = Evaluation(
-                priority=job.priority,
-                type=job.type,
-                triggered_by=EVAL_TRIGGER_JOB_REGISTER,
-                job_id=job.id,
-                namespace=job.namespace,
-            )
-            h.process("service", ev)
-        plans[alg] = (h.plans, h.evals, h.create_evals)
-
-    host_plans, _, _ = plans["binpack"]
-    ch_plans, _, _ = plans["tpu_binpack_chunked"]
-    assert len(host_plans) == len(ch_plans)
-    assert plan_assignments(host_plans) == plan_assignments(ch_plans)

@@ -1,7 +1,7 @@
 """Wave throughput: mini c1m-mixed end-to-end through the async pipeline.
 
-Tier-1 guard for the r06 perf round. The headline bench (bench.py
-bench_c1m_system) depends on three properties that used to regress
+Tier-1 guard for the r06 perf round. A full-wave fill (chip_smoke.py's
+C1M tranches) depends on three properties that used to regress
 silently:
 
   1. WAVE FORMATION — the broker/gather cadence hands workers enough
@@ -17,7 +17,7 @@ silently:
      above bought throughput by changing answers.
 
 Scale is deliberately small (2K placements over 50 nodes) so this stays
-tier-1; bench.py runs the same assertions at 1M via BENCH_r06.json.
+tier-1.
 """
 import copy
 import time

@@ -5,6 +5,7 @@ nomad/server.go:1079): multi-node clusters over real loopback TCP — the
 reference's in-process multi-server strategy (nomad/testing.go joining N
 TestServers, SURVEY §4.2).
 """
+import dataclasses
 import shutil
 import tempfile
 import time
@@ -252,6 +253,51 @@ class TestWireRaft:
             shutil.rmtree(tmp, ignore_errors=True)
 
 
+@pytest.mark.parametrize("path", ["jsonapi", "fsm_snapshot"])
+def test_stored_scheduler_config_drops_the_removed_fields(path):
+    """A SchedulerConfiguration written before the chunked tier went still
+    carries chunk_k and parity_sample_rate: both decoders drop them and
+    keep the rest."""
+    from nomad_tpu.structs.structs import (
+        PreemptionConfig,
+        SchedulerConfiguration,
+    )
+
+    if path == "jsonapi":
+        from nomad_tpu.agent import jsonapi
+
+        cfg = jsonapi.from_json_obj(SchedulerConfiguration, {
+            "SchedulerAlgorithm": "binpack", "ChunkK": 256,
+            "ParitySampleRate": 0.25,
+            "PreemptionConfig": {"BatchSchedulerEnabled": True},
+        })
+    else:
+        import msgpack
+
+        from nomad_tpu.server import wire_raft as wr
+        from nomad_tpu.state import StateStore
+
+        store = StateStore()
+        store.scheduler_set_config(5, SchedulerConfiguration(
+            scheduler_algorithm="binpack",
+            preemption_config=PreemptionConfig(batch_scheduler_enabled=True),
+        ))
+        wire = msgpack.unpackb(wr._encode_fsm_state(store.snapshot()),
+                               raw=False, strict_map_key=False)
+        wire["scheduler_config_entry"].update(
+            chunk_k=256, parity_sample_rate=0.25)
+        fsm = NomadFSM()
+        fsm.restore(wr._decode_fsm_state(
+            msgpack.packb(wire, use_bin_type=True)))
+        index, cfg = fsm.state.scheduler_config()
+        assert index == 5
+    assert cfg.scheduler_algorithm == "binpack"
+    assert cfg.preemption_config.batch_scheduler_enabled
+    assert not hasattr(cfg, "chunk_k")
+    assert not hasattr(cfg, "parity_sample_rate")
+    cfg.validate()
+
+
 class TestServerOnWireRaft:
     def test_three_servers_schedule_and_replicate(self):
         """Three Server processes-worth of runtime on wire raft: writes on
@@ -266,7 +312,14 @@ class TestServerOnWireRaft:
             peers = {
                 f"s{j}": rpcs[j].addr for j in range(3) if j != i
             }
-            rafts.append(WireRaft(rpc, peers, fast_config(f"s{i}")))
+            # three Servers' threads share one GIL (and, under xdist, the
+            # cores): a follower that hears no heartbeat for 0.15-0.3 s
+            # there is starved, not partitioned, and its election unseats
+            # the leader mid-apply. Elections here wait 1-2 s.
+            rafts.append(WireRaft(rpc, peers, dataclasses.replace(
+                fast_config(f"s{i}"),
+                election_timeout_min=1.0, election_timeout_max=2.0,
+            )))
         servers = [
             Server(ServerConfig(num_schedulers=1, deterministic=True),
                    raft=rafts[i], name=f"s{i}")
@@ -279,9 +332,14 @@ class TestServerOnWireRaft:
                 s.start()
             for r in rafts:
                 r.start()
+            # a leader that stands: its first write (the seeded scheduler
+            # configuration) is committed and on every replica
             wait_until(
-                lambda: sum(1 for r in rafts if r.state == LEADER) == 1,
-                msg="server leader",
+                lambda: sum(1 for r in rafts if r.state == LEADER) == 1
+                and all(s.fsm.state.scheduler_config()[1] is not None
+                        for s in servers),
+                timeout=30,
+                msg="a leader whose first write reached every replica",
             )
             leader = next(s for s, r in zip(servers, rafts) if r.state == LEADER)
             followers = [s for s in servers if s is not leader]
@@ -300,6 +358,7 @@ class TestServerOnWireRaft:
                     len(f.fsm.state.allocs_by_job("default", job.id, True)) == 10
                     for f in followers
                 ),
+                timeout=30,
                 msg="alloc replication to followers",
             )
         finally:
