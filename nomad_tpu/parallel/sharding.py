@@ -39,11 +39,13 @@ def make_mesh(n_devices: Optional[int] = None, eval_parallel: int = 1):
 
 
 def batched_scan_shardings(mesh):
-    """(static, carry, xs) NamedShardings for the FULLY-batched scan
+    """(static, carry, xs, p_real) NamedShardings for the FULLY-batched scan
     (engine._build_batched_scan): every array carries a leading eval axis
     (concurrent evals see different snapshots/node sets/jobs, so node
     tables batch too). Eval axis shards over "evals"; node dims over
-    "nodes"; small per-TG/spread tables replicate within an eval shard.
+    "nodes"; small per-TG/spread tables replicate within an eval shard;
+    the evals' step counts replicate everywhere, since the loop's bound is
+    their maximum.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -105,7 +107,7 @@ def batched_scan_shardings(mesh):
         ns(e, None, None),           # rev_factor [B, P, 2]
         ns(e, None, None),           # forced_node [B, P, W]
     )
-    return static, carry, xs
+    return static, carry, xs, ns()   # p_real [B]
 
 
 def batched_place_scan(mesh):

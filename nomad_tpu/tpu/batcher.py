@@ -12,10 +12,11 @@ dispatch latency. Unsharded, a dispatch crosses the host-device boundary
 once each way: the evals are written into one flat buffer per dtype
 (tpu/wire.py), the program (engine._build_wire_scan) unpacks them and
 hands back one array. With an ("evals", "nodes") mesh configured the 48
-stacked arrays go up one by one, sharded (engine._build_batched_scan).
+stacked arrays and the evals' step counts go up one by one, sharded
+(engine._build_batched_scan).
 
 Per-eval semantics are untouched: the batched scan vmaps the exact
-single-eval parity scan, so each eval's plan is identical to what the
+single-eval parity step, so each eval's plan is identical to what the
 single dispatch produces; cross-eval conflicts resolve in the plan applier
 exactly as with the reference's optimistically-concurrent workers.
 """
@@ -87,14 +88,17 @@ def pad_encoded(enc: EncodedEval, n_pad: int, g_pad: int, s_pad: int,
                 dpv_pad: Optional[int] = None,
                 fnd_pad: Optional[int] = None,
                 prec_pad: Optional[int] = None,
-                pregp_pad: Optional[int] = None) -> Tuple[tuple, tuple, tuple]:
-    """Pad one eval's arrays to the batch's shared bucketed dims.
+                pregp_pad: Optional[int] = None) -> tuple:
+    """Pad one eval's arrays to the batch's shared bucketed dims:
+    ``(static, carry, xs, p_real)``, the last the eval's own step count.
 
     Padding is semantically inert by construction:
       - nodes beyond n_real are infeasible and outside the ring window
-      - task-group slots >= g are born with failed=True in the carry
-      - placement steps beyond p index a padded (pre-failed) TG slot, so
-        the scan body skips them (skip_step) and mutates nothing
+      - task-group slots >= g (a co-batched eval has more groups) are born
+        with failed=True in the carry; no step of this eval points at one
+      - placement steps beyond p are masked by index on the device
+        (``step >= p_real``), which runs the wave's longest eval and no
+        further: the scan body skips them (skip_step) and mutates nothing
       - spread rows beyond s are inactive; the invalid vocab bucket is
         remapped from v-1 to v_pad-1
       - capacity dims beyond the eval's own (device dims of co-batched
@@ -138,7 +142,6 @@ def pad_encoded(enc: EncodedEval, n_pad: int, g_pad: int, s_pad: int,
     dd = d_pad - d0
     assert min(dn, dg, ds, dv, dp, dd) >= 0
     assert k_pad >= penalty_idx.shape[1] and aff_pad >= aff_score.shape[0]
-    assert dp == 0 or g_pad > g0  # padded steps need a pre-failed TG slot
 
     def pad(arr, widths, fill=0):
         if all(w == (0, 0) for w in widths):
@@ -213,7 +216,7 @@ def pad_encoded(enc: EncodedEval, n_pad: int, g_pad: int, s_pad: int,
         pad(f(spread_counts0), ((0, dg), (0, ds), (0, dv))),
         pad(spread_entry0, ((0, dg), (0, ds), (0, dv)), False),
         np.int32(offset0),
-        # padded TG slots are pre-failed -> padded steps are no-ops
+        # TG slots only a co-batched eval forces are pre-failed
         pad(failed0, ((0, dg),), True),
         pad(e_base0, ((0, dn if e_base0.shape[0] else 0), (0, 0)),
             _E27_NEUTRAL),
@@ -229,7 +232,7 @@ def pad_encoded(enc: EncodedEval, n_pad: int, g_pad: int, s_pad: int,
         pad(pre_counts0, ((0, pregp_pad - pre_counts0.shape[0]),), 0),
     )
     xs = (
-        pad(tg_idx, ((0, dp),), g0),  # g0 = first padded (pre-failed) slot
+        pad(tg_idx, ((0, dp),), 0),  # padded steps are masked by index
         # K axis may be zero (no reschedule history) — pad to the batch's
         # K with -1 sentinels, which match nothing
         pad(penalty_idx, ((0, dp), (0, k_pad - penalty_idx.shape[1])), -1),
@@ -245,7 +248,7 @@ def pad_encoded(enc: EncodedEval, n_pad: int, g_pad: int, s_pad: int,
         pad(rev_factor, ((0, dp), (0, fac_pad - rev_factor.shape[1])), _E27_NEUTRAL),
         pad(forced_node, ((0, dp), (0, fnd_pad - forced_node.shape[1])), -1),
     )
-    return static, carry, xs
+    return static, carry, xs, np.int32(p0)
 
 
 class _Request:
@@ -372,7 +375,8 @@ class DeviceBatcher:
             "h2d_arrays_total": 0,
             "d2h_arrays_total": 0,
             # placement steps the evals asked for against the steps the
-            # padded batch ran (b_pad x p_pad): what the buckets pad
+            # padded batch ran (b_pad x the wave's longest eval, which is
+            # the bound of the device's loop): what the batch axis pads
             "steps": 0,
             "padded_steps": 0,
             # degradations that keep the eval alive but hide a device
@@ -442,9 +446,10 @@ class DeviceBatcher:
 
     def has_warmed(self) -> bool:
         """True once at least one batch has dispatched — i.e. compile
-        buckets exist and a follow-up eval of a seen shape pays only the
-        padded-step cost. The engine's warm-bucket retry gate
-        (compute_placements) reroutes small OCC retries here."""
+        buckets exist and a follow-up eval of a seen shape pays for its
+        own steps alone (the device's loop stops at the wave's longest
+        eval). The engine's warm-bucket retry gate (compute_placements)
+        reroutes small OCC retries here."""
         with self._lock:
             return self.stats["dispatches"] > 0
 
@@ -785,20 +790,23 @@ class DeviceBatcher:
     @staticmethod
     def _batch_dims(encs: List[EncodedEval]) -> Dict[str, int]:
         """The batch's shared bucketed dims (pad_encoded's keywords)."""
-        # pow2 buckets bound recompiles; G always gets a padded slot so
-        # padded steps have a pre-failed TG to point at
-        g_pad = _pow2ceil(max(e.g for e in encs) + 1)
+        # pow2 buckets bound recompiles. A wave of one-group evals keeps
+        # a group axis of ONE, and every per-group row select of the step
+        # folds into a reshape; a mixed wave widens, as every axis does
+        g_pad = _pow2ceil(max(e.g for e in encs))
         # S stays ZERO when no co-batched eval has spreads (the
         # compiled step skips the whole spread machinery); mixed
         # batches widen — same pattern as the affinity axis
         s_raw = max(e.s for e in encs)
-        # COARSE placement-count buckets (16/64/256/1024, pow2 beyond):
-        # retried partial evals arrive at arbitrary small p, and a fresh
-        # compile (even a persistent-cache load) per pow2 bucket costs
-        # seconds — far more than the padded steps, which skip cheaply.
-        # 257..1024 collapses into ONE bucket: a mid-run OCC retry of a
-        # few hundred placements must ride the wave cohort's warm 1024
-        # bucket, not stall the dispatcher on a fresh 512 compile.
+        # COARSE placement-count buckets (64/256/1024, pow2 beyond): a
+        # fresh compile (even a persistent-cache load) per pow2 bucket
+        # costs seconds, and a padded step costs NOTHING: the device's
+        # loop stops at the wave's longest eval (p_real), so the bucket
+        # sizes only the xs and the output buffers. A retried partial
+        # eval of 1-16 placements rides the 64 program at a bound of its
+        # own p. 257..1024 collapses into ONE bucket: a mid-run OCC retry
+        # of a few hundred placements must ride the wave cohort's warm
+        # 1024 bucket, not stall the dispatcher on a fresh 512 compile.
         p_raw = max(e.p for e in encs)
         d_pad = max(e.static[0].shape[1] for e in encs)
         # absent-feature axes stay ZERO when the whole batch lacks them
@@ -814,9 +822,8 @@ class DeviceBatcher:
             "s_pad": _pow2ceil(s_raw) if s_raw else 0,
             "v_pad": _pow2ceil(max(max(e.v for e in encs), 2)),
             "p_pad": (
-                16 if p_raw <= 16 else 64 if p_raw <= 64
-                else 256 if p_raw <= 256 else 1024 if p_raw <= 1024
-                else _pow2ceil(p_raw)
+                64 if p_raw <= 64 else 256 if p_raw <= 256
+                else 1024 if p_raw <= 1024 else _pow2ceil(p_raw)
             ),
             "d_pad": d_pad,
             "k_pad": max(e.xs[1].shape[1] for e in encs),
@@ -874,11 +881,12 @@ class DeviceBatcher:
             if self.mesh is not None:
                 # ROADMAP D9: the mesh shards arrays by their node axis
                 # (parallel/sharding.py's positional specs), which a flat
-                # buffer does not have: this path keeps the 48 stacked
+                # buffer does not have: this path keeps the 49 stacked
                 # arrays up, a fence, and five copies down
                 args, b_pad, n_pad = self._pad_and_stack(
                     encs, dims, dtype, b_pad)
-                h2d_arrays, d2h_arrays = sum(len(part) for part in args), 5
+                h2d_arrays = len(jax.tree_util.tree_leaves(args))
+                d2h_arrays = 5
             else:
                 bufs = self._pack(encs, dims, dtype, b_pad)
                 args = (bufs.layout,) + bufs.arrays
@@ -908,7 +916,9 @@ class DeviceBatcher:
                              + skipped.nbytes + evict.nbytes)
         t_host = _phases.now()
         steps = sum(e.p for e in encs)
-        padded_steps = b_pad * p_pad
+        # the bound the device's loop ran: the wave's longest eval
+        n_steps = max(e.p for e in encs)
+        padded_steps = b_pad * n_steps
         t_first_enqueue = min(r.t_enqueue for r in batch)
 
         with self._lock:
@@ -946,7 +956,7 @@ class DeviceBatcher:
             eval_ids=[r.eval_id for r in batch if r.eval_id is not None],
             b=b, b_pad=b_pad, p_pad=p_pad,
             # the mesh may have widened the node axis past the batch's
-            n_pad=n_pad, steps=steps,
+            n_pad=n_pad, steps=steps, n_steps=n_steps,
             padded_steps=padded_steps, closed_by=closed_by,
             d2h_bytes=d2h_bytes, h2d_arrays=h2d_arrays,
             d2h_arrays=d2h_arrays, t_first_enqueue=t_first_enqueue,
@@ -997,6 +1007,8 @@ class DeviceBatcher:
         """The mesh path's host side: every eval padded (pad_encoded), the
         batch stacked array by array along a leading eval axis rounded to
         the mesh's eval axis, the node axis to its node axis."""
+        from jax.tree_util import tree_leaves, tree_map
+
         ep = self.mesh.shape.get("evals", 1)
         b_pad = ((b_pad + ep - 1) // ep) * ep
         nn = self.mesh.shape.get("nodes", 1)
@@ -1005,19 +1017,14 @@ class DeviceBatcher:
         one = padded[0]
 
         def call_at(b: int) -> None:
-            stacked = tuple(tuple(np.stack([a] * b) for a in part)
-                            for part in one)
+            stacked = tree_map(lambda a: np.stack([a] * b), one)
             np.asarray(self._scan_fn()(*stacked)[1][0])
 
         self._prewarm_siblings(
-            tuple((a.shape, str(a.dtype)) for part in one for a in part),
+            tuple((a.shape, str(a.dtype)) for a in tree_leaves(one)),
             b_pad, call_at)
 
         while len(padded) < b_pad:
             padded.append(one)  # inert copies; results discarded
-        stacked = tuple(
-            tuple(np.stack([p[part][i] for p in padded])
-                  for i in range(len(one[part])))
-            for part in range(3)
-        )
+        stacked = tree_map(lambda *a: np.stack(a), *padded)
         return stacked, b_pad, dims["n_pad"]

@@ -248,7 +248,7 @@ def _make_step():
         unpack_feat_lane,
     )
 
-    def step(static, carry, x):
+    def step(static, carry, x, past_end=False):
         (totals, reserved, asks, feat_packed, aff_score, desired_counts,
          dh_job, dh_tg, limits, spread_vids, spread_desired, spread_weights,
          spread_has_targets, spread_active, sum_spread_weights, n_real,
@@ -284,7 +284,9 @@ def _make_step():
             out = jnp.sum(jnp.where(sel_g.reshape(shape), arr, fill), axis=0)
             return out.astype(arr.dtype)
 
-        skip_step = jnp.any(sel_g & failed)
+        # ``past_end``: the batched program's mask of a step at or past the
+        # eval's own count (the wave's bound is its longest eval's)
+        skip_step = jnp.any(sel_g & failed) | past_end
 
         with jax.named_scope("evict_prev"):
             # -- eviction of the previous alloc (one-hot adds) -----------------
@@ -474,7 +476,6 @@ def _make_step():
             invalid_bucket = v_plus - 1
             oh_vids = vids[:, None, :] == iota_v[None, :, None]  # [S, V, N]
             current = jnp.sum(jnp.where(oh_vids, s_counts[:, :, None], 0), axis=1)
-            d = jnp.sum(jnp.where(oh_vids, desired_sv[:, :, None], 0), axis=1)
             missing = vids == invalid_bucket
             has_entries = jnp.any(s_entry[:, :invalid_bucket], axis=-1)  # [S]
 
@@ -486,6 +487,7 @@ def _make_step():
                 RECIP_BITS,
                 TERM_BITS,
                 TERM_ONE,
+                binpack_q30,
             )
 
             with jax.named_scope("binpack_score"):
@@ -496,8 +498,10 @@ def _make_step():
                 e_sel_i32 = e_sel.astype(jnp.int32)                # placement update
                 fit = i64(20 * E27_ONE) - e_sel[:, 0] - e_sel[:, 1]
                 fit = jnp.clip(fit, 0, 18 * E27_ONE)
-                # Q30 = fit * 2**30 / (18 * 2**27) = (fit*4)//9 (const divisor)
-                binpack = (fit * 4) // 9
+                # Q30 = fit * 2**30 / (18 * 2**27) = (fit*4)//9, as one
+                # multiply and one shift (an int64 // is a 30-kernel long
+                # division on the TPU: 19 of a step's 84 us, PERF.md §7)
+                binpack = binpack_q30(fit)
 
             with jax.named_scope("affinity"):
                 rsh = RECIP_BITS - TERM_BITS
@@ -514,19 +518,26 @@ def _make_step():
                 resched = jnp.where(pmask, i64(-TERM_ONE), i64(0))
 
             with jax.named_scope("spread"):
-                d64 = d.astype(i64)
-                u64 = current.astype(i64) + 1
+                d64 = desired_sv.astype(i64)                       # [S, V]
+                u64 = s_counts.astype(i64) + 1
                 w64 = weights_s.astype(i64)[:, None]
                 sw64 = jnp.maximum(sum_sw_p.astype(i64), 1)
                 # targeted boost: ((d - u)/d)*(w/sum_w) as ONE fused Q30
-                # rational, floor-rounded (d in hundredths: d = pct*count)
+                # rational, floor-rounded (d in hundredths: d = pct*count).
+                # Its operands are functions of the value id alone, so the
+                # int64 division (a 30-kernel long division on the TPU)
+                # runs over the [S, V] table and each node looks its
+                # value's boost up: one non-zero term a sum, so exact
                 t_num = (d64 - 100 * u64) * w64 * TERM_ONE
                 t_den = jnp.maximum(d64, 1) * sw64
-                targeted_raw = jnp.where(
+                targeted_sv = jnp.where(
                     d64 > 0,
                     jnp.floor_divide(t_num, t_den),
                     jnp.where(d64 == 0, i64(-BIG_FP), i64(-TERM_ONE)),
                 )
+                targeted_raw = jnp.sum(
+                    jnp.where(oh_vids, targeted_sv[:, :, None], 0), axis=1
+                )                                                  # [S, N]
 
                 # even-spread boost (same branch structure as the host);
                 # divisions by min_c (a count) via its Q45 reciprocal — [S]-
@@ -603,7 +614,9 @@ def _make_step():
             with jax.named_scope("spread"):
                 big = jnp.finfo(fdt).max / 16.0
                 used_count = current.astype(fdt) + 1.0           # [S, N]
-                df = d.astype(fdt)
+                df = jnp.sum(
+                    jnp.where(oh_vids, desired_sv[:, :, None], 0), axis=1
+                ).astype(fdt)
                 # divisor: the host SpreadIterator's weight sum accumulates
                 # across visited task groups -> passed per placement (sum_sw_p)
                 weight_frac = weights_s[:, None] / jnp.maximum(sum_sw_p, 1e-9)
@@ -973,13 +986,19 @@ def _build_forced_kernel():
 
         anti_present = tgc > 0
         if int_mode:
-            from .intscore import E27_BITS, E27_ONE, RECIP_BITS, TERM_BITS
+            from .intscore import (
+                E27_BITS,
+                E27_ONE,
+                RECIP_BITS,
+                TERM_BITS,
+                binpack_q30,
+            )
 
             e_sel = (e_base0[j].astype(i64) * e_ask[g, j].astype(i64)) \
                 >> E27_BITS                            # [P, 2]
             fit = i64(20 * E27_ONE) - e_sel[:, 0] - e_sel[:, 1]
             fit = jnp.clip(fit, 0, 18 * E27_ONE)
-            binpack = (fit * 4) // 9
+            binpack = binpack_q30(fit)
             rsh = RECIP_BITS - TERM_BITS
             q_d = jnp.floor_divide(
                 i64(1 << RECIP_BITS),
@@ -1020,18 +1039,56 @@ def _build_forced_kernel():
 
 
 def _batched_scan_fn():
-    """The eval-batched scan, not yet jitted: vmap of the per-eval parity
-    scan over a leading batch axis (the one body both programs below
-    compile)."""
+    """The eval-batched scan, not yet jitted (the one body both programs
+    below compile): ``batched(static_b, carry_b, xs_b, p_real)`` runs ONE
+    loop of the vmapped step, whose bound is the scalar ``max(p_real)``:
+    the wave's longest eval and no further. ``p_real[b]`` is each eval's
+    own step count; a shorter eval's steps from its count on are masked
+    by index (``past_end``), so padded steps need no inert task group to
+    point at. The predicate is unbatched by construction: a ``while`` with
+    a batched one would put a ``select`` on every carry.
+
+    The outputs are ``[b, p_pad, ...]`` buffers pre-filled with what a
+    skipped step returns and written at the step's index; rows at or past
+    the bound keep the fill, and no caller reads them."""
     import jax
     import jax.lax as lax
+    import jax.numpy as jnp
 
-    step = _make_step()
+    vstep = jax.vmap(_make_step())
 
-    def one(static, carry, xs):
-        return lax.scan(lambda c, x: step(static, c, x), carry, xs)
+    def batched(static_b, carry_b, xs_b, p_real):
+        # step-major, as lax.scan lays its xs and ys out: one step's row
+        # of every eval is one contiguous slice
+        xs_t = tuple(jnp.moveaxis(a, 1, 0) for a in xs_b)
 
-    return jax.vmap(one)
+        def at(i):
+            return tuple(
+                lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+                for a in xs_t)
+
+        p_pad = xs_t[0].shape[0]
+        zero = jnp.int32(0)
+        _, out_shapes = jax.eval_shape(
+            vstep, static_b, carry_b, at(zero), zero >= p_real)
+        # chosen, score, pulls, skipped, evict of a skipped step
+        fills = (-1, 0, 0, True, -1)
+        outs0 = tuple(
+            jnp.full((p_pad,) + o.shape, fill, o.dtype)
+            for o, fill in zip(out_shapes, fills))
+
+        def body(i, state):
+            carry, outs = state
+            carry, out = vstep(static_b, carry, at(i), i >= p_real)
+            return carry, tuple(
+                lax.dynamic_update_index_in_dim(buf, o, i, 0)
+                for buf, o in zip(outs, out))
+
+        bound = jnp.minimum(jnp.max(p_real), p_pad)
+        carry, outs = lax.fori_loop(zero, bound, body, (carry_b, outs0))
+        return carry, tuple(jnp.moveaxis(o, 0, 1) for o in outs)
+
+    return batched
 
 
 def _build_batched_scan(in_shardings=None):
@@ -1044,10 +1101,10 @@ def _build_batched_scan(in_shardings=None):
     independent evaluations (the device analog of the reference's
     N-scheduler-workers-per-server, nomad/server.go:1307).
 
-    ``in_shardings``: optional (static, carry, xs) NamedSharding tuples
+    ``in_shardings``: optional (static, carry, xs, p_real) NamedShardings
     (parallel.sharding.batched_scan_shardings) to shard the dispatch over
-    an ("evals", "nodes") mesh. This entry takes the 48 stacked arrays one
-    by one: the mesh path's and chip_smoke.py's. The unsharded
+    an ("evals", "nodes") mesh. This entry takes the 48 stacked arrays and
+    the evals' step counts one by one: the mesh path's. The unsharded
     batcher dispatches ``_build_wire_scan``'s program over the same body."""
     import jax
 
@@ -1055,8 +1112,8 @@ def _build_batched_scan(in_shardings=None):
     _enable_persistent_compile_cache()
     batched = _batched_scan_fn()
 
-    def body(static_b, carry_b, xs_b):
-        return batched(static_b, carry_b, xs_b)
+    def body(static_b, carry_b, xs_b, p_real):
+        return batched(static_b, carry_b, xs_b, p_real)
 
     if in_shardings is not None:
         return jax.jit(body, in_shardings=in_shardings)
@@ -1065,11 +1122,11 @@ def _build_batched_scan(in_shardings=None):
 
 def _build_wire_scan():
     """The batched scan behind the wire layout (tpu/wire.py): the program
-    takes one flat buffer per dtype, slices the 48 fields out of them by
-    the layout (a static argument: one compile per layout), runs the same
-    vmapped scan, and returns ONE int32 array holding chosen, scores,
-    pulls, skipped and evict in bit-exact lanes. The final carry, which no
-    caller of the batcher reads, is not returned. Still jitted as ``body``:
+    takes one flat buffer per dtype, slices the 48 fields and the evals'
+    step counts out of them by the layout (a static argument: one compile
+    per layout), runs the same bounded loop, and returns ONE int32 array
+    holding chosen, scores, pulls, skipped and evict in bit-exact lanes.
+    The final carry, which no caller of the batcher reads, is not returned. Still jitted as ``body``:
     benchmark/harness/scan.py finds the scan by ``jit_body``."""
     import jax
     import jax.numpy as jnp
@@ -1081,8 +1138,7 @@ def _build_wire_scan():
     batched = _batched_scan_fn()
 
     def body(layout, *buffers):
-        static_b, carry_b, xs_b = wire.unpack(layout, buffers, jnp)
-        _carry, outs = batched(static_b, carry_b, xs_b)
+        _carry, outs = batched(*wire.unpack(layout, buffers, jnp))
         return wire.pack_outputs(layout, *outs)
 
     return jax.jit(body, static_argnums=0)
@@ -1209,7 +1265,10 @@ def _record_lone_dispatch(source: str, enc: "EncodedEval", p_pad: int,
     goes around the batcher — the forced kernel, the single scan — with
     the fields it has: one eval (the one whose stage is open on this
     thread), no gather, no fence between the kernel and the copy back
-    (``t_ready`` stays None). Called once the outputs are numpy arrays."""
+    (``t_ready`` stays None). Called once the outputs are numpy arrays.
+    These paths keep their own padding (the device is told no ``p_real``):
+    ``p_pad`` is what the device computed, the forced kernel's pow2 bucket
+    in one pass or the single scan's ``enc.p`` steps, none of them padded."""
     from ..trace import lifecycle as _tlc
 
     t_host = _phases.now()
@@ -1218,7 +1277,7 @@ def _record_lone_dispatch(source: str, enc: "EncodedEval", p_pad: int,
         wave=_tlc.next_wave(), source=source,
         eval_ids=[eval_id] if eval_id is not None else [],
         b=1, b_pad=1, p_pad=p_pad, n_pad=enc.n_pad, steps=enc.p,
-        padded_steps=p_pad, t_start=t_stack, t_stack=t_stack,
+        n_steps=p_pad, padded_steps=p_pad, t_start=t_stack, t_stack=t_stack,
         t_called=t_called, t_host=t_host, t_handed=t_host,
     )
 
@@ -1361,9 +1420,10 @@ class TpuPlacementEngine:
                 # Warm-bucket retry ride-along: a partial OCC retry (the
                 # tail of a plan-rejected eval) is usually a few placements
                 # of a job shape whose compile bucket is ALREADY warm from
-                # the first pass — padding it into that bucket costs
-                # nothing, while the host fallback re-walks the ranking
-                # iterators per placement. Only reroute when the batcher
+                # the first pass — it rides that very program, and the
+                # device's loop runs its own few steps and no padded one,
+                # while the host fallback re-walks the ranking iterators
+                # per placement. Only reroute when the batcher
                 # has completed at least one batch (so buckets exist) and
                 # the retry isn't trivially small.
                 if (

@@ -27,7 +27,9 @@ Hence the exponential is INCREMENTAL-MULTIPLICATIVE:
              (the evicted node is known at encode time)
   score      E_sel = (e_base * e_ask) >> 27 per dim; BestFit-v3 =
              clip(20*2**27 - Ec - Em, 0, 18*2**27); Q30 term =
-             (fit * 4) // 9 (constant divisor — lowered to mult+shift)
+             (fit * 4) // 9, a constant divisor that the device computes
+             as one multiply and one shift (``binpack_q30``: the TPU's
+             compiler lowers an int64 ``//`` to a 30-kernel long division)
 
 Numeric layout
   x (free fraction)   Q24, x_q = floor(x * 2**24), clamped to [-2, 1]
@@ -173,6 +175,24 @@ def binpack_fp_from_e(ec: int, em: int) -> int:
     fit = 20 * E27_ONE - int(ec) - int(em)
     fit = max(0, min(18 * E27_ONE, fit))
     return (fit * 4) // 9
+
+
+# 9 * _NINTH == 2**33 + 1: for 0 <= fit < 2**33, (fit * _NINTH) >> 33 is
+# floor(fit / 9) exactly. With fit = 9a + r the product is a * 2**33 +
+# (a + r * _NINTH), and the bracket stays under 2**33 while fit does.
+_NINTH = ((1 << 33) + 1) // 9
+
+
+def binpack_q30(fit):
+    """``(fit * 4) // 9`` for ``0 <= fit <= 18 * E27_ONE`` (under 2**32,
+    so the product stays under 2**62) without a division: the quotient
+    and remainder of ``fit`` by 9 from one multiply and one shift, then
+    ``4a + (4r) // 9`` with ``(4r) // 9 == (116 r) >> 8`` for r in 0..8.
+    Takes an int64 array of numpy or jax, or a Python int; bit for bit
+    ``binpack_fp_from_e``'s value (tests/test_intscore.py)."""
+    a = (fit * _NINTH) >> 33
+    r = fit - 9 * a
+    return 4 * a + ((r * 116) >> 8)
 
 
 def e_sel_py(e_base: int, e_ask: int) -> int:

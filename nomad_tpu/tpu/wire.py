@@ -2,12 +2,13 @@
 
 Each array a dispatch sends or fetches by itself costs host time whatever
 its size (65 us up, 400 us down on the v5e), and the scan takes 26 static
-+ 12 carry + 10 xs arrays and returns five. So the 48 fields travel as ONE
-flat buffer per dtype, and the five results come back as ONE array. ``FIELDS`` is the single table both sides
-read: ``pack`` (host, numpy) writes each eval's arrays straight into
-their padded slots of the preallocated buffers, and ``unpack`` (inside
-the jitted program, or numpy in the tests) slices the buffers back into
-the ``(static_b, carry_b, xs_b)`` tuples the vmapped scan takes. Packing
++ 12 carry + 10 xs arrays and each eval's step count, and returns five. So
+the 49 fields travel as ONE flat buffer per dtype, and the five results
+come back as ONE array. ``FIELDS`` is the single table both sides read:
+``pack`` (host, numpy) writes each eval's arrays straight into their
+padded slots of the preallocated buffers, and ``unpack`` (inside the
+jitted program, or numpy in the tests) slices the buffers back into the
+``(static_b, carry_b, xs_b, p_real)`` the batched scan takes. Packing
 moves bits and computes nothing: every fill, remap and cast below is
 ``batcher.pad_encoded``'s, which stays as the reference the tests hold
 this module to (and as the mesh path's own padder).
@@ -51,7 +52,7 @@ class Field(NamedTuple):
 
 
 def _fields() -> Tuple[Field, ...]:
-    s, c, x = "static", "carry", "xs"
+    s, c, x, b = "static", "carry", "xs", "bound"
     i32 = np.int32
     return (
         Field(s, "totals", MODE, ("n", "d")),
@@ -94,7 +95,8 @@ def _fields() -> Tuple[Field, ...]:
         Field(c, "spread_counts0", MODE, ("g", "s", "v")),
         Field(c, "spread_entry0", None, ("g", "s", "v"), False),
         Field(c, "offset0", i32, ()),
-        # padded TG slots are pre-failed -> padded steps are no-ops
+        # a TG slot that only a co-batched eval of more groups forces is
+        # pre-failed (no step of this eval points at it)
         Field(c, "failed0", None, ("g",), True),
         Field(c, "e_base0", None, ("n?", "="), _E27_NEUTRAL),
         Field(c, "dp_counts0", None, ("dpd", "dpv")),
@@ -104,8 +106,8 @@ def _fields() -> Tuple[Field, ...]:
         Field(c, "pre_remaining0", None, ("n_if_prec", "=")),
         Field(c, "pre_counts0", None, ("pregp",)),
 
-        # padded steps point at the eval's first padded (pre-failed) slot
-        Field(x, "tg_idx", None, ("p",), "g"),
+        # padded steps are masked by index (p_real) whatever they hold
+        Field(x, "tg_idx", None, ("p",)),
         Field(x, "penalty_idx", None, ("p", "k"), -1),
         Field(x, "evict_node", None, ("p",), -1),
         Field(x, "evict_res", MODE, ("p", "evd")),
@@ -115,12 +117,16 @@ def _fields() -> Tuple[Field, ...]:
         Field(x, "ev_factor", None, ("p", "fac"), _E27_NEUTRAL),
         Field(x, "rev_factor", None, ("p", "fac"), _E27_NEUTRAL),
         Field(x, "forced_node", None, ("p", "fnd"), -1),
+
+        # the eval's own step count (enc.p): the device runs the wave's
+        # longest eval and masks each eval's steps from its count on
+        Field(b, "p_real", i32, ()),
     )
 
 
 FIELDS = _fields()
 assert [f.part for f in FIELDS] == (
-    ["static"] * N_STATIC + ["carry"] * N_CARRY + ["xs"] * N_XS)
+    ["static"] * N_STATIC + ["carry"] * N_CARRY + ["xs"] * N_XS + ["bound"])
 _SPREAD_VIDS = next(i for i, f in enumerate(FIELDS) if f.name == "spread_vids")
 _DP_VIDS = next(i for i, f in enumerate(FIELDS) if f.name == "dp_vids")
 _DP_COUNTS0 = next(i for i, f in enumerate(FIELDS) if f.name == "dp_counts0")
@@ -128,7 +134,8 @@ _DP_COUNTS0 = next(i for i, f in enumerate(FIELDS) if f.name == "dp_counts0")
 
 def eval_arrays(enc) -> tuple:
     """The eval's arrays in ``FIELDS`` order."""
-    arrays = tuple(enc.static) + tuple(enc.carry) + tuple(enc.xs)
+    arrays = (tuple(enc.static) + tuple(enc.carry) + tuple(enc.xs)
+              + (np.int32(enc.p),))
     if (len(enc.static), len(enc.carry), len(enc.xs)) != (
             N_STATIC, N_CARRY, N_XS):
         raise ValueError(
@@ -156,7 +163,7 @@ def _padded_shape(field: Field, own: tuple, dims: Dict[str, int]) -> tuple:
 
 
 def shape_key(enc, dims: Dict[str, int], dtype) -> tuple:
-    """``((padded shape, dtype name), ...)`` of one eval's 48 fields in a
+    """``((padded shape, dtype name), ...)`` of one eval's 49 fields in a
     dispatch of these dims: what the batcher keys its compiled shapes and
     its layouts on. Conditional axes are read off this eval."""
     mode = np.dtype(dtype)
@@ -200,7 +207,7 @@ def _on_the_wire(flat, slot: Slot, b_pad: int, xp):
 
 
 class WireLayout:
-    """Offsets of the 48 fields in the per-dtype buffers of one padded
+    """Offsets of the 49 fields in the per-dtype buffers of one padded
     shape at one batch bucket. Computed once per (shape key, b_pad) and
     cached by the batcher; hashable, so the jitted program takes it as a
     static argument and compiles once per layout. ``bool`` fields ride
@@ -275,12 +282,10 @@ class WireBuffers:
             for slot in layout.slots)
 
 
-def _fill(field: Field, enc, layout: WireLayout):
+def _fill(field: Field, layout: WireLayout):
     fill = field.fill
     if not isinstance(fill, str):
         return fill
-    if fill == "g":
-        return enc.g
     return (layout.v_pad if fill == "v_pad-1" else layout.dpv_pad) - 1
 
 
@@ -306,7 +311,7 @@ def pack(bufs: WireBuffers, encs: Sequence) -> None:
                 if src.ndim != len(shape) and src.size:
                     raise ValueError(
                         f"{field.name}: shape {src.shape} into {shape}")
-                fill = _fill(field, enc, layout)
+                fill = _fill(field, layout)
                 if src.size == 0:
                     view[bi] = fill
                     continue
@@ -330,10 +335,10 @@ def pack(bufs: WireBuffers, encs: Sequence) -> None:
                 view[b:] = view[0]
 
 
-def unpack(layout: WireLayout, arrays: Sequence, xp) -> Tuple[tuple, tuple, tuple]:
-    """The ``(static_b, carry_b, xs_b)`` the vmapped scan takes, as slices
-    of the group buffers. ``xp`` is ``jax.numpy`` inside the program and
-    ``numpy`` on the host: the same code reads the table either way."""
+def unpack(layout: WireLayout, arrays: Sequence, xp) -> tuple:
+    """The ``(static_b, carry_b, xs_b, p_real)`` the batched scan takes, as
+    slices of the group buffers. ``xp`` is ``jax.numpy`` inside the program
+    and ``numpy`` on the host: the same code reads the table either way."""
     out = []
     for slot in layout.slots:
         if slot.size == 0:
@@ -344,8 +349,9 @@ def unpack(layout: WireLayout, arrays: Sequence, xp) -> Tuple[tuple, tuple, tupl
         if slot.dtype == np.bool_:
             flat = flat != 0
         out.append(_on_the_wire(flat, slot, layout.b_pad, xp))
+    xs_end = N_STATIC + N_CARRY + N_XS
     return (tuple(out[:N_STATIC]), tuple(out[N_STATIC:N_STATIC + N_CARRY]),
-            tuple(out[N_STATIC + N_CARRY:]))
+            tuple(out[N_STATIC + N_CARRY:xs_end]), out[xs_end])
 
 
 def pack_outputs(layout: WireLayout, chosen, scores, pulls, skipped, evict):

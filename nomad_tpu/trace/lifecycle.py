@@ -541,8 +541,8 @@ def pipeline_record(ring: str, wave_id: str, t0: float, t1: float) -> None:
 #: kernel and the single scan have no gather) leaves it None
 DISPATCH_FIELDS = (
     "wave", "source", "batcher", "eval_ids", "b", "b_pad", "p_pad", "n_pad",
-    "steps", "padded_steps", "closed_by", "d2h_bytes", "h2d_arrays",
-    "d2h_arrays",
+    "steps", "n_steps", "padded_steps", "closed_by", "d2h_bytes",
+    "h2d_arrays", "d2h_arrays",
     "t_first_enqueue", "t_start", "t_stack", "t_called", "t_ready",
     "t_host", "t_handed",
 )

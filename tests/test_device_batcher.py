@@ -9,6 +9,7 @@ plans to the host pipeline.
 """
 import copy
 import random
+import re
 import threading
 import time
 
@@ -120,7 +121,7 @@ class TestBatchedScanParity:
 
     def test_pad_encoded_shapes(self):
         enc = synthetic_enc(10, 2, 4, n_spreads=1, seed=0)
-        static, carry, xs = pad_encoded(
+        static, carry, xs, p_real = pad_encoded(
             enc, n_pad=32, g_pad=4, s_pad=2, v_pad=8, p_pad=8,
             dtype=np.float64,
         )
@@ -132,7 +133,10 @@ class TestBatchedScanParity:
         assert carry[6].shape == (4,)              # failed
         assert carry[6][enc.g:].all()              # padded TGs pre-failed
         assert xs[0].shape == (8,)
-        assert (xs[0][enc.p:] == enc.g).all()      # padded steps -> failed TG
+        # padded steps point at no pre-failed slot: the device masks them
+        # by index, from the eval's own count on
+        assert (xs[0][enc.p:] == 0).all()
+        assert p_real == enc.p and p_real.dtype == np.int32
         # remapped invalid vocab bucket
         assert (static[9] <= 7).all()
         assert (static[9][:, :, enc.n_pad:] == 7).all()
@@ -718,11 +722,16 @@ def _case_record_stamps_monotone_and_steps_padded():
         for d in recs:
             stamps = [d[k] for k in DISPATCH_STAMPS]
             assert stamps == sorted(stamps), d
-            assert 0 < d["steps"] <= d["padded_steps"] == d["b_pad"] * d["p_pad"]
+            # the device ran the wave's longest eval and no further
+            assert d["n_steps"] <= d["p_pad"] == 64
+            assert 0 < d["steps"] <= d["padded_steps"] == d["b_pad"] * d["n_steps"]
             assert d["b"] <= d["b_pad"] and d["n_pad"] >= 64
             assert d["d2h_bytes"] > 0 and d["closed_by"] in (
                 "full", "window", "nothing_announced", "demand_drained")
         assert sum(d["steps"] for d in recs) == 5 + 20 + 9
+        assert max(d["n_steps"] for d in recs) == 20
+        if len(recs) == 1:
+            assert recs[0]["padded_steps"] == 4 * 20
         with batcher._lock:
             stats = dict(batcher.stats)
         assert stats["steps"] == 34
@@ -799,13 +808,15 @@ def _case_scan_is_jit_body_and_carries_scope_names():
     enc = synthetic_enc(64, 2, 16, seed=7, dtype=np.float32)
     stacked = [tuple(np.stack([np.asarray(a)] * 2) for a in part)
                for part in (enc.static, enc.carry, enc.xs)]
-    lowered = _build_batched_scan().lower(*stacked)
+    lowered = _build_batched_scan().lower(
+        *stacked, np.full(2, enc.p, np.int32))
     # benchmark/harness/scan.py finds the program by this name
     assert lowered.as_text().splitlines()[0].startswith("module @jit_body")
     debug = lowered.as_text(debug_info=True)
     for scope in ("row_select", "feasibility", "affinity", "spread",
                   "binpack_score", "score_mean", "select", "carry_update"):
-        assert f'loc("{scope}/' in debug, scope
+        # e.g. loc("jit(body)/while/body/vmap(row_select)/...")
+        assert re.search(rf'loc\("[^"]*\b{scope}\b', debug), scope
     # metadata only: no scope name reaches the program text itself
     assert "binpack_score" not in lowered.as_text()
 
@@ -839,14 +850,15 @@ def _case_a_dispatch_crosses_the_boundary_once_each_way():
             assert 2 <= d["h2d_arrays"] <= 4 and d["d2h_arrays"] == 1, d
             stamps = [d[k] for k in DISPATCH_STAMPS]
             assert None not in stamps and stamps == sorted(stamps), d
-            assert d["p_pad"] == 16
+            # a 5-step eval rides the 64 program at a bound of 5
+            assert (d["p_pad"], d["n_steps"]) == (64, 5)
         # int32 mode: int32 + uint8; float modes add the float group
         assert sorted(d["h2d_arrays"] for d in recs) == [2, 3, 3]
         # the one array down: chosen, pulls, skipped and the scores in
         # int32 lanes (one for float32, two for int64), or, for the
         # float64 pair, four float64 lanes
         assert sorted(d["d2h_bytes"] for d in recs) == [
-            16 * 4 * 4, 16 * 5 * 4, 2 * 16 * 4 * 8]
+            64 * 4 * 4, 64 * 5 * 4, 2 * 64 * 4 * 8]
         with batcher._lock:
             stats = dict(batcher.stats)
         assert stats["h2d_arrays_total"] == 8 and stats["d2h_arrays_total"] == 3
@@ -859,7 +871,7 @@ def _case_a_dispatch_crosses_the_boundary_once_each_way():
         batcher.stop()
 
 
-def _case_the_mesh_path_still_sends_48_up_and_five_down():
+def _case_the_mesh_path_sends_49_up_and_five_down():
     import jax
 
     if len(jax.devices()) < 4:
@@ -872,10 +884,12 @@ def _case_the_mesh_path_still_sends_48_up_and_five_down():
         run_concurrent(batcher, [synthetic_enc(32, 2, 6, seed=s)
                                  for s in (11, 12)])
         (d,) = _dispatches_of(batcher)
-        assert (d["h2d_arrays"], d["d2h_arrays"]) == (48, 5)
+        # the 48 stacked arrays and the evals' step counts
+        assert (d["h2d_arrays"], d["d2h_arrays"]) == (49, 5)
+        assert (d["n_steps"], d["padded_steps"]) == (6, d["b_pad"] * 6)
         stamps = [d[k] for k in DISPATCH_STAMPS]
         assert None not in stamps and stamps == sorted(stamps)
-        assert batcher.dispatch_profile()["h2d_arrays_avg"] == 48.0
+        assert batcher.dispatch_profile()["h2d_arrays_avg"] == 49.0
     finally:
         batcher.stop()
 
@@ -891,16 +905,17 @@ def _case_the_wire_scan_is_jit_body_too():
     lowered = _build_wire_scan().lower(layout, *bufs.arrays)
     # benchmark/harness/scan.py finds the program by this name
     assert lowered.as_text().splitlines()[0].startswith("module @jit_body")
-    assert 'loc("binpack_score/' in lowered.as_text(debug_info=True)
+    assert re.search(r'loc\("[^"]*\bbinpack_score\b',
+                     lowered.as_text(debug_info=True))
     # one flat buffer per dtype in, one int32 array out
     assert len(lowered.in_avals[0]) == len(layout.groups) == 3
     out = lowered.out_info
-    assert out.dtype == np.int32 and out.shape == (2, 16 * 4)
+    assert out.dtype == np.int32 and out.shape == (2, 64 * 4)
 
 
 @pytest.mark.parametrize("case", [
     _case_a_dispatch_crosses_the_boundary_once_each_way,
-    _case_the_mesh_path_still_sends_48_up_and_five_down,
+    _case_the_mesh_path_sends_49_up_and_five_down,
     _case_the_wire_scan_is_jit_body_too,
     _case_record_stamps_monotone_and_steps_padded,
     _case_gather_phase_covers_a_held_gather,
@@ -1060,8 +1075,9 @@ def _wire_id(case):
 @pytest.mark.parametrize("case", _WIRE_CASES, ids=_wire_id)
 def test_wire_pack_then_unpack_is_pad_and_stack(wire_evals, case):
     """The packer followed by the unpacker yields, bit for bit, shape for
-    shape and dtype for dtype, the 48 arrays ``pad_encoded`` + ``np.stack``
-    yield — into buffers that held another batch before."""
+    shape and dtype for dtype, the 48 arrays and the step counts that
+    ``pad_encoded`` + ``np.stack`` yield — into buffers that held another
+    batch before."""
     names, b_pad = case
     encs = [wire_evals[n] for n in names]
     dims = DeviceBatcher._batch_dims(encs)
@@ -1082,6 +1098,21 @@ def test_wire_pack_then_unpack_is_pad_and_stack(wire_evals, case):
             where = f"{part_name}[{i}]"
             assert have.dtype == want.dtype, where
             np.testing.assert_array_equal(have, want, err_msg=where)
+    assert len(got) == len(padded[0]) == 4
+    want = np.stack([p[3] for p in padded])
+    assert got[3].dtype == want.dtype == np.int32
+    np.testing.assert_array_equal(got[3], want, err_msg="p_real")
+    np.testing.assert_array_equal(got[3][:len(encs)], [e.p for e in encs])
+
+
+def _assert_same_bits(have, want, where):
+    """Same dtype, same shape, same bits (a float's NaN or not)."""
+    assert have.dtype == want.dtype, where
+    assert have.shape == want.shape, where
+    if want.dtype.kind == "f":
+        want, have = (np.ascontiguousarray(a).view(f"i{a.dtype.itemsize}")
+                      for a in (want, have))
+    np.testing.assert_array_equal(have, want, err_msg=where)
 
 
 @pytest.mark.parametrize("case", _WIRE_CASES, ids=_wire_id)
@@ -1114,10 +1145,187 @@ def test_wire_run_returns_what_the_single_scan_returns(wire_evals, case):
                 have = have[:, :want.shape[1]]
                 if not want.size:
                     continue
-            assert have.dtype == want.dtype, where
-            assert have.shape == want.shape, where
-            if want.dtype.kind == "f":   # bit for bit, NaN or not
-                want, have = (a.view(f"i{a.dtype.itemsize}")
-                              for a in (np.ascontiguousarray(want),
-                                        np.ascontiguousarray(have)))
-            np.testing.assert_array_equal(have, want, err_msg=where)
+            _assert_same_bits(have, want, where)
+
+
+# ---------------------------------------------------------------------------
+# the bounded loop (engine._batched_scan_fn): the device is told each eval's
+# real step count, runs the wave's longest eval and no further, and needs no
+# pre-failed task group for padded steps to point at. The plain reference is
+# vmap(lax.scan) over the same step under the padding the batcher had before.
+# ---------------------------------------------------------------------------
+
+
+def _scan_under_the_old_padding(encs, dtype):
+    """The five outputs ``[b, p_pad, ...]`` of ``vmap(lax.scan)`` over the
+    one step, every eval padded as it was before the device knew
+    ``p_real``: a group axis one slot wider than the widest eval, that
+    slot born failed and every padded step pointing at it, in the
+    16/64/256/1024 step buckets; every padded step runs."""
+    import jax
+    from jax import lax
+    from nomad_tpu.tpu.engine import _build_place_scan, _make_step
+
+    _build_place_scan()   # x64 on before any array is made
+    dims = DeviceBatcher._batch_dims(encs)
+    g_pad = _pow2ceil(max(e.g for e in encs) + 1)
+    p_raw = max(e.p for e in encs)
+    dims.update(
+        g_pad=g_pad, aff_pad=g_pad if dims["aff_pad"] else 0,
+        p_pad=(16 if p_raw <= 16 else 64 if p_raw <= 64
+               else 256 if p_raw <= 256 else 1024))
+    padded = []
+    for e in encs:
+        static, carry, xs, _p_real = pad_encoded(e, dtype=dtype, **dims)
+        tg_idx = xs[0].copy()
+        tg_idx[e.p:] = e.g   # the eval's first padded, pre-failed slot
+        assert carry[6][e.g:].all()
+        padded.append((static, carry, (tg_idx,) + tuple(xs[1:])))
+    stacked = jax.tree_util.tree_map(lambda *a: np.stack(a), *padded)
+    step = _make_step()
+    scan = jax.jit(jax.vmap(lambda static, carry, xs: lax.scan(
+        lambda c, x: step(static, c, x), carry, xs)))
+    _carry, outs = scan(*stacked)
+    return [np.asarray(o) for o in outs]
+
+
+def _scan_as_dispatched(encs, dtype, b_pad):
+    """The same evals through the program a dispatch runs: packed, the
+    bounded loop, one array down, split."""
+    from nomad_tpu.tpu.engine import _build_wire_scan
+
+    dims = DeviceBatcher._batch_dims(encs)
+    layout = wire.WireLayout(wire.shape_key(encs[0], dims, dtype), b_pad, dims)
+    bufs = wire.WireBuffers(layout)
+    wire.pack(bufs, encs)
+    host = np.asarray(_build_wire_scan()(layout, *bufs.arrays))
+    return dims, wire.split_outputs(layout, host)
+
+
+def _random_wave(wire_evals, seed, mode, always=()):
+    """1-6 evals of one dtype drawn from the seed: synthetic ones of 1-64
+    steps, 1-3 task groups, 0-2 spreads and 8-200 nodes, and evals the
+    real encoder made (an affinity, a distinct_property, a destructive
+    update's evictions, preemption candidates); ``always`` names members
+    the wave has whatever the draw."""
+    rng = np.random.default_rng([seed, 0xB0D])
+    dtype = np.dtype(mode).type
+    pool = [n for n in _ENCODED if n.startswith(mode)]
+    encs = [wire_evals[n] for n in always]
+    for _ in range(int(rng.integers(1, 7)) - len(encs)):
+        if rng.random() < 0.3:
+            encs.append(wire_evals[pool[int(rng.integers(len(pool)))]])
+        else:
+            encs.append(synthetic_enc(
+                int(rng.integers(8, 201)), int(rng.integers(1, 4)),
+                int(rng.integers(1, 65)), n_spreads=int(rng.integers(0, 3)),
+                seed=int(rng.integers(1 << 30)), dtype=dtype))
+    order = rng.permutation(len(encs))
+    return [encs[i] for i in order], dtype
+
+
+_BOUND_CASES = [
+    (1, "int32", ()), (2, "int32", ()), (3, "int32", ()),
+    (4, "int32", ("int32-affinity",)), (5, "int32", ("int32-evict",)),
+    (6, "int32", ("int32-preempt-high",)),
+    (7, "int32", ("int32-distinct", "int32-spread")),
+    (8, "int32", ("int32-plain", "int32-evict", "int32-preempt-high",
+                  "int32-affinity")),
+    (9, "float32", ()), (10, "float32", ("float32-evict",)),
+    (11, "float32", ("float32-affinity", "float32-spread")),
+]
+
+
+@pytest.mark.parametrize(
+    "case", _BOUND_CASES,
+    ids=lambda c: f"wave{c[0]}-{c[1]}" + "".join(
+        "+" + n.split("-", 1)[1] for n in c[2]))
+def test_bounded_loop_equals_the_scan_it_replaces(wire_evals, case):
+    """For random waves of mixed p, mixed G, with and without spreads,
+    affinities, evictions and preemption candidates: on every row below
+    its eval's own step count, the dispatched program's five outputs are,
+    bit for bit, those of ``vmap(lax.scan)`` under the old padding."""
+    seed, mode, always = case
+    encs, dtype = _random_wave(wire_evals, seed, mode, always)
+    b_pad = DeviceBatcher(max_batch=8)._bucket(len(encs))
+    want = _scan_under_the_old_padding(encs, dtype)
+    dims, have = _scan_as_dispatched(encs, dtype, b_pad)
+    assert dims["g_pad"] == _pow2ceil(max(e.g for e in encs))
+    assert dims["p_pad"] == 64
+    names = ("chosen", "scores", "pulls", "skipped", "evict")
+    for bi, enc in enumerate(encs):
+        for name, w, h in zip(names, want, have):
+            _assert_same_bits(h[bi, :enc.p], w[bi, :enc.p],
+                              f"eval {bi} (p={enc.p}, g={enc.g}) {name}")
+        # past its own count an eval's rows are skipped steps' or the fill
+        assert have[3][bi, enc.p:].all() and (have[0][bi, enc.p:] == -1).all()
+
+
+def _case_padded_steps_are_the_batch_times_the_longest_eval():
+    batcher = DeviceBatcher(max_batch=8, window_ms=300.0)
+    try:
+        encs = [synthetic_enc(24, 1, p, seed=p) for p in (3, 37, 9)]
+        run_concurrent(batcher, encs)
+        (d,) = _dispatches_of(batcher)
+        assert (d["b"], d["b_pad"], d["p_pad"]) == (3, 8, 64)
+        assert (d["steps"], d["n_steps"], d["padded_steps"]) == (49, 37, 8 * 37)
+        run_concurrent(batcher, [synthetic_enc(24, 1, 50, seed=50)])
+        d = _dispatches_of(batcher)[-1]
+        # a lone dispatch pads nothing: useful steps read 100%
+        assert (d["b_pad"], d["steps"], d["n_steps"], d["padded_steps"]) == (
+            1, 50, 50, 50)
+        with batcher._lock:
+            stats = dict(batcher.stats)
+        assert (stats["steps"], stats["padded_steps"]) == (99, 8 * 37 + 50)
+    finally:
+        batcher.stop()
+
+
+def _case_one_group_wave_packs_without_a_group_axis():
+    one = [synthetic_enc(24, 1, p, seed=p) for p in (5, 12)]
+    dims = DeviceBatcher._batch_dims(one)
+    assert (dims["g_pad"], dims["p_pad"]) == (1, 64)
+    # one two-group eval widens the wave, as for every other axis, and the
+    # narrower eval's padded slot stays failed: no step of it points there
+    mixed = one + [synthetic_enc(24, 2, 7, seed=7)]
+    dims = DeviceBatcher._batch_dims(mixed)
+    assert dims["g_pad"] == 2
+    layout = wire.WireLayout(
+        wire.shape_key(mixed[0], dims, mixed[0].dtype), 8, dims)
+    bufs = wire.WireBuffers(layout)
+    wire.pack(bufs, mixed)
+    _static, carry, xs, p_real = wire.unpack(layout, bufs.arrays, np)
+    failed0, tg_idx = carry[6], xs[0]
+    assert failed0.shape == (8, 2)
+    assert failed0[:2, 1].all() and not failed0[:3, 0].any()
+    assert not failed0[2].any()
+    assert (tg_idx[:2] == 0).all()
+    np.testing.assert_array_equal(p_real[:3], [5, 12, 7])
+    np.testing.assert_array_equal(p_real[3:], 5)   # inert copies of slot 0
+    _dims, (chosen, _s, _p, skipped, _e) = _scan_as_dispatched(
+        mixed, mixed[0].dtype, 8)
+    engine = TpuPlacementEngine.shared()
+    for bi, enc in enumerate(mixed):
+        alone = engine.run_scan_single(enc)
+        np.testing.assert_array_equal(chosen[bi, :enc.p], alone[0])
+        np.testing.assert_array_equal(skipped[bi, :enc.p], alone[3])
+
+
+@pytest.mark.parametrize("p", [1, 2, 15, 16, 17, 33, 50, 63, 64])
+def test_batch_dims_has_no_step_bucket_under_64(p):
+    assert DeviceBatcher._batch_dims([synthetic_enc(16, 1, p)])["p_pad"] == 64
+
+
+@pytest.mark.parametrize("p, p_pad", [(65, 256), (256, 256), (257, 1024),
+                                      (1024, 1024), (1025, 2048)])
+def test_batch_dims_keeps_the_larger_step_buckets(p, p_pad):
+    assert DeviceBatcher._batch_dims(
+        [synthetic_enc(16, 1, p)])["p_pad"] == p_pad
+
+
+@pytest.mark.parametrize("case", [
+    _case_padded_steps_are_the_batch_times_the_longest_eval,
+    _case_one_group_wave_packs_without_a_group_axis,
+], ids=lambda f: f.__name__.replace("_case_", ""))
+def test_device_learns_the_step_count(case):
+    case()

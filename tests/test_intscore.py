@@ -72,6 +72,33 @@ def test_binpack_from_e_tracks_float_reference():
         assert abs(fp - ref) < 2.5e-6, (uc, um, cc, cm, fp, ref)
 
 
+@pytest.mark.parametrize("fits", [
+    "every value up to 2**17", "the top of the range", "a million drawn",
+    "just past every multiple of 9 * 2**k",
+])
+def test_binpack_q30_is_the_division_it_replaces(fits):
+    """The device's multiply-and-shift form of the Q30 binpack term is,
+    over the whole clipped range 0..18*2**27, the spec's (fit*4)//9."""
+    top = 18 * intscore.E27_ONE
+    fit = {
+        "every value up to 2**17": np.arange(1 << 17, dtype=np.int64),
+        "the top of the range": np.arange(top - (1 << 17), top + 1,
+                                          dtype=np.int64),
+        "a million drawn": np.random.default_rng(9).integers(
+            0, top + 1, 1_000_000, dtype=np.int64),
+        "just past every multiple of 9 * 2**k": np.concatenate([
+            np.clip(9 * (np.int64(1) << k) * m + np.arange(-9, 10), 0, top)
+            for k in range(28) for m in (1, 2)]).astype(np.int64),
+    }[fits]
+    np.testing.assert_array_equal(intscore.binpack_q30(fit), (fit * 4) // 9)
+    assert intscore.binpack_q30(top) == intscore.TERM_ONE
+    for ec, em in ((0, 0), (intscore.E27_ONE, 3 * intscore.E27_ONE),
+                   (123456789, 987654321)):
+        want = intscore.binpack_fp_from_e(ec, em)
+        f = max(0, min(top, 20 * intscore.E27_ONE - ec - em))
+        assert intscore.binpack_q30(f) == want
+
+
 def test_running_product_drift_is_bounded():
     # place/evict the same amounts repeatedly: the Q27 running product
     # must stay within k*2**-26 of the directly-computed exponential

@@ -38,11 +38,14 @@ def make_nodes(num, seed):
     return nodes
 
 
-def run_pair(nodes, jobs, evals_for):
-    """Run the same workload under binpack and tpu_binpack; return plans."""
+def run_pair(nodes, jobs, evals_for, batcher=None):
+    """Run the same workload under binpack and tpu_binpack; return plans.
+    With a ``batcher`` the tpu_binpack side dispatches through it."""
     plans = {}
     for alg in ("binpack", "tpu_binpack"):
         h = Harness()
+        if alg == "tpu_binpack" and batcher is not None:
+            h.device_batcher = batcher
         h.state.scheduler_set_config(
             h.next_index(), SchedulerConfiguration(scheduler_algorithm=alg)
         )
@@ -107,6 +110,52 @@ def test_parity_multi_tg_multi_job():
             job.task_groups.append(tg)
         jobs.append(job)
     assert_parity(run_pair(nodes, jobs, lambda j: "service"))
+
+
+@pytest.mark.parametrize("counts, stanzas", [
+    ((1,), False), ((16,), False), ((17,), True), ((50,), True),
+    ((64,), False), ((3, 5), False), ((30, 2, 9), True),
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else
+    ("stanzas" if v else "plain"))
+def test_parity_through_the_bounded_loop(counts, stanzas):
+    """Host pipeline against the batcher's program, every eval a lone
+    dispatch: the device's loop runs the eval's own step count inside the
+    one 64-step program (no 16 bucket), and a one-group job rides a group
+    axis of one (no pre-failed pad slot)."""
+    from nomad_tpu.tpu.batcher import DeviceBatcher
+    from nomad_tpu.trace import lifecycle
+
+    nodes = make_nodes(40, seed=sum(counts))
+    job = mock.job()
+    tg0 = job.task_groups[0]
+    job.task_groups = []
+    for t, count in enumerate(counts):
+        tg = copy.deepcopy(tg0)
+        tg.name = f"tg{t}"
+        tg.count = count
+        tg.tasks[0].resources.cpu = 100 + 50 * t
+        tg.tasks[0].resources.memory_mb = 64
+        job.task_groups.append(tg)
+    if stanzas:
+        job.affinities = [Affinity(ltarget="${attr.rack}", rtarget="r1",
+                                   operand="=", weight=50)]
+        job.spreads = [Spread(
+            attribute="${node.datacenter}", weight=100,
+            spread_target=[SpreadTarget(value="dc1", percent=60),
+                           SpreadTarget(value="dc2", percent=40)])]
+    batcher = DeviceBatcher(max_batch=1)
+    try:
+        plans = run_pair(nodes, [job], lambda j: "service", batcher=batcher)
+        recs = [d for d in lifecycle.dispatch_records()
+                if d["batcher"] == batcher._serial]
+    finally:
+        batcher.stop()
+    assert_parity(plans)
+    assert len(plan_assignments(plans["tpu_binpack"][0])) == sum(counts)
+    (d,) = recs
+    assert (d["p_pad"], d["n_steps"], d["steps"], d["padded_steps"]) == (
+        64, sum(counts), sum(counts), sum(counts))
+    assert batcher.stats["batch_fallbacks"] == 0
 
 
 def test_parity_batch_power_of_two():
