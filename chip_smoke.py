@@ -12,7 +12,7 @@ python chip_smoke.py`` exits 2 and prints no result). On the chip it drives
 -> ``TpuPlacementEngine`` -> ``DeviceBatcher`` -> plan queue -> raft/FSM ->
 state store at the width of the C1M headline (BASELINE.md config 5: 5,000
 heterogeneous nodes, ``deterministic=True``, ``device_batch=64``, the
-900-1,000-task job mix of ``mock.C1M_TEMPLATES``), depth cut to two 64-job waves,
+900-1,000-task job mix of ``benchmark/configs/c1m-5k.json``), depth cut to two 64-job waves,
 plus the two other compiled programs the benchmark's cells use on the same
 cluster: a system job over every eligible node (the scan-free forced kernel)
 and a preempting system eval whose encode carries preemption tables
@@ -33,6 +33,7 @@ from __future__ import annotations
 import argparse
 import copy
 import dataclasses
+import functools
 import json
 import logging
 import os
@@ -211,14 +212,25 @@ def make_nodes(n: int, seed: int):
     return nodes
 
 
+@functools.lru_cache(maxsize=1)
+def c1m_templates() -> list:
+    """The C1M mix: ``jobs.templates`` of benchmark/configs/c1m-5k.json,
+    the one table of it (the benchmark's cell reads the same file)."""
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmark", "configs", "c1m-5k.json")
+    with open(path) as f:
+        return json.load(f)["jobs"]["templates"]
+
+
 def c1m_job(sizes: Sizes, i: int, job_id: str, count: int = 0):
-    """Job ``i`` of the C1M mix (mock.C1M_TEMPLATES: 40 templates
-    round-robin — 0-9 service with spread+affinity stanzas, 10-27 plain
-    service, 28-39 batch; 900-1,000 tasks each, all in the p=1024 scan
-    bucket). ``count`` overrides the template's task count."""
+    """Job ``i`` of the C1M mix (40 templates round-robin — 0-9 service
+    with spread+affinity stanzas, 10-27 plain service, 28-39 batch;
+    900-1,000 tasks each, all in the p=1024 scan bucket). ``count``
+    overrides the template's task count."""
     from nomad_tpu import mock
 
-    tpl = mock.C1M_TEMPLATES[i % len(mock.C1M_TEMPLATES)]
+    templates = c1m_templates()
+    tpl = templates[i % len(templates)]
     if not count:
         count = max(1, int(round(tpl["count"] * sizes.count_scale)))
     return mock.c1m_job(tpl, job_id, count)

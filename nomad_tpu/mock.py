@@ -201,41 +201,33 @@ def batch_job() -> Job:
     return j
 
 
-# The C1M job mix (BASELINE.md config 5: mixed service+batch,
-# heterogeneous asks and counts, spread+affinity stanzas on a quarter of
-# the jobs): 28 service templates, the first 10 with stanzas, then 12
-# batch; every count pads into the p=1024 scan bucket. chip_smoke.py
-# instantiates them round-robin (ROADMAP D14: R11's benchmark PR carries
-# the table as data).
-_C1M_CPUS = (8, 12, 16, 20)
-_C1M_MEMS = (16, 24, 32, 48)
-C1M_TEMPLATES = [
-    dict(kind="service", cpu=_C1M_CPUS[t % 4], mem=_C1M_MEMS[(t // 4) % 4],
-         count=(900, 950, 1000)[t % 3], stanzas=t < 10)
-    for t in range(28)
-] + [
-    dict(kind="batch", cpu=_C1M_CPUS[t % 4], mem=_C1M_MEMS[t % 4],
-         count=(950, 1000)[t % 2], stanzas=False)
-    for t in range(12)
-]
-
-
-def c1m_job(tpl: dict, job_id: str, count: int) -> Job:
-    """One job of a ``C1M_TEMPLATES`` entry with ``count`` tasks."""
+def c1m_job(tpl: dict, job_id: str, count: int = 0) -> Job:
+    """One job of the C1M mix from a template row of
+    ``benchmark/configs/c1m-5k.json`` (``jobs.templates``: the one table
+    of the mix, in the benchmark harness's template keys), with ``count``
+    tasks or the row's own."""
     j = job() if tpl["kind"] == "service" else batch_job()
     j.id = job_id
+    j.datacenters = list(tpl["datacenters"])
+    j.constraints = [
+        Constraint(ltarget="${attr.kernel.name}", rtarget="linux", operand="=")
+    ] if tpl["linux_only"] else []
     tg = j.task_groups[0]
-    tg.count = count
-    tg.ephemeral_disk.size_mb = 50
+    tg.count = count or tpl["count"]
+    tg.ephemeral_disk.size_mb = tpl["disk"]
     tg.tasks[0].resources = Resources(cpu=tpl["cpu"], memory_mb=tpl["mem"])
-    if tpl["stanzas"]:
+    spread, affinity = tpl.get("spread"), tpl.get("affinity")
+    if spread:
         tg.spreads = [Spread(
-            attribute="${node.datacenter}", weight=50,
-            spread_target=[SpreadTarget(value="dc1", percent=100)],
+            attribute=spread["attribute"], weight=spread["weight"],
+            spread_target=[SpreadTarget(value=v, percent=p)
+                           for v, p in spread["targets"].items()],
         )]
+    if affinity:
         tg.affinities = [Affinity(
-            ltarget="${attr.kernel.name}", rtarget="linux",
-            operand="=", weight=50,
+            ltarget="${attr.kernel.name}",
+            rtarget="linux" if affinity["linux"] else "windows",
+            operand="=", weight=affinity["weight"],
         )]
     return j
 

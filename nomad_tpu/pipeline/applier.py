@@ -20,8 +20,11 @@ enqueue to broker ack:
                               │                        │
                     wait_min_index(alloc_index)   redispatch (bounded
                               │                   attempts; cached
-                        broker.ack                encode re-entry) or
-                                                  broker.nack
+                        broker.ack                encode re-entry), or
+                                                  broker.refresh (a worker
+                                                  runs the eval again on
+                                                  the refreshed snapshot),
+                                                  or broker.nack
 
 Per-payload failure isolation comes from the Planner's batched waiter
 (one raft entry per batch, per-payload error list from the FSM): a
@@ -125,6 +128,8 @@ class AsyncApplier:
                     return
                 self._enabled = True
             self._stop.clear()
+            # published at 0, so that a reader tells "none" from "no such path"
+            metrics.incr_counter("nomad.pipeline.refresh_retry", 0.0)
             self._thread = threading.Thread(
                 target=self._run, name="pipeline-applier", daemon=True)
             self._thread.start()
@@ -261,8 +266,13 @@ class AsyncApplier:
         metrics.incr_counter("nomad.pipeline.partial_commit")
         logger.debug("partial commit for %s: attempted %d placed %d",
                      rec.plan.eval_id[:8], expected, actual)
+        # what the remembered encode cannot serve (stanzas, distinct_*, a
+        # moved node epoch, a tail patched twice already) goes back to a
+        # worker at once, to be run on the refreshed snapshot: a nack
+        # only once that has stalled as often as upstream allows
         if rec.attempts >= self.redispatch_max:
-            self._finish(rec, ack=False, why="redispatch_exhausted")
+            self._finish(rec, ack=False, why="redispatch_exhausted",
+                         refresh=result)
             return
         retry = None
         try:
@@ -270,7 +280,7 @@ class AsyncApplier:
         except Exception:  # noqa: BLE001
             logger.exception("redispatch failed for %s", rec.plan.eval_id[:8])
         if retry is None:
-            self._finish(rec, ack=False, why="no_redispatch")
+            self._finish(rec, ack=False, why="no_redispatch", refresh=result)
             return
         rec.plan = retry
         rec.attempts += 1
@@ -319,7 +329,11 @@ class AsyncApplier:
             self._waves.pop(rec.plan.eval_id, None)
             return True
 
-    def _finish(self, rec: _Wave, ack: bool, why: str = "") -> None:
+    def _finish(self, rec: _Wave, ack: bool, why: str = "",
+                refresh: Optional[PlanResult] = None) -> None:
+        """The wave leaves the applier: acked, or handed back to a worker
+        on the refreshed snapshot (``refresh``: the partial commit's
+        result; eval_broker.refresh), or nacked."""
         if not self._mark_done(rec):
             return
         self.registry.forget(rec.plan.eval_id)
@@ -328,6 +342,10 @@ class AsyncApplier:
             if ack:
                 broker.ack(rec.plan.eval_id, rec.token)
                 metrics.incr_counter("nomad.pipeline.acked")
+            elif refresh is not None and broker.refresh(
+                    rec.plan.eval_id, rec.token, refresh.refresh_index,
+                    progress=bool(refresh.dense_placements)):
+                metrics.incr_counter("nomad.pipeline.refresh_retry")
             else:
                 broker.nack(rec.plan.eval_id, rec.token)
                 metrics.incr_counter("nomad.pipeline.nacked")

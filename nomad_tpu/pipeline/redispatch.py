@@ -57,11 +57,16 @@ class _ShimCtx:
     encodes only exist in deterministic mode — fleet_static returns
     None otherwise, and the engine's cache path requires a fleet)."""
 
-    __slots__ = ("state", "deterministic")
+    __slots__ = ("state", "deterministic", "regexp_cache",
+                 "version_constraint_cache", "semver_constraint_cache")
 
     def __init__(self, state) -> None:
         self.state = state
         self.deterministic = True
+        # what an affinity match reads (the referee's float64 scoring)
+        self.regexp_cache = {}
+        self.version_constraint_cache = {}
+        self.semver_constraint_cache = {}
 
 
 class WaveEncodeRegistry:
@@ -191,15 +196,17 @@ class Redispatcher:
             return None
 
         from ..tpu.engine import TpuPlacementEngine
+        from ..tpu.referee import referee
 
         engine = TpuPlacementEngine.shared()
         batcher = self.server.device_batcher
+        dispatch = batcher.run if batcher is not None else engine.run_scan_single
         with _lifecycle.stage("device_wait", plan.eval_id):
-            if batcher is not None:
-                chosen, scores, pulls, skipped, _evict = batcher.run(retry_enc)
-            else:
-                chosen, scores, pulls, skipped, _evict = engine.run_scan_single(
-                    retry_enc)
+            outs = dispatch(retry_enc)
+        # a near tie in the tail is decided as in the first pass
+        outs = referee(retry_enc, job, _ShimCtx(snap), outs, dispatch,
+                       plan.eval_id)
+        chosen, scores, pulls, skipped = outs[:4]
         p = retry_enc.p
         chosen = np.asarray(chosen)[:p]
         skipped = np.asarray(skipped)[:p]

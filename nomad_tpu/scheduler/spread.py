@@ -89,19 +89,9 @@ class SpreadIterator:
                     # No targets: even-spread scoring.
                     total_spread_score += even_spread_score_boost(pset, option.node)
                 else:
-                    desired_count = spread_details.desired_counts.get(nvalue)
-                    if desired_count is None:
-                        desired_count = spread_details.desired_counts.get(IMPLICIT_TARGET)
-                        if desired_count is None:
-                            total_spread_score -= 1.0
-                            continue
-                    # Go float division semantics: x/0 = ±Inf, 0/0 = NaN — a
-                    # percent-0 target yields -Inf, steering allocs away.
-                    spread_weight = _godiv(
-                        float(spread_details.weight), float(self.sum_spread_weights)
-                    )
-                    boost = _godiv(desired_count - float(used_count), desired_count) * spread_weight
-                    total_spread_score += boost
+                    total_spread_score += targeted_spread_boost(
+                        spread_details.desired_counts, nvalue, used_count,
+                        spread_details.weight, self.sum_spread_weights)
 
             if total_spread_score != 0.0:
                 option.scores.append(total_spread_score)
@@ -114,16 +104,38 @@ class SpreadIterator:
         combined = list(tg.spreads) + list(self.job_spreads)
         for spread in combined:
             si = SpreadInfo(spread.weight)
-            sum_desired = 0.0
-            for st in spread.spread_target:
-                desired = (float(st.percent) / 100.0) * float(total_count)
-                si.desired_counts[st.value] = desired
-                sum_desired += desired
-            if 0 < sum_desired < float(total_count):
-                si.desired_counts[IMPLICIT_TARGET] = float(total_count) - sum_desired
+            si.desired_counts = desired_counts(spread, total_count)
             spread_infos[spread.attribute] = si
             self.sum_spread_weights += spread.weight
         self.tg_spread_info[tg.name] = spread_infos
+
+
+def desired_counts(spread, total_count: int) -> Dict[str, float]:
+    """A spread's targets as placements of ``total_count``; what the targets
+    leave over goes to the implicit target."""
+    desired: Dict[str, float] = {}
+    sum_desired = 0.0
+    for st in spread.spread_target:
+        desired[st.value] = (float(st.percent) / 100.0) * float(total_count)
+        sum_desired += desired[st.value]
+    if 0 < sum_desired < float(total_count):
+        desired[IMPLICIT_TARGET] = float(total_count) - sum_desired
+    return desired
+
+
+def targeted_spread_boost(desired: Dict[str, float], nvalue, used_count: int,
+                          weight, sum_weights) -> float:
+    """One targeted spread's term for a node whose attribute reads
+    ``nvalue``; ``used_count`` includes the placement being scored."""
+    desired_count = desired.get(nvalue)
+    if desired_count is None:
+        desired_count = desired.get(IMPLICIT_TARGET)
+        if desired_count is None:
+            return -1.0
+    # Go float division semantics: x/0 = ±Inf, 0/0 = NaN — a
+    # percent-0 target yields -Inf, steering allocs away.
+    spread_weight = _godiv(float(weight), float(sum_weights))
+    return _godiv(desired_count - float(used_count), desired_count) * spread_weight
 
 
 def even_spread_score_boost(pset: PropertySet, node) -> float:
@@ -134,9 +146,14 @@ def even_spread_score_boost(pset: PropertySet, node) -> float:
     nvalue, ok = get_property(node, pset.target_attribute)
     if not ok:
         return -1.0
-    current = combined_use.get(nvalue, 0)
-    min_count = min(combined_use.values())
-    max_count = max(combined_use.values())
+    return even_spread_boost(combined_use.get(nvalue, 0),
+                             min(combined_use.values()),
+                             max(combined_use.values()))
+
+
+def even_spread_boost(current: int, min_count: int, max_count: int) -> float:
+    """The even-spread term from the node's value's count and the least and
+    the most any value holds."""
     if min_count == 0:
         delta_boost = -1.0
     else:

@@ -26,6 +26,13 @@ DEFAULT_NACK_TIMEOUT = 60.0
 DEFAULT_DELIVERY_LIMIT = 3
 DEFAULT_INITIAL_NACK_DELAY = 1.0
 DEFAULT_SUBSEQUENT_NACK_DELAY = 20.0
+# A delivery whose plan committed in part is run again on the refreshed
+# snapshot, as upstream's worker does in place (generic_sched.go
+# retryMax(maxServiceScheduleAttempts | maxBatchScheduleAttempts, ...,
+# progressMade)): so many times without progress, the count reset while
+# progress is made.
+REFRESH_ATTEMPTS = {"batch": 2}
+DEFAULT_REFRESH_ATTEMPTS = 5
 
 
 class NotOutstandingError(Exception):
@@ -100,6 +107,8 @@ class EvalBroker:
             "eval_broker.EvalBroker.unack", {})
         # token -> eval to requeue on Ack
         self.requeue: Dict[str, Evaluation] = {}
+        # eval id -> refreshes in a row that made no progress (refresh)
+        self.refreshes: Dict[str, int] = {}
         # eval id -> wait timer (Evaluation.wait_ns)
         self.time_wait: Dict[str, threading.Timer] = {}
         # delayed evals (wait_until) handled by a timer per eval too
@@ -277,6 +286,7 @@ class EvalBroker:
             unack.nack_timer.cancel()
             del self.unack[eval_id]
             del self.evals[eval_id]
+            self.refreshes.pop(eval_id, None)
             # close BEFORE the requeue below may reopen the same id
             _trace.on_ack(eval_id)
             # close the unblock->place storm sample (no-op for evals
@@ -308,6 +318,8 @@ class EvalBroker:
                 raise TokenMismatchError(eval_id)
             unack.nack_timer.cancel()
             del self.unack[eval_id]
+            # a redelivery starts its refreshes afresh, as upstream's
+            self.refreshes.pop(eval_id, None)
 
             prev_dequeues = self.evals.get(eval_id, 0)
             if prev_dequeues >= self.delivery_limit:
@@ -321,6 +333,41 @@ class EvalBroker:
             timer.daemon = True
             self.time_wait[eval_id] = timer
             timer.start()
+
+    def refresh(self, eval_id: str, token: str, refresh_index: int,
+                progress: bool) -> bool:
+        """The delivery's plan committed in part: hand the eval to a
+        worker again AT ONCE, to be run on a snapshot at or above
+        ``refresh_index`` (reconcile then sees what committed and places
+        the rest). Upstream's worker does this in place; here the plan's
+        commit is the async applier's, so the same delivery goes back
+        through the READY heap. Not a nack: no re-enqueue delay, the
+        delivery count stays where it was, the eval keeps its place ahead
+        of the job's blocked evals. False, and nothing done, once
+        ``REFRESH_ATTEMPTS`` refreshes in a row made no progress: the
+        caller then nacks."""
+        with self._lock:
+            unack = self.unack.get(eval_id)
+            if unack is None:
+                raise NotOutstandingError(eval_id)
+            if unack.token != token:
+                raise TokenMismatchError(eval_id)
+            stalled = 0 if progress else self.refreshes.get(eval_id, 0) + 1
+            if stalled >= REFRESH_ATTEMPTS.get(unack.eval.type,
+                                               DEFAULT_REFRESH_ATTEMPTS):
+                return False
+            self.refreshes[eval_id] = stalled
+            self.requeue.pop(token, None)
+            unack.nack_timer.cancel()
+            del self.unack[eval_id]
+            # the dequeue to come counts the same delivery again
+            self.evals[eval_id] = max(0, self.evals.get(eval_id, 1) - 1)
+            # a copy: the stored eval is not this delivery's to change
+            again = unack.eval.copy()
+            again.snapshot_index = max(again.snapshot_index, int(refresh_index))
+            _trace.on_refresh(eval_id)
+            self._enqueue_locked(again, again.type)
+            return True
 
     def _nack_reenqueue_delay(self, prev_dequeues: int) -> float:
         if prev_dequeues <= 1:
@@ -366,6 +413,7 @@ class EvalBroker:
             self.ready.clear()
             self.unack.clear()
             self.requeue.clear()
+            self.refreshes.clear()
             self.time_wait.clear()
             self._delayed.clear()
             self._cond.notify_all()

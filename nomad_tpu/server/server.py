@@ -9,6 +9,7 @@ in-process equivalents of the RPC endpoint layer; a transport front-end
 """
 from __future__ import annotations
 
+import gc
 import logging
 import threading
 import time
@@ -53,6 +54,11 @@ from .plan_apply import Planner, PlanQueue
 from .raft import InProcRaft
 from .worker import Worker
 from ..utils.lock_witness import witness_rlock
+
+# how often the leader looks for an idle moment to settle the heap in
+# (Server._settle_heap): a look that finds the store unmoved or the broker
+# busy costs nothing, and the oftener it settles the less each pass walks
+HEAP_SETTLE_INTERVAL_S = 0.25
 
 
 def leader_forward(rpc_method: str):
@@ -253,6 +259,7 @@ class Server:
         self._leadership = False
         self._leader_generation = 0
         self._leader_timers: List[threading.Timer] = []
+        self._heap_settled_index = -1
         self._lock = witness_rlock("server.Server._lock")
 
         # follower->leader write forwarding (leader_forward decorator):
@@ -422,6 +429,10 @@ class Server:
         # wake every parked blocking query and stop the flusher thread
         self.watch_hub.close()
         self._revoke_leadership()
+        # what _settle_heap froze is the collector's again (not at every
+        # loss of leadership: a whole-heap pass after each would stall the
+        # process in the middle of an election)
+        gc.unfreeze()
 
     # -- leadership ------------------------------------------------------
 
@@ -467,6 +478,11 @@ class Server:
                                    self._reap_failed_evals)
         self._schedule_leader_task(gen, self.config.eval_gc_interval, self._create_gc_evals)
         self._schedule_leader_task(gen, 10.0, self.publish_stats_gauges)
+        # what the process holds when leadership begins (its modules, the
+        # restored store) is frozen unexamined, so that no pass of
+        # _settle_heap ever walks more than what was made since the last
+        gc.freeze()
+        self._schedule_leader_task(gen, HEAP_SETTLE_INTERVAL_S, self._settle_heap)
         if self.config.watchdog_interval > 0:
             self._schedule_leader_task(
                 gen, self.config.watchdog_interval, self.watchdog.tick
@@ -558,6 +574,7 @@ class Server:
             for t in self._leader_timers:
                 t.cancel()
             self._leader_timers.clear()
+            self._heap_settled_index = -1
 
     def _restore_evals(self) -> None:
         """Re-enqueue non-terminal evals on leadership (leader.go:295)."""
@@ -593,6 +610,31 @@ class Server:
             # prune fired timers
             self._leader_timers = [x for x in self._leader_timers if x.is_alive() or x is t]
         t.start()
+
+    def _settle_heap(self) -> None:
+        """Keep the cyclic collector off the long-lived state.
+
+        The nodes, the state store's tables and every committed placement
+        live as long as the cluster does, and CPython's full collection
+        walks all of them with every thread stopped: 0.24-0.52 s a pass at
+        1,024 nodes, nine or ten passes in 51 s of 1,000-task jobs
+        (PERF.md §6, PR 31), each one a stall in the middle of somebody's
+        plan. So when the store has moved since the last time and the
+        broker holds no eval (nothing ready, nothing unacked), collect
+        once and freeze what survived: an idle moment's survivors are
+        state, not garbage. The collector then walks only what was made
+        since. A frozen object is still freed when its last reference
+        goes; one that a cycle orphans later waits for the unfreeze when
+        the server stops."""
+        index = self.fsm.state.latest_index
+        if index == self._heap_settled_index:
+            return
+        stats = self.eval_broker.stats()
+        if stats["total_ready"] or stats["total_unacked"]:
+            return
+        self._heap_settled_index = index  # race-ok: one leader timer at a time
+        gc.collect()
+        gc.freeze()
 
     def _reap_failed_evals(self) -> None:
         """Drain the _failed queue: mark failed + create follow-ups

@@ -360,8 +360,8 @@ class DeviceBatcher:
             # into the wire buffers) vs compute (the call, then the one
             # wait for the output on the host: H2D, launch, kernel and the
             # output's copy down) vs transfer (the one host array split
-            # into the five results; on the mesh path compute ends at a
-            # fence and transfer is five copies down). Totals of the
+            # into the six results; on the mesh path compute ends at a
+            # fence and transfer is six copies down). Totals of the
             # stamps each dispatch's record keeps (trace/lifecycle
             # .on_dispatch), which splits compute further and feeds
             # dispatch_profile()
@@ -379,6 +379,8 @@ class DeviceBatcher:
             # the bound of the device's loop): what the batch axis pads
             "steps": 0,
             "padded_steps": 0,
+            # steps whose runner-up lay inside the near-tie band
+            "near_ties": 0,
             # degradations that keep the eval alive but hide a device
             # problem unless counted: a batched dispatch that raised and
             # was retried eval-by-eval on the single scan, and a sibling
@@ -728,7 +730,7 @@ class DeviceBatcher:
         buffers), H2D + launch (the call: one upload per dtype group),
         kernel wait (until the ONE output array is on the host: the
         kernel and its copy down) and transfer (D2H: that one array split
-        into the five results, host work only; on the mesh path five
+        into the six results, host work only; on the mesh path six
         copies down after a fence) — and how many arrays cross the
         boundary each way? The note names the binding leg so
         four-rounds-flat throughput plateaus read as "kernel wait-bound at
@@ -862,9 +864,9 @@ class DeviceBatcher:
         covers the kernel and the copy down (starting that copy when the
         call returns, ``copy_to_host_async``, bought nothing on the v5e:
         7.857 against 7.856 ms a lone dispatch). → ``t_host`` (d2h): that
-        array split into the five results, host work only. →
+        array split into the six results, host work only. →
         ``t_handed``: every worker released. On the mesh path ``t_ready``
-        is a fence on the device's outputs and d2h is five copies down."""
+        is a fence on the device's outputs and d2h is six copies down."""
         import jax
 
         annotate = jax.profiler.TraceAnnotation
@@ -882,11 +884,11 @@ class DeviceBatcher:
                 # ROADMAP D9: the mesh shards arrays by their node axis
                 # (parallel/sharding.py's positional specs), which a flat
                 # buffer does not have: this path keeps the 49 stacked
-                # arrays up, a fence, and five copies down
+                # arrays up, a fence, and six copies down
                 args, b_pad, n_pad = self._pad_and_stack(
                     encs, dims, dtype, b_pad)
                 h2d_arrays = len(jax.tree_util.tree_leaves(args))
-                d2h_arrays = 5
+                d2h_arrays = 6
             else:
                 bufs = self._pack(encs, dims, dtype, b_pad)
                 args = (bufs.layout,) + bufs.arrays
@@ -907,18 +909,23 @@ class DeviceBatcher:
         with annotate("nomad.d2h", wave=wave):
             if self.mesh is None:
                 d2h_bytes = host.nbytes
-                chosen, scores, pulls, skipped, evict = wire.split_outputs(
-                    bufs.layout, host)
+                chosen, scores, pulls, skipped, evict, rival = (
+                    wire.split_outputs(bufs.layout, host))
             else:
-                chosen, scores, pulls, skipped, evict = (
+                chosen, scores, pulls, skipped, evict, rival = (
                     np.asarray(a) for a in out)
                 d2h_bytes = (chosen.nbytes + scores.nbytes + pulls.nbytes
-                             + skipped.nbytes + evict.nbytes)
+                             + skipped.nbytes + evict.nbytes + rival.nbytes)
         t_host = _phases.now()
         steps = sum(e.p for e in encs)
         # the bound the device's loop ran: the wave's longest eval
         n_steps = max(e.p for e in encs)
         padded_steps = b_pad * n_steps
+        # steps whose runner-up lay inside the near-tie band: what the
+        # engine's referee will look at (intscore.NEAR_TIE_BAND_Q30)
+        near_ties = int(sum(
+            np.count_nonzero(rival[bi, :e.p] >= 0)
+            for bi, e in enumerate(encs)))
         t_first_enqueue = min(r.t_enqueue for r in batch)
 
         with self._lock:
@@ -934,6 +941,7 @@ class DeviceBatcher:
             self.stats["d2h_arrays_total"] += d2h_arrays
             self.stats["steps"] += steps
             self.stats["padded_steps"] += padded_steps
+            self.stats["near_ties"] += near_ties
             for req in batch:
                 wait_ms = (t_start - req.t_enqueue) * 1000.0
                 if wait_ms > 0:
@@ -946,7 +954,7 @@ class DeviceBatcher:
             p = req.enc.p
             req.result = (
                 chosen[bi, :p], scores[bi, :p], pulls[bi, :p], skipped[bi, :p],
-                evict[bi, :p],
+                evict[bi, :p], rival[bi, :p],
             )
             req.event.set()
         t_handed = _phases.now()
@@ -957,7 +965,8 @@ class DeviceBatcher:
             b=b, b_pad=b_pad, p_pad=p_pad,
             # the mesh may have widened the node axis past the batch's
             n_pad=n_pad, steps=steps, n_steps=n_steps,
-            padded_steps=padded_steps, closed_by=closed_by,
+            padded_steps=padded_steps, near_ties=near_ties,
+            closed_by=closed_by,
             d2h_bytes=d2h_bytes, h2d_arrays=h2d_arrays,
             d2h_arrays=d2h_arrays, t_first_enqueue=t_first_enqueue,
             t_start=t_start, t_stack=t_stack, t_called=t_called,

@@ -60,11 +60,6 @@ logger = logging.getLogger("nomad_tpu.tpu.engine")
 
 MAX_SKIP = 3
 
-# Partial OCC retries below device_min_placements still ride the device
-# when their compile bucket is already warm (see compute_placements) —
-# but only above this floor; 1-2 placement stragglers stay on the host.
-RETRY_DEVICE_FLOOR = 4
-
 # GIL convoy guard shared with the scheduler's other host phases
 # (utils/hostwork.py): encode/apply are pure-Python, so letting hundreds
 # of worker threads enter them at once only buys context-switch thrash.
@@ -240,13 +235,23 @@ def _make_step():
     from .intscore import (
         FEAT_AFF_BIT,
         FEAT_FEAS_BIT,
+        NEAR_TIE_BAND60,
         PACK_COUNT_MAX,
+        RIVAL_BITS,
         pack_count_lanes,
         pack_presence_lanes,
         unpack_count_hi,
         unpack_count_lo,
         unpack_feat_lane,
     )
+
+    # the near-tie key, an int32: (distance >> 1) << 15 | node index (the
+    # band's 60 * 2**10 halves into 15 bits); two indices and the crowded
+    # bit ride one int32 out (intscore.RIVAL_BITS). _MIX: odd multipliers
+    # of the twin test's wrapping sum
+    _I32_MAX = (1 << 31) - 1
+    _MIX = (-1640531527, 506961463, 668265263, 374761393)
+    assert NEAR_TIE_BAND60 >> 1 < 1 << (31 - RIVAL_BITS)
 
     def step(static, carry, x, past_end=False):
         (totals, reserved, asks, feat_packed, aff_score, desired_counts,
@@ -745,14 +750,68 @@ def _make_step():
             winner_rank = jnp.where(winners, rank, jnp.int32(2**31 - 1))
             best_rank = jnp.min(winner_rank)
             any_cand = jnp.any(cand)
-            chosen = jnp.where(
-                any_cand & (~skip_step),
-                jnp.argmax(winners & (rank == best_rank)).astype(jnp.int32),
-                -1,
-            )
+            first = winners & (rank == best_rank)
+
+            # -- near tie: the candidates inside the band --------------------
+            # Q30 orders two nodes as float64 does only while their scores
+            # lie farther apart than the two roundings (intscore.py:
+            # NEAR_TIE_BAND_Q30). The step names the candidates whose score
+            # is UNDER the winner's by no more than the band: the nearest,
+            # the farthest, and whether more than these two crowd it; or
+            # -1, on nearly every step. The host scores them in float64
+            # (tpu/referee.py) and the device's pick stands unless float64
+            # orders them the other way. Candidates that tie with the
+            # winner to the last bit are its twins wherever they hold the
+            # winner's cpu and memory, capacity and use (an idle fleet is
+            # all twins): they score the same in both arithmetics and tie
+            # to the ring's first in both. Whether all of them do is ONE
+            # comparison: the four numbers ride a wrapping int32 sum of odd
+            # multiples (two different nodes share one once in 2**32), and
+            # its min and max over the tied set differ when a tie is not a
+            # twin, which also reads "crowded". All of it rides the
+            # reduction that finds ``chosen``, as further operands, so the
+            # compiled loop has the parent's count of kernels. The key costs
+            # a step 5 us on a v5e however its reductions are written
+            # (PERF.md §6): its arithmetic over the node plane, not them.
+            i32 = jnp.int32
+            refereed = int_mode and n_pad <= (1 << RIVAL_BITS)
+            operands = [(jnp.where(first, iota, _I32_MAX), _I32_MAX, jnp.minimum)]
+            if refereed:
+                mix = (totals[:, 0] * i32(_MIX[0]) + totals[:, 1] * i32(_MIX[1])
+                       + util[:, 0] * i32(_MIX[2]) + util[:, 1] * i32(_MIX[3]))
+                delta = best_score - cand_scores
+                in_band = (delta > 0) & (delta <= NEAR_TIE_BAND60)
+                key = ((delta >> 1).astype(i32) << RIVAL_BITS) | iota
+                operands += [
+                    (jnp.where(winners, mix, _I32_MAX), _I32_MAX, jnp.minimum),
+                    (jnp.where(winners, mix, -_I32_MAX - 1), -_I32_MAX - 1,
+                     jnp.maximum),
+                    (jnp.where(in_band, key, _I32_MAX), _I32_MAX, jnp.minimum),
+                    (jnp.where(in_band, key, 0), 0, jnp.maximum),
+                    (in_band.astype(i32), 0, jnp.add)]
+            arrays, inits, joins = zip(*operands)
+            found = jlax.reduce(
+                arrays, tuple(i32(v) for v in inits),
+                lambda a, b: tuple(j(x, y) for j, x, y in zip(joins, a, b)),
+                (0,))
+            chosen = jnp.where(any_cand & (~skip_step), found[0], -1)
 
             pulls = jnp.where(skip_step, 0, jnp.sum(pulled.astype(jnp.int32))).astype(jnp.int32)
             offset = jnp.where(skip_step, offset, (offset + pulls) % nr).astype(jnp.int32)
+
+            if refereed:
+                _first, mix_lo, mix_hi, nearest, farthest, members = found
+                uneven = mix_lo != mix_hi
+                index = i32((1 << RIVAL_BITS) - 1)
+                rival = jnp.where(
+                    ((members > 0) | uneven) & any_cand & (~skip_step),
+                    (nearest & index) | ((farthest & index) << RIVAL_BITS)
+                    | (((members > 2) | uneven).astype(i32)
+                       << (2 * RIVAL_BITS)),
+                    i32(-1),
+                )
+            else:
+                rival = i32(-1)
 
         with jax.named_scope("carry_update"):
             # -- apply placement / revert eviction (one-hot adds) --------------
@@ -895,7 +954,7 @@ def _make_step():
                      offset, failed, e_base, dp_counts,
                      pre_alive, pre_remaining, pre_counts)
         out = (chosen, jnp.where(success, best_score, score_zero), pulls,
-               skip_step, evict_out)
+               skip_step, evict_out, rival)
         return new_carry, out
 
     return step
@@ -1031,9 +1090,11 @@ def _build_forced_kernel():
         chosen = jnp.where(feasible, j, -1).astype(jnp.int32)
         scores = jnp.where(feasible, final, score_zero)
         p = tg_idx.shape[0]
-        # the forced fast path never encodes preemption -> empty column
+        # the forced fast path never encodes preemption -> empty column;
+        # one candidate a step -> no rival
         return (chosen, scores, jnp.zeros(p, jnp.int32),
-                jnp.zeros(p, bool), jnp.zeros((p, 0), jnp.int32))
+                jnp.zeros(p, bool), jnp.zeros((p, 0), jnp.int32),
+                jnp.full(p, -1, jnp.int32))
 
     return jax.jit(forced_eval)
 
@@ -1071,22 +1132,34 @@ def _batched_scan_fn():
         zero = jnp.int32(0)
         _, out_shapes = jax.eval_shape(
             vstep, static_b, carry_b, at(zero), zero >= p_real)
-        # chosen, score, pulls, skipped, evict of a skipped step
-        fills = (-1, 0, 0, True, -1)
+        # chosen, score, pulls, skipped, evict, rival of a skipped step
+        fills = (-1, 0, 0, True, -1, -1)
         outs0 = tuple(
             jnp.full((p_pad,) + o.shape, fill, o.dtype)
             for o, fill in zip(out_shapes, fills))
+
+        # pulls and rival, two int32 a step, are written as ONE int64 (a
+        # buffer's update is a kernel of its own in every step) and parted
+        # again after the loop
+        def joined(outs):
+            chosen, score, pulls, skipped, evict, rival = outs
+            return (chosen, score, skipped, evict,
+                    (rival.astype(jnp.int64) << 32) | pulls.astype(jnp.int64))
 
         def body(i, state):
             carry, outs = state
             carry, out = vstep(static_b, carry, at(i), i >= p_real)
             return carry, tuple(
                 lax.dynamic_update_index_in_dim(buf, o, i, 0)
-                for buf, o in zip(outs, out))
+                for buf, o in zip(outs, joined(out)))
 
         bound = jnp.minimum(jnp.max(p_real), p_pad)
-        carry, outs = lax.fori_loop(zero, bound, body, (carry_b, outs0))
-        return carry, tuple(jnp.moveaxis(o, 0, 1) for o in outs)
+        carry, outs = lax.fori_loop(
+            zero, bound, body, (carry_b, joined(outs0)))
+        chosen, score, skipped, evict, both = (
+            jnp.moveaxis(o, 0, 1) for o in outs)
+        return carry, (chosen, score, both.astype(jnp.int32), skipped, evict,
+                       (both >> 32).astype(jnp.int32))
 
     return batched
 
@@ -1362,13 +1435,14 @@ class TpuPlacementEngine:
         xs = tuple(jnp.asarray(a) for a in xs)
         t_stack = _phases.now()
         with _phases.track("device"):
-            chosen, scores, pulls, skipped, evict = kernel(static, init_carry, xs)
+            chosen, scores, pulls, skipped, evict, rival = kernel(
+                static, init_carry, xs)
             t_called = _phases.now()
             chosen = np.asarray(chosen)
         out = (
             chosen[:p], np.asarray(scores)[:p],
             np.asarray(pulls)[:p], np.asarray(skipped)[:p],
-            np.asarray(evict)[:p],
+            np.asarray(evict)[:p], np.asarray(rival)[:p],
         )
         _record_lone_dispatch("forced", enc, p_pad, t_stack, t_called)
         return out
@@ -1393,6 +1467,7 @@ class TpuPlacementEngine:
         from ..utils import metrics as _metrics
 
         from ..trace import lifecycle as _tlc
+        from .referee import referee
 
         wave_id = sched.eval.id
         batcher = getattr(sched.planner, "device_batcher", None)
@@ -1418,19 +1493,15 @@ class TpuPlacementEngine:
             total = len(destructive) + len(place)
             if n_min and total < n_min:
                 # Warm-bucket retry ride-along: a partial OCC retry (the
-                # tail of a plan-rejected eval) is usually a few placements
-                # of a job shape whose compile bucket is ALREADY warm from
-                # the first pass — it rides that very program, and the
+                # tail of a plan-rejected eval) is a few placements of a
+                # job shape whose compile bucket is ALREADY warm from the
+                # first pass — it rides that very program, and the
                 # device's loop runs its own few steps and no padded one,
-                # while the host fallback re-walks the ranking iterators
-                # per placement. Only reroute when the batcher
-                # has completed at least one batch (so buckets exist) and
-                # the retry isn't trivially small.
-                if (
-                    batcher is None
-                    or total < RETRY_DEVICE_FLOOR
-                    or not batcher.has_warmed()
-                ):
+                # while the host stack re-walks the ranking iterators per
+                # placement (5.7 s a placement for a stanza job at 5,000
+                # nodes). The host keeps only what arrives before the
+                # batcher has completed a batch.
+                if batcher is None or not batcher.has_warmed():
                     _metrics.incr_counter("nomad.tpu_engine.small_eval_host")
                     return NotImplemented
                 _metrics.incr_counter(
@@ -1455,15 +1526,19 @@ class TpuPlacementEngine:
                 with _tlc.stage("device_wait", wave_id):
                     if batcher is not None:
                         expected_held = False  # run() consumes the token
-                        chosen, scores, pulls, skipped_steps, evict = batcher.run(
-                            enc, expected=True)
+                        outs = batcher.run(enc, expected=True)
                         # a worker with a backlog is en route again from
                         # here: to its next eval's arrival
                         again = getattr(sched.planner, "announce_next", None)
                         if again is not None:
                             again()
                     else:
-                        chosen, scores, pulls, skipped_steps, evict = self.run_scan_single(enc)
+                        outs = self.run_scan_single(enc)
+                outs = referee(
+                    enc, sched.job, sched.ctx, outs,
+                    batcher.run if batcher is not None
+                    else self.run_scan_single, wave_id)
+                chosen, scores, pulls, skipped_steps, evict = outs[:5]
             except Exception:  # noqa: BLE001 — device dispatch failed
                 # A failed/poisoned device round trip must not fail the eval:
                 # the host iterator stack computes the identical placements
@@ -2070,14 +2145,9 @@ class TpuPlacementEngine:
         xs = tuple(jnp.asarray(a) for a in enc.xs)
 
         t_stack = _phases.now()
-        _carry, (chosen, scores, pulls, skipped, evict) = place_scan(
-            enc.n_pad, static, init_carry, xs
-        )
+        _carry, outs = place_scan(enc.n_pad, static, init_carry, xs)
         t_called = _phases.now()
-        out = (
-            np.asarray(chosen), np.asarray(scores),
-            np.asarray(pulls), np.asarray(skipped), np.asarray(evict),
-        )
+        out = tuple(np.asarray(o) for o in outs)
         _record_lone_dispatch("single", enc, enc.p, t_stack, t_called)
         return out
 
@@ -2339,7 +2409,7 @@ class TpuPlacementEngine:
             if len(set(forced.tolist())) == p and pre_tables is None:
                 # (the forced fast path never encodes preemption — a preempt
                 # pass always takes the sequential scan below)
-                chosen, scores, pulls, skipped, evict = self.run_forced(enc)
+                chosen, scores, pulls, skipped, evict = self.run_forced(enc)[:5]
                 if batcher is not None:
                     # the forced kernel bypasses the gather queue; count it in
                     # the batcher's stats so dispatch accounting stays whole.
@@ -2350,9 +2420,9 @@ class TpuPlacementEngine:
                         batcher.stats["dispatches"] = batcher.stats.get("dispatches", 0) + 1
                         batcher.stats["evals"] = batcher.stats.get("evals", 0) + 1
             elif batcher is not None:
-                chosen, scores, pulls, skipped, evict = batcher.run(enc)
+                chosen, scores, pulls, skipped, evict = batcher.run(enc)[:5]
             else:
-                chosen, scores, pulls, skipped, evict = self.run_scan_single(enc)
+                chosen, scores, pulls, skipped, evict = self.run_scan_single(enc)[:5]
 
         # Preemption is a host-side greedy search per node. When enabled
         # and a forced node failed on CAPACITY (feasible by constraints

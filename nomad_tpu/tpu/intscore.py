@@ -83,6 +83,22 @@ E27_ONE = 1 << E27_BITS
 # far beyond the legitimate term range works.
 BIG_FP = 1 << 44
 
+# The near-tie band. The spec tracks the float64 score to within ~5e-7
+# (module doc; the widest distance a run of the benchmark has read is
+# 1.06e-7), so two candidates whose Q30 scores lie no farther apart than
+# two such roundings may stand the other way round in float64, and the
+# guarantee is upstream's float64 order. 2**10 Q30 units of the
+# mean-normalised score are 9.5e-7: twice the stated bound rounded to a
+# power of two. The scan's step names the candidates inside the band
+# (engine._make_step, ``rival``) and the host decides among them in
+# float64 (tpu/referee.py). score60 carries the mean times 60.
+NEAR_TIE_BAND_Q30 = 1 << 10
+NEAR_TIE_BAND60 = 60 * NEAR_TIE_BAND_Q30
+# ``rival``, one int32 a step: the nearest band member's node index, the
+# farthest's above it, then the bit "more than these two"; -1 for none
+RIVAL_BITS = 15
+RIVAL_CROWDED = 1 << (2 * RIVAL_BITS)
+
 # Max job total count for the int path (overflow gate, see module doc)
 MAX_TOTAL_COUNT = 100_000
 

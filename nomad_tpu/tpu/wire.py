@@ -224,13 +224,14 @@ class WireLayout:
         mode = np.dtype(key[0][1])
         # what the scan returns: chosen, scores (int64 score60s in the
         # integer spec, the eval's float dtype in the throughput modes),
-        # pulls, skipped, and one eviction rank per preemption candidate
-        # (an empty int32 column where the batch has none); pack_outputs
+        # pulls, skipped, one eviction rank per preemption candidate
+        # (an empty int32 column where the batch has none) and the
+        # near-tie rival; pack_outputs
         # holds the program to it. They come back in one int32 array, or
         # a float64 one for a float64 batch (pack_outputs says why).
         self.out_dtypes = tuple(np.dtype(d) for d in (
             np.int32, np.int64 if mode.kind == "i" else mode, np.int32,
-            np.bool_, np.int64 if self.prec_pad else np.int32))
+            np.bool_, np.int64 if self.prec_pad else np.int32, np.int32))
         self.carrier = np.dtype(
             np.float64 if mode == np.float64 else np.int32)
         carriers: List[np.dtype] = []
@@ -354,8 +355,9 @@ def unpack(layout: WireLayout, arrays: Sequence, xp) -> tuple:
             tuple(out[N_STATIC + N_CARRY:xs_end]), out[xs_end])
 
 
-def pack_outputs(layout: WireLayout, chosen, scores, pulls, skipped, evict):
-    """Inside the program: the scan's five outputs ``[b, p, ...]`` as ONE
+def pack_outputs(layout: WireLayout, chosen, scores, pulls, skipped, evict,
+                 rival):
+    """Inside the program: the scan's six outputs ``[b, p, ...]`` as ONE
     array ``[b, p * lanes]``, segment after segment, in the dtypes the
     layout declares (checked here, while tracing). Every int32 lane is a
     bitcast (an int64 is its two halves), so what ``split_outputs`` hands
@@ -366,7 +368,7 @@ def pack_outputs(layout: WireLayout, chosen, scores, pulls, skipped, evict):
     import jax.lax as lax
     import jax.numpy as jnp
 
-    outs = (chosen, scores, pulls, skipped, evict)
+    outs = (chosen, scores, pulls, skipped, evict, rival)
     if tuple(np.dtype(o.dtype) for o in outs) != layout.out_dtypes:
         raise TypeError(
             f"the scan returns {[str(o.dtype) for o in outs]}, the wire "
@@ -385,7 +387,7 @@ def pack_outputs(layout: WireLayout, chosen, scores, pulls, skipped, evict):
 
 def split_outputs(layout: WireLayout, host: np.ndarray):
     """The one host array back as ``(chosen, scores, pulls, skipped,
-    evict)``, each ``[b_pad, p_pad, ...]`` in its declared dtype: views of
+    evict, rival)``, each ``[b_pad, p_pad, ...]`` in its declared dtype: views of
     ``host`` where a lane is a bitcast, copies where it rode by value."""
     b, p = host.shape[0], layout.p_pad
     at = 0
@@ -405,4 +407,4 @@ def split_outputs(layout: WireLayout, host: np.ndarray):
         evict = np.zeros((b, p, 0), layout.out_dtypes[4])
     else:
         evict = take(layout.out_dtypes[4], layout.prec_pad)
-    return chosen, scores, pulls, skipped, evict
+    return chosen, scores, pulls, skipped, evict, take(layout.out_dtypes[5])

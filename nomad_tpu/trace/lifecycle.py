@@ -62,6 +62,7 @@ class EvalTrace:
         "submit_t", "evaluate_start_t", "apply_t", "commit_t", "end_t",
         "outcome", "trace_ctx",
         "snapshot_index", "commit_index", "waves", "stages",
+        "attempts", "refresh_t",
     )
 
     def __init__(self, eval_id: str, job_id: str, namespace: str,
@@ -98,6 +99,11 @@ class EvalTrace:
         # re-dispatch) and its (name, t0, t1) stages, in completion order
         self.waves: List[int] = []
         self.stages: List[Tuple[str, float, float]] = []
+        # scheduling attempts inside this ONE delivery: 1, and one more
+        # for each refresh after a partial commit (broker.refresh); the
+        # record stays open across them, so its stages repeat
+        self.attempts = 1
+        self.refresh_t: Optional[float] = None
         # carried distributed-trace context ({"trace_id","span_id"}) so
         # the record's phase spans land in the cross-process trace
         self.trace_ctx: Optional[Dict[str, str]] = None
@@ -120,6 +126,7 @@ class EvalTrace:
             "triggered_by": self.triggered_by,
             "priority": self.priority,
             "attempt": self.attempt,
+            "attempts": self.attempts,
             "worker_id": self.worker_id,
             "path": self.path,
             "outcome": self.outcome,
@@ -151,6 +158,7 @@ class EvalTrace:
             "job_id": self.job_id,
             "type": self.type,
             "attempt": self.attempt,
+            "attempts": self.attempts,
             "path": self.path,
             "outcome": self.outcome,
             "enqueue_t": self.enqueue_t,
@@ -263,9 +271,25 @@ def on_enqueue(evaluation) -> None:
 def on_dequeue(eval_id: str, attempt: int) -> None:
     with _lock:
         rec = _inflight.get(eval_id)
-        if rec is not None and rec.dequeue_t is None:
+        if rec is None:
+            return
+        if rec.dequeue_t is None:
             rec.dequeue_t = _clock()
             rec.attempt = attempt
+        elif rec.refresh_t is not None:
+            # the READY heap again, between two attempts of one delivery
+            rec.stages.append(("refresh_wait", rec.refresh_t, _clock()))
+            rec.refresh_t = None
+
+
+def on_refresh(eval_id: str) -> None:
+    """The delivery's plan committed in part and the eval goes back to
+    a worker (broker.refresh): the record stays open, one attempt more."""
+    with _lock:
+        rec = _inflight.get(eval_id)
+        if rec is not None:
+            rec.attempts += 1
+            rec.refresh_t = _clock()
 
 
 def on_worker(eval_id: str, worker_id: int) -> None:

@@ -47,7 +47,16 @@ def test_cache_dir_defaults_to_fixed_path_in_checkout(monkeypatch):
     assert os.path.isdir(engine.DEFAULT_COMPILE_CACHE_DIR)
 
 
-# -- the C1M job mix (mock.C1M_TEMPLATES, read by chip_smoke.c1m_job) ------
+# -- the C1M job mix (benchmark/configs/c1m-5k.json's jobs.templates, the
+# one table of it: read by chip_smoke.c1m_job and by the benchmark) -------
+
+
+def _c1m_templates():
+    sys.path.insert(0, REPO)
+    import chip_smoke
+
+    return chip_smoke.c1m_templates()
+
 
 # (kind, cpu, mem, count, stanzas) of the 40 templates, in order, as the
 # parent's bench.c1m_mixed_jobs built them
@@ -76,10 +85,8 @@ C1M_TABLE = [
 
 
 def test_c1m_templates_are_the_parents_forty_in_order():
-    from nomad_tpu import mock
-
-    got = [(t["kind"], t["cpu"], t["mem"], t["count"], t["stanzas"])
-           for t in mock.C1M_TEMPLATES]
+    got = [(t["kind"], t["cpu"], t["mem"], t["count"],
+            "spread" in t and "affinity" in t) for t in _c1m_templates()]
     assert got == C1M_TABLE
     assert [k for k, *_ in got] == ["service"] * 28 + ["batch"] * 12
     assert [st for *_, st in got] == [True] * 10 + [False] * 30
@@ -91,14 +98,14 @@ def test_c1m_templates_are_the_parents_forty_in_order():
 def test_c1m_job_carries_its_template():
     from nomad_tpu import mock
 
-    for tpl in mock.C1M_TEMPLATES:
-        j = mock.c1m_job(tpl, "c1m-x", tpl["count"])
+    for tpl in _c1m_templates():
+        j = mock.c1m_job(tpl, "c1m-x")
         tg = j.task_groups[0]
         res = tg.tasks[0].resources
         assert (j.type, res.cpu, res.memory_mb, tg.count) == (
             tpl["kind"], tpl["cpu"], tpl["mem"], tpl["count"])
         assert (j.id, tg.ephemeral_disk.size_mb) == ("c1m-x", 50)
-        if tpl["stanzas"]:
+        if "spread" in tpl:
             (sp,), (af,) = tg.spreads, tg.affinities
             assert (sp.attribute, sp.weight) == ("${node.datacenter}", 50)
             assert [(t.value, t.percent) for t in sp.spread_target] == [

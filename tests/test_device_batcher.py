@@ -854,11 +854,11 @@ def _case_a_dispatch_crosses_the_boundary_once_each_way():
             assert (d["p_pad"], d["n_steps"]) == (64, 5)
         # int32 mode: int32 + uint8; float modes add the float group
         assert sorted(d["h2d_arrays"] for d in recs) == [2, 3, 3]
-        # the one array down: chosen, pulls, skipped and the scores in
-        # int32 lanes (one for float32, two for int64), or, for the
-        # float64 pair, four float64 lanes
+        # the one array down: chosen, pulls, skipped, the near-tie rival
+        # and the scores in int32 lanes (one for float32, two for int64),
+        # or, for the float64 pair, five float64 lanes
         assert sorted(d["d2h_bytes"] for d in recs) == [
-            64 * 4 * 4, 64 * 5 * 4, 2 * 64 * 4 * 8]
+            64 * 5 * 4, 64 * 6 * 4, 2 * 64 * 5 * 8]
         with batcher._lock:
             stats = dict(batcher.stats)
         assert stats["h2d_arrays_total"] == 8 and stats["d2h_arrays_total"] == 3
@@ -885,7 +885,7 @@ def _case_the_mesh_path_sends_49_up_and_five_down():
                                  for s in (11, 12)])
         (d,) = _dispatches_of(batcher)
         # the 48 stacked arrays and the evals' step counts
-        assert (d["h2d_arrays"], d["d2h_arrays"]) == (49, 5)
+        assert (d["h2d_arrays"], d["d2h_arrays"]) == (49, 6)
         assert (d["n_steps"], d["padded_steps"]) == (6, d["b_pad"] * 6)
         stamps = [d[k] for k in DISPATCH_STAMPS]
         assert None not in stamps and stamps == sorted(stamps)
@@ -910,7 +910,7 @@ def _case_the_wire_scan_is_jit_body_too():
     # one flat buffer per dtype in, one int32 array out
     assert len(lowered.in_avals[0]) == len(layout.groups) == 3
     out = lowered.out_info
-    assert out.dtype == np.int32 and out.shape == (2, 64 * 4)
+    assert out.dtype == np.int32 and out.shape == (2, 64 * 5)
 
 
 @pytest.mark.parametrize("case", [
@@ -1302,7 +1302,7 @@ def _case_one_group_wave_packs_without_a_group_axis():
     assert (tg_idx[:2] == 0).all()
     np.testing.assert_array_equal(p_real[:3], [5, 12, 7])
     np.testing.assert_array_equal(p_real[3:], 5)   # inert copies of slot 0
-    _dims, (chosen, _s, _p, skipped, _e) = _scan_as_dispatched(
+    _dims, (chosen, _s, _p, skipped, _e, _r) = _scan_as_dispatched(
         mixed, mixed[0].dtype, 8)
     engine = TpuPlacementEngine.shared()
     for bi, enc in enumerate(mixed):

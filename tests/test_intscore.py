@@ -171,7 +171,7 @@ def test_scan_scores_are_exact_spec_values():
     """Every emitted score60 is on the 60-scaled mean grid: divisible by
     60//num_terms for some num_terms in 1..5 (necessary structural
     property of the exact integer normalization)."""
-    chosen, scores, pulls, skipped, _evict = _scan_outputs()
+    chosen, scores, pulls, skipped, _evict, _rival = _scan_outputs()
     assert scores.dtype == np.int64
     placed = chosen >= 0
     assert placed.any()

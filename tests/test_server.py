@@ -466,3 +466,45 @@ def test_node_capacity_event_racing_block_is_not_lost():
     assert blocked.stats()["total_blocked"] == 0
     dequeued, token = broker.dequeue(["system"], timeout=1.0)
     assert dequeued is not None and dequeued.job_id == "sysjob"
+
+
+@pytest.mark.parametrize("case", ["idle", "unmoved", "busy"])
+def test_heap_is_settled_in_idle_moments_only(case, monkeypatch):
+    """The leader freezes what survives a collection when the store has
+    moved and the broker holds no eval; an unmoved store costs no
+    collection, and an eval in flight puts it off. Leadership begins with
+    a freeze and ends with the unfreeze."""
+    from nomad_tpu.server import server as server_mod
+
+    calls = []
+
+    class Collector:
+        collect = staticmethod(lambda: calls.append("collect"))
+        freeze = staticmethod(lambda: calls.append("freeze"))
+        unfreeze = staticmethod(lambda: calls.append("unfreeze"))
+
+    monkeypatch.setattr(server_mod, "gc", Collector)
+    # the test calls the leader task itself: keep the timer out of it
+    monkeypatch.setattr(server_mod, "HEAP_SETTLE_INTERVAL_S", 3600.0)
+    s = Server(ServerConfig(num_schedulers=0, scheduler_algorithm="binpack"))
+    s.start()
+    try:
+        assert calls == ["freeze"]
+        s.register_node(mock.node())
+        if case == "busy":
+            s.raft_apply("eval-update", [mock.eval()])
+            got, token = s.eval_broker.dequeue(["service"], timeout=2)
+            assert got is not None
+            s._settle_heap()
+            assert calls == ["freeze"]
+            assert s._heap_settled_index != s.fsm.state.latest_index
+            s.eval_broker.ack(got.id, token)
+        s._settle_heap()
+        assert calls == ["freeze", "collect", "freeze"]
+        assert s._heap_settled_index == s.fsm.state.latest_index
+        if case == "unmoved":
+            s._settle_heap()
+            assert calls == ["freeze", "collect", "freeze"]
+    finally:
+        s.stop()
+    assert calls[-1] == "unfreeze"     # a server that stops gives it back
