@@ -337,6 +337,9 @@ class DeviceBatcher:
         # read-modify-write takes _lock (enforced by nomad-lint).
         self.stats = tracked_dict("batcher.DeviceBatcher.stats", {  # guarded-by: _lock
             "dispatches": 0,
+            # of those, the ones of a single eval slot (b_pad == 1): they
+            # run the program without a batch axis (engine._batched_scan_fn)
+            "lone_dispatches": 0,
             "evals": 0,
             "max_batch_seen": 0,
             "padded_evals": 0,
@@ -930,6 +933,7 @@ class DeviceBatcher:
 
         with self._lock:
             self.stats["dispatches"] += 1
+            self.stats["lone_dispatches"] += int(b_pad == 1)
             self.stats["evals"] += b
             self.stats["padded_evals"] += b_pad - b
             self.stats["max_batch_seen"] = max(self.stats["max_batch_seen"], b)

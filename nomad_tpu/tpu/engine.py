@@ -26,6 +26,7 @@ all-reduce/argmax collectives.
 from __future__ import annotations
 
 import logging
+import math
 import os
 import time as _time
 from functools import partial
@@ -218,6 +219,14 @@ def _make_step():
     indexed formulation — fuzz-asserted against the host pipeline in
     tests/test_tpu_parity.py.
 
+    ``static`` and ``carry`` arrive in the step's own layout
+    (``_step_layout``, applied once by every caller, outside its loop):
+    node planes with the node axis LAST, one axis of ``n_pad`` or, in a
+    program without a batch axis, two, ``(n_pad // 128, 128)``. The step
+    reads the node shape off ``job_counts`` and is written for either:
+    small arrays meet node planes through ``col``, reductions over nodes
+    name ``nodes``.
+
     The stages of a step carry ``jax.named_scope`` names, so a profiler
     trace groups the scan's operations by what they are for (metadata
     only: the lowered program is bit for bit the unscoped one):
@@ -264,7 +273,11 @@ def _make_step():
         (tg_idx, penalty_idx, evict_node, evict_res, evict_tg, limit_p,
          sum_sw_p, ev_factor, rev_factor, forced_node) = x
 
-        n_pad = totals.shape[0]
+        # node planes lie node-axis LAST (``_step_layout``): one axis of
+        # n_pad, or two, (n_pad // 128, 128), where the program folded it
+        node_shape = job_counts.shape
+        nodes = tuple(range(-len(node_shape), 0))
+        n_pad = math.prod(node_shape)
         g_count = asks.shape[0]
         v_plus = spread_desired.shape[-1]
         fdt = totals.dtype
@@ -277,8 +290,12 @@ def _make_step():
 
         iota_g = jnp.arange(g_count, dtype=jnp.int32)
         sel_g = (iota_g == g)                       # [G] one-hot of the TG
-        iota = jnp.arange(n_pad, dtype=jnp.int32)
+        iota = jnp.arange(n_pad, dtype=jnp.int32).reshape(node_shape)
         iota_v = jnp.arange(v_plus, dtype=jnp.int32)
+
+        def col(arr):
+            # a small array against node planes: one unit axis per node axis
+            return arr.reshape(arr.shape + (1,) * len(node_shape))
 
         def pick_g(arr, fill=0):
             # arr[g] without gather/dot: one-hot mask + sum (exactly one
@@ -304,7 +321,7 @@ def _make_step():
                 do_evict = (evict_node >= 0) & (~skip_step)
                 ev_node = jnp.maximum(evict_node, 0)
                 ev_tg = jnp.maximum(evict_tg, 0)
-                oh_ev_node = (iota == ev_node)              # [N]
+                oh_ev_node = (iota == ev_node)              # [*N]
                 oh_ev_nodef = oh_ev_node.astype(fdt)
                 sel_evg = (iota_g == ev_tg)                 # [G]
 
@@ -314,29 +331,29 @@ def _make_step():
                     return out.astype(arr.dtype)
 
                 evict_vec = jnp.where(do_evict, evict_res, 0)  # [D]
-                used = used - oh_ev_nodef[:, None] * evict_vec[None, :]
+                used = used - col(evict_vec) * oh_ev_nodef
                 dec_tg = jnp.where(do_evict & (evict_tg >= 0), 1, 0)
-                tg_counts = tg_counts - (sel_evg[:, None] & oh_ev_node[None, :]) * dec_tg
+                tg_counts = tg_counts - (col(sel_evg) & oh_ev_node) * dec_tg
                 job_counts = job_counts - oh_ev_node * jnp.where(do_evict, 1, 0)
                 # The evicted alloc's spread usage clears too (host: propertyset
                 # cleared_values from plan.node_update; floor-at-zero at read).
                 ev_active = pick_evg(spread_active, False)       # [S]
                 ev_dec = jnp.where(do_evict & (evict_tg >= 0) & ev_active, 1, 0).astype(fdt)
-                vids_evg = pick_evg(spread_vids)                 # [S, N]
-                ev_vid = jnp.sum(jnp.where(oh_ev_node[None, :], vids_evg, 0), axis=1)
+                vids_evg = pick_evg(spread_vids)                 # [S, *N]
+                ev_vid = jnp.sum(jnp.where(oh_ev_node, vids_evg, 0), axis=nodes)
                 oh_ev_vid = (iota_v[None, :] == ev_vid[:, None]).astype(fdt)  # [S, V]
                 spread_counts = spread_counts - jnp.where(
                     sel_evg[:, None, None], (oh_ev_vid * ev_dec[:, None])[None, :, :], 0
                 )
                 # eviction frees capacity -> multiply the node's Q27
                 # exponential by the precomputed per-placement factor
-                if e_base.shape[0]:
+                if e_base.size:
                     from .intscore import E27_BITS, E27_ONE
 
                     ev_f = jnp.where(do_evict, ev_factor, E27_ONE).astype(i64)  # [2]
-                    eb_ev = (e_base.astype(i64) * ev_f[None, :]) >> E27_BITS
+                    eb_ev = (e_base.astype(i64) * col(ev_f)) >> E27_BITS
                     e_base = jnp.where(
-                        oh_ev_node[:, None], eb_ev, e_base.astype(i64)
+                        oh_ev_node, eb_ev, e_base.astype(i64)
                     ).astype(jnp.int32)
                 # (distinct_property + in-eval evictions never encode together
                 # — the host PropertySet cleared-refund quirk can't be
@@ -348,9 +365,9 @@ def _make_step():
             # ONE packed uint8 feature plane carries feasibility and affinity
             # presence (intscore.pack_feat_planes): one pick_g pass where the
             # unpacked layout needed two
-            feat_g = pick_g(feat_packed)                     # [N] uint8
+            feat_g = pick_g(feat_packed)                     # [*N] uint8
             feas_g = unpack_feat_lane(feat_g, FEAT_FEAS_BIT)
-            tg_counts_g = pick_g(tg_counts)                  # [N]
+            tg_counts_g = pick_g(tg_counts)                  # [*N]
             desired_g = pick_g(desired_counts).astype(fdt)
             dh_job_g = jnp.any(sel_g & dh_job)
             dh_tg_g = jnp.any(sel_g & dh_tg)
@@ -359,8 +376,8 @@ def _make_step():
             # score term vanish from the compiled step entirely (the packed
             # plane's affinity lane is all-zero and never read)
             if aff_score.shape[0] == 0:
-                aff = jnp.zeros(n_pad, fdt)
-                aff_p = jnp.zeros(n_pad, bool)
+                aff = jnp.zeros(node_shape, fdt)
+                aff_p = jnp.zeros(node_shape, bool)
             else:
                 aff = pick_g(aff_score)
                 aff_p = unpack_feat_lane(feat_g, FEAT_AFF_BIT)
@@ -371,11 +388,11 @@ def _make_step():
             # exponentials are precomputed factors, so nothing else needs the
             # split) and passes a ZERO-height reserved — one [N, D] add less
             # per step
-            if reserved.shape[0]:
-                util = used + reserved + ask[None, :]  # [N, D]
+            if reserved.size:
+                util = used + reserved + col(ask)  # [D, *N]
             else:
-                util = used + ask[None, :]
-            fits = jnp.all(util <= totals, axis=-1)  # superset + bandwidth check
+                util = used + col(ask)
+            fits = jnp.all(util <= totals, axis=0)  # superset + bandwidth check
 
             # job-level distinct_hosts: any co-located alloc of the job rejects;
             # tg-level requires both a job and task-group collision
@@ -397,6 +414,10 @@ def _make_step():
             has_pre = pre_res.shape[1] > 0
             if has_pre:
                 from .preempt import CQ_BITS, PENALTY_UNIT, greedy_select_jnp
+
+                # the candidate tables stay as the wire has them, node axis
+                # first ([N, C, ...]): ``_step_layout`` folds no such eval
+                assert len(node_shape) == 1
 
                 gp_w = pre_counts.shape[0]
                 iota_gp = jnp.arange(gp_w, dtype=jnp.int32)
@@ -423,7 +444,7 @@ def _make_step():
                 cap_ok = fits
 
         with jax.named_scope("feasibility"):
-            feasible = feas_g & cap_ok & dh_mask  # [N]
+            feasible = feas_g & cap_ok & dh_mask  # [*N]
             # system-scheduler mode: the candidate node is FIXED per placement
             # (one alloc per eligible node, system_sched.go:268-286); a
             # zero-width axis (generic evals) compiles the restriction away
@@ -438,15 +459,15 @@ def _make_step():
             if dp_vids.shape[0]:
                 v2 = dp_counts.shape[-1]
                 iota_v2 = jnp.arange(v2, dtype=jnp.int32)
-                oh_dpv = dp_vids[:, None, :] == iota_v2[None, :, None]  # [D, V2, N]
+                oh_dpv = dp_vids[:, None] == col(iota_v2)  # [D, V2, *N]
                 dp_cnts = jnp.maximum(dp_counts, 0)  # cleared-value floor
                 dp_cnt_n = jnp.sum(
-                    jnp.where(oh_dpv, dp_cnts[:, :, None], 0), axis=1
-                )  # [D, N]
+                    jnp.where(oh_dpv, col(dp_cnts), 0), axis=1
+                )  # [D, *N]
                 dp_applies_g = pick_g(dp_applies, False)  # [D]
                 dp_missing = dp_vids == (v2 - 1)
-                dp_ok = (~dp_applies_g[:, None]) | (
-                    (~dp_missing) & (dp_cnt_n < dp_limit[:, None])
+                dp_ok = (~col(dp_applies_g)) | (
+                    (~dp_missing) & (dp_cnt_n < col(dp_limit))
                 )
                 feasible = feasible & jnp.all(dp_ok, axis=0)
 
@@ -459,17 +480,17 @@ def _make_step():
         #   float (throughput): f32 arithmetic, non-parity.
         with jax.named_scope("affinity"):
             # same specialization: no reschedule history -> penalty_idx has a
-            # zero K axis and the [N, K] compare disappears
+            # zero K axis and the [K, *N] compare disappears
             if penalty_idx.shape[-1] == 0:
-                pmask = jnp.zeros(n_pad, bool)
+                pmask = jnp.zeros(node_shape, bool)
             else:
-                pmask = jnp.any(iota[:, None] == penalty_idx[None, :], axis=-1)
+                pmask = jnp.any(iota == col(penalty_idx), axis=0)
 
             anti_present = tg_counts_g > 0
 
         with jax.named_scope("spread"):
             # spread row selects (shared) — value-id lookups as one-hot sums
-            vids = pick_g(spread_vids)                       # [S, N]
+            vids = pick_g(spread_vids)                       # [S, *N]
             # floor-at-zero matches the host's cleared-value clamping
             s_counts = jnp.maximum(pick_g(spread_counts), 0)    # [S, V]
             s_entry = pick_g(spread_entry, False)            # [S, V]
@@ -479,8 +500,8 @@ def _make_step():
             active_s = pick_g(spread_active, False)
 
             invalid_bucket = v_plus - 1
-            oh_vids = vids[:, None, :] == iota_v[None, :, None]  # [S, V, N]
-            current = jnp.sum(jnp.where(oh_vids, s_counts[:, :, None], 0), axis=1)
+            oh_vids = vids[:, None] == col(iota_v)           # [S, V, *N]
+            current = jnp.sum(jnp.where(oh_vids, col(s_counts), 0), axis=1)
             missing = vids == invalid_bucket
             has_entries = jnp.any(s_entry[:, :invalid_bucket], axis=-1)  # [S]
 
@@ -498,10 +519,10 @@ def _make_step():
             with jax.named_scope("binpack_score"):
                 # selection-time exponentials: e_base (running product in the
                 # carry) times the static per-TG ask factor — 10**(free - ask/cap)
-                ea = pick_g(e_ask)                                 # [N, 2] int32
+                ea = pick_g(e_ask)                                 # [2, *N] int32
                 e_sel = (e_base.astype(i64) * ea.astype(i64)) >> E27_BITS
                 e_sel_i32 = e_sel.astype(jnp.int32)                # placement update
-                fit = i64(20 * E27_ONE) - e_sel[:, 0] - e_sel[:, 1]
+                fit = i64(20 * E27_ONE) - e_sel[0] - e_sel[1]
                 fit = jnp.clip(fit, 0, 18 * E27_ONE)
                 # Q30 = fit * 2**30 / (18 * 2**27) = (fit*4)//9, as one
                 # multiply and one shift (an int64 // is a 30-kernel long
@@ -541,8 +562,8 @@ def _make_step():
                     jnp.where(d64 == 0, i64(-BIG_FP), i64(-TERM_ONE)),
                 )
                 targeted_raw = jnp.sum(
-                    jnp.where(oh_vids, targeted_sv[:, :, None], 0), axis=1
-                )                                                  # [S, N]
+                    jnp.where(oh_vids, col(targeted_sv), 0), axis=1
+                )                                                  # [S, *N]
 
                 # even-spread boost (same branch structure as the host);
                 # divisions by min_c (a count) via its Q45 reciprocal — [S]-
@@ -559,32 +580,33 @@ def _make_step():
                 r_min = jnp.floor_divide(
                     i64(1 << RECIP_BITS), jnp.maximum(min_c, 1)
                 )  # [S]
-                min_cn = min_c[:, None]
+                min_cn = col(min_c)
+                max_cn = col(max_c)
                 cur64 = current.astype(i64)
                 delta_boost = jnp.where(
                     min_cn == 0,
                     i64(-TERM_ONE),
-                    ((min_cn - cur64) * r_min[:, None]) >> rsh,
+                    ((min_cn - cur64) * col(r_min)) >> rsh,
                 )
                 even = jnp.where(
                     cur64 != min_cn,
                     delta_boost,
                     jnp.where(
-                        min_cn == max_c[:, None],
+                        min_cn == max_cn,
                         i64(-TERM_ONE),
                         jnp.where(
                             min_cn == 0,
                             i64(TERM_ONE),
-                            ((max_c[:, None] - min_cn) * r_min[:, None]) >> rsh,
+                            ((max_cn - min_cn) * col(r_min)) >> rsh,
                         ),
                     ),
                 )
-                even = jnp.where(has_entries[:, None], even, 0)
+                even = jnp.where(col(has_entries), even, 0)
 
-                per_spread = jnp.where(has_targets_s[:, None], targeted_raw, even)
+                per_spread = jnp.where(col(has_targets_s), targeted_raw, even)
                 per_spread = jnp.where(missing, i64(-TERM_ONE), per_spread)
-                per_spread = jnp.where(active_s[:, None], per_spread, 0)
-                spread_total = jnp.sum(per_spread, axis=0)  # [N] int64
+                per_spread = jnp.where(col(active_s), per_spread, 0)
+                spread_total = jnp.sum(per_spread, axis=0)  # [*N] int64
                 spread_p = spread_total != 0
 
             with jax.named_scope("score_mean"):
@@ -604,10 +626,10 @@ def _make_step():
                 score_zero = i64(0)
         else:
             with jax.named_scope("binpack_score"):
-                node_cpu = totals[:, DIM_CPU] - reserved[:, DIM_CPU]
-                node_mem = totals[:, DIM_MEM] - reserved[:, DIM_MEM]
-                free_cpu = 1.0 - util[:, DIM_CPU] / jnp.maximum(node_cpu, 1e-9)
-                free_mem = 1.0 - util[:, DIM_MEM] / jnp.maximum(node_mem, 1e-9)
+                node_cpu = totals[DIM_CPU] - reserved[DIM_CPU]
+                node_mem = totals[DIM_MEM] - reserved[DIM_MEM]
+                free_cpu = 1.0 - util[DIM_CPU] / jnp.maximum(node_cpu, 1e-9)
+                free_mem = 1.0 - util[DIM_MEM] / jnp.maximum(node_mem, 1e-9)
                 fitness = 20.0 - (jnp.power(10.0, free_cpu) + jnp.power(10.0, free_mem))
                 binpack = jnp.clip(fitness, 0.0, 18.0) / 18.0
 
@@ -618,13 +640,13 @@ def _make_step():
 
             with jax.named_scope("spread"):
                 big = jnp.finfo(fdt).max / 16.0
-                used_count = current.astype(fdt) + 1.0           # [S, N]
+                used_count = current.astype(fdt) + 1.0           # [S, *N]
                 df = jnp.sum(
-                    jnp.where(oh_vids, desired_sv[:, :, None], 0), axis=1
+                    jnp.where(oh_vids, col(desired_sv), 0), axis=1
                 ).astype(fdt)
                 # divisor: the host SpreadIterator's weight sum accumulates
                 # across visited task groups -> passed per placement (sum_sw_p)
-                weight_frac = weights_s[:, None] / jnp.maximum(sum_sw_p, 1e-9)
+                weight_frac = col(weights_s) / jnp.maximum(sum_sw_p, 1e-9)
                 # Go float semantics: d == 0 -> -Inf boost (clamped large neg)
                 targeted_raw = jnp.where(
                     df > 0.0,
@@ -639,29 +661,31 @@ def _make_step():
                 max_counts = jnp.where(s_entry[:, :invalid_bucket], scf, -jnp.inf)
                 max_c = jnp.where(has_entries, jnp.max(max_counts, axis=-1), 0.0)
                 currentf = current.astype(fdt)
+                min_cn = col(min_c)
+                max_cn = col(max_c)
                 delta_boost = jnp.where(
-                    min_c[:, None] == 0.0, -1.0,
-                    (min_c[:, None] - currentf) / jnp.maximum(min_c[:, None], 1e-9)
+                    min_cn == 0.0, -1.0,
+                    (min_cn - currentf) / jnp.maximum(min_cn, 1e-9)
                 )
                 even = jnp.where(
-                    currentf != min_c[:, None],
+                    currentf != min_cn,
                     delta_boost,
                     jnp.where(
-                        min_c[:, None] == max_c[:, None],
+                        min_cn == max_cn,
                         -1.0,
                         jnp.where(
-                            min_c[:, None] == 0.0,
+                            min_cn == 0.0,
                             1.0,
-                            (max_c[:, None] - min_c[:, None]) / jnp.maximum(min_c[:, None], 1e-9),
+                            (max_cn - min_cn) / jnp.maximum(min_cn, 1e-9),
                         ),
                     ),
                 )
-                even = jnp.where(has_entries[:, None], even, 0.0)
+                even = jnp.where(col(has_entries), even, 0.0)
 
-                per_spread = jnp.where(has_targets_s[:, None], targeted_raw, even)
+                per_spread = jnp.where(col(has_targets_s), targeted_raw, even)
                 per_spread = jnp.where(missing, -1.0, per_spread)
-                per_spread = jnp.where(active_s[:, None], per_spread, 0.0)
-                spread_total = jnp.sum(per_spread, axis=0)  # [N]
+                per_spread = jnp.where(col(active_s), per_spread, 0.0)
+                spread_total = jnp.sum(per_spread, axis=0)  # [*N]
                 spread_p = spread_total != 0.0
 
             with jax.named_scope("score_mean"):
@@ -703,8 +727,11 @@ def _make_step():
             low = feas_v & (final <= 0)
 
             def ring_cumsum(a_int):
-                s_nat = jnp.cumsum(a_int)
-                total = s_nat[-1]
+                # the natural order is the flat one: a folded plane is the
+                # same words in the same order, so the flat view is free
+                s_flat = jnp.cumsum(a_int.reshape(n_pad))
+                s_nat = s_flat.reshape(node_shape)
+                total = s_flat[-1]
                 before = jnp.sum(jnp.where(iota < offset, a_int, 0),
                                  dtype=jnp.int32)
                 ring = jnp.where(
@@ -777,8 +804,8 @@ def _make_step():
             refereed = int_mode and n_pad <= (1 << RIVAL_BITS)
             operands = [(jnp.where(first, iota, _I32_MAX), _I32_MAX, jnp.minimum)]
             if refereed:
-                mix = (totals[:, 0] * i32(_MIX[0]) + totals[:, 1] * i32(_MIX[1])
-                       + util[:, 0] * i32(_MIX[2]) + util[:, 1] * i32(_MIX[3]))
+                mix = (totals[0] * i32(_MIX[0]) + totals[1] * i32(_MIX[1])
+                       + util[0] * i32(_MIX[2]) + util[1] * i32(_MIX[3]))
                 delta = best_score - cand_scores
                 in_band = (delta > 0) & (delta <= NEAR_TIE_BAND60)
                 key = ((delta >> 1).astype(i32) << RIVAL_BITS) | iota
@@ -793,7 +820,7 @@ def _make_step():
             found = jlax.reduce(
                 arrays, tuple(i32(v) for v in inits),
                 lambda a, b: tuple(j(x, y) for j, x, y in zip(joins, a, b)),
-                (0,))
+                tuple(range(len(node_shape))))
             chosen = jnp.where(any_cand & (~skip_step), found[0], -1)
 
             pulls = jnp.where(skip_step, 0, jnp.sum(pulled.astype(jnp.int32))).astype(jnp.int32)
@@ -820,12 +847,12 @@ def _make_step():
             oh_ch = (iota == ch)
             oh_chf = oh_ch.astype(fdt)
             add_vec = jnp.where(success, ask, 0)
-            used = used + oh_chf[:, None] * add_vec[None, :]
+            used = used + col(add_vec) * oh_chf
             inc_i = jnp.where(success, 1, 0)
-            tg_counts = tg_counts + (sel_g[:, None] & oh_ch[None, :]) * inc_i
+            tg_counts = tg_counts + (col(sel_g) & oh_ch) * inc_i
             job_counts = job_counts + oh_ch * inc_i
 
-            ch_vid = jnp.sum(jnp.where(oh_ch[None, :], vids, 0), axis=1)  # [S]
+            ch_vid = jnp.sum(jnp.where(oh_ch, vids, 0), axis=nodes)  # [S]
             oh_ch_vid = (iota_v[None, :] == ch_vid[:, None])              # [S, V]
             inc = jnp.where(success & active_s, 1, 0).astype(fdt)
             spread_counts = spread_counts + jnp.where(
@@ -836,10 +863,10 @@ def _make_step():
 
             # placement commits the chosen node's new exponential — EXACTLY the
             # already-computed selection value (running-product spec)
-            if e_base.shape[0]:
-                e_base = jnp.where((oh_ch & success)[:, None], e_sel_i32, e_base)
+            if e_base.size:
+                e_base = jnp.where(oh_ch & success, e_sel_i32, e_base)
             if dp_vids.shape[0]:
-                ch_vid_dp = jnp.sum(jnp.where(oh_ch[None, :], dp_vids, 0), axis=1)  # [D]
+                ch_vid_dp = jnp.sum(jnp.where(oh_ch, dp_vids, 0), axis=nodes)  # [D]
                 inc_dp = dp_applies_g & success
                 dp_counts = dp_counts + (
                     (iota_v2[None, :] == ch_vid_dp[:, None]) & inc_dp[:, None]
@@ -879,7 +906,7 @@ def _make_step():
                     jnp.where(keep[:, None], res4_ch.astype(fdt), 0), axis=0,
                     dtype=fdt,
                 )                                                  # [4]
-                d_dims = totals.shape[1]
+                d_dims = totals.shape[0]
                 if d_dims > 4:
                     # batch padding may widen D past the gate's 4 dims; the
                     # extra (device) dims free nothing
@@ -888,21 +915,22 @@ def _make_step():
                     )
                 else:
                     freed_vec = freed4[:d_dims]
-                used = used - oh_chf[:, None] * freed_vec[None, :]
+                used = used - col(freed_vec) * oh_chf
 
                 # running Q27 exponential: multiply the just-committed chosen
                 # row by each kept candidate's eviction factor (slot-ascending
                 # product order is fixed, so the result is deterministic)
-                if e_base.shape[0]:
+                if e_base.size:
                     from .intscore import E27_BITS as _PB, E27_ONE as _PO
 
-                    eb_ch = row_c(e_base).astype(i64)              # [2]
+                    eb_ch = jnp.sum(jnp.where(oh_ch, e_base, 0), axis=nodes,
+                                    dtype=i64)                     # [2]
                     evf_ch = row_c(pre_evf)                        # [C, 2] i32
                     for ci in range(c_w):
                         f = jnp.where(keep[ci], evf_ch[ci].astype(i64), i64(_PO))
                         eb_ch = (eb_ch * f) >> _PB
                     e_base = jnp.where(
-                        (oh_ch & use_pre)[:, None], eb_ch.astype(jnp.int32), e_base
+                        oh_ch & use_pre, col(eb_ch.astype(jnp.int32)), e_base
                     )
 
                 evicted = oh_ch[:, None] & keep[None, :]           # [N, C]
@@ -928,22 +956,22 @@ def _make_step():
             # failed placement: revert eviction, mark TG failed
             if has_evict:
                 revert = do_evict & (~success)
-                used = used + oh_ev_nodef[:, None] * jnp.where(revert, evict_res, 0)[None, :]
+                used = used + col(jnp.where(revert, evict_res, 0)) * oh_ev_nodef
                 rev_i = jnp.where(revert & (evict_tg >= 0), 1, 0)
-                tg_counts = tg_counts + (sel_evg[:, None] & oh_ev_node[None, :]) * rev_i
+                tg_counts = tg_counts + (col(sel_evg) & oh_ev_node) * rev_i
                 job_counts = job_counts + oh_ev_node * jnp.where(revert, 1, 0)
                 spread_counts = spread_counts + jnp.where(
                     sel_evg[:, None, None],
                     (oh_ev_vid * jnp.where(revert, ev_dec, 0).astype(fdt)[:, None])[None, :, :],
                     0,
                 )
-                if e_base.shape[0]:
+                if e_base.size:
                     from .intscore import E27_BITS as _E27B, E27_ONE as _E27O
 
                     rev_f = jnp.where(revert, rev_factor, _E27O).astype(i64)  # [2]
-                    eb_rev = (e_base.astype(i64) * rev_f[None, :]) >> _E27B
+                    eb_rev = (e_base.astype(i64) * col(rev_f)) >> _E27B
                     e_base = jnp.where(
-                        oh_ev_node[:, None], eb_rev, e_base.astype(i64)
+                        oh_ev_node, eb_rev, e_base.astype(i64)
                     ).astype(jnp.int32)
             # forced-node (system) placements are independent per-node
             # decisions: a failure must NOT poison the TG for later nodes
@@ -960,6 +988,60 @@ def _make_step():
     return step
 
 
+# Where the wire layout (EncodedEval.static / .carry, tpu/wire.py) keeps a
+# leaf's node axis; the preemption tables (node axis first, [N, C, ...])
+# are not listed: preempt.py's kernels read them as they are.
+_STATIC_NODE_AXIS = {0: 0, 1: 0, 3: 1, 4: 1, 9: 2, 16: 1, 17: 1}
+_CARRY_NODE_AXIS = {0: 0, 1: 1, 2: 0, 7: 0}
+_LANES = 128
+
+
+def _step_layout(static, carry, fold):
+    """One eval's ``static`` and ``carry`` as ``_make_step``'s step reads
+    them: every node plane with its node axis LAST, and with ``fold`` that
+    axis viewed as ``(n_pad // 128, 128)``. The TPU tiles an array by its
+    last two axes: ``[n_pad, 2]``, ``[n_pad, 4]`` and a ``[1, n_pad]`` row
+    of a single task group or spread fill two, four and one sublane of a
+    register's eight, where ``[2, 40, 128]`` fills every one. A program
+    without a batch axis folds; under ``vmap`` the batch axis fills the
+    sublanes. A zero-sized leaf has no node axis and stays as it is; an
+    eval with preemption candidates, whose tables keep the node axis
+    first, and a fleet padded to less than a lane row are not folded.
+    Called once, outside the loop; ``_wire_carry`` is its inverse."""
+    import jax.numpy as jnp
+
+    n_pad = carry[2].shape[0]
+    fold = fold and n_pad % _LANES == 0 and static[20].shape[1] == 0
+
+    def lay(leaves, node_axis):
+        out = list(leaves)
+        for i, axis in node_axis.items():
+            a = leaves[i]
+            if a.shape[axis] != n_pad:
+                continue
+            a = jnp.moveaxis(a, axis, -1)
+            if fold:
+                a = a.reshape(a.shape[:-1] + (n_pad // _LANES, _LANES))
+            out[i] = a
+        return tuple(out)
+
+    return lay(static, _STATIC_NODE_AXIS), lay(carry, _CARRY_NODE_AXIS)
+
+
+def _wire_carry(carry):
+    """A step-layout carry back in the wire's layout."""
+    import jax.numpy as jnp
+
+    out = list(carry)
+    node_dims = carry[2].ndim
+    for i, axis in _CARRY_NODE_AXIS.items():
+        a = carry[i]
+        if a.size:
+            flat = a.reshape(a.shape[:a.ndim - node_dims] + (-1,))
+            out[i] = jnp.moveaxis(flat, -1, axis)
+    return tuple(out)
+
+
 def _build_place_scan():
     import jax
 
@@ -974,7 +1056,10 @@ def _build_place_scan():
     def place_scan(n_pad, static, init_carry, xs):
         import jax.lax as lax
 
-        return lax.scan(lambda c, x: step(static, c, x), init_carry, xs)
+        static, init_carry = _step_layout(static, init_carry, fold=True)
+        carry, outs = lax.scan(
+            lambda c, x: step(static, c, x), init_carry, xs)
+        return _wire_carry(carry), outs
 
     return place_scan
 
@@ -1109,6 +1194,13 @@ def _batched_scan_fn():
     point at. The predicate is unbatched by construction: a ``while`` with
     a batched one would put a ``select`` on every carry.
 
+    A wave of ONE eval (a static shape: the leading axis is 1) runs the
+    step itself, with no batch axis: under ``vmap`` at b = 1 the TPU tiles
+    a ``[1, n_pad]`` node plane ``T(1,128)``, one sublane of eight in use
+    and forty register-rows a pass over 5,120 nodes, where the bare
+    ``[n_pad]`` vector is a full tile. Same step, same loop, same outputs:
+    the axis comes off the inputs before the loop and goes back on after.
+
     The outputs are ``[b, p_pad, ...]`` buffers pre-filled with what a
     skipped step returns and written at the step's index; rows at or past
     the bound keep the fill, and no caller reads them."""
@@ -1116,12 +1208,23 @@ def _batched_scan_fn():
     import jax.lax as lax
     import jax.numpy as jnp
 
-    vstep = jax.vmap(_make_step())
+    step = _make_step()
+    vstep = jax.vmap(step)
 
     def batched(static_b, carry_b, xs_b, p_real):
-        # step-major, as lax.scan lays its xs and ys out: one step's row
-        # of every eval is one contiguous slice
-        xs_t = tuple(jnp.moveaxis(a, 1, 0) for a in xs_b)
+        lone = p_real.shape[0] == 1
+        if lone:
+            static_b, carry_b, xs_t, p_real = jax.tree_util.tree_map(
+                lambda a: a[0], (static_b, carry_b, tuple(xs_b), p_real))
+            static_b, carry_b = _step_layout(static_b, carry_b, fold=True)
+            run = step
+        else:
+            # step-major, as lax.scan lays its xs and ys out: one step's
+            # row of every eval is one contiguous slice
+            xs_t = tuple(jnp.moveaxis(a, 1, 0) for a in xs_b)
+            static_b, carry_b = jax.vmap(
+                partial(_step_layout, fold=False))(static_b, carry_b)
+            run = vstep
 
         def at(i):
             return tuple(
@@ -1131,7 +1234,7 @@ def _batched_scan_fn():
         p_pad = xs_t[0].shape[0]
         zero = jnp.int32(0)
         _, out_shapes = jax.eval_shape(
-            vstep, static_b, carry_b, at(zero), zero >= p_real)
+            run, static_b, carry_b, at(zero), zero >= p_real)
         # chosen, score, pulls, skipped, evict, rival of a skipped step
         fills = (-1, 0, 0, True, -1, -1)
         outs0 = tuple(
@@ -1148,7 +1251,7 @@ def _batched_scan_fn():
 
         def body(i, state):
             carry, outs = state
-            carry, out = vstep(static_b, carry, at(i), i >= p_real)
+            carry, out = run(static_b, carry, at(i), i >= p_real)
             return carry, tuple(
                 lax.dynamic_update_index_in_dim(buf, o, i, 0)
                 for buf, o in zip(outs, joined(out)))
@@ -1156,8 +1259,14 @@ def _batched_scan_fn():
         bound = jnp.minimum(jnp.max(p_real), p_pad)
         carry, outs = lax.fori_loop(
             zero, bound, body, (carry_b, joined(outs0)))
-        chosen, score, skipped, evict, both = (
-            jnp.moveaxis(o, 0, 1) for o in outs)
+        if lone:
+            carry = jax.tree_util.tree_map(
+                lambda a: a[None], _wire_carry(carry))
+            outs = tuple(o[None] for o in outs)
+        else:
+            carry = jax.vmap(_wire_carry)(carry)
+            outs = tuple(jnp.moveaxis(o, 0, 1) for o in outs)
+        chosen, score, skipped, evict, both = outs
         return carry, (chosen, score, both.astype(jnp.int32), skipped, evict,
                        (both >> 32).astype(jnp.int32))
 

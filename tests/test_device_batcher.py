@@ -198,6 +198,33 @@ class TestBatchedScanParity:
             np.testing.assert_array_equal(single[0], batch_r[0])
             np.testing.assert_array_equal(single[1], batch_r[1])
 
+    @pytest.mark.parametrize("n_nodes", [32, 512],
+                             ids=["unfolded", "folded"])
+    def test_mesh_sharded_lone_wave_matches_single(self, n_nodes):
+        """One eval alone on a mesh whose "evals" axis is 1 takes the
+        program without a batch axis: the axis comes off (and 512 nodes
+        fold to four lane rows) inside the program, after the shardings,
+        and the result is the unsharded single scan's."""
+        import jax
+
+        if len(jax.devices()) < 4:
+            pytest.skip("needs >=4 devices")
+        from nomad_tpu.parallel import make_mesh
+
+        enc = synthetic_enc(n_nodes, 2, 12, n_spreads=1, seed=21,
+                            dtype=np.int32)
+        single = TpuPlacementEngine.shared().run_scan_single(enc)
+        batcher = DeviceBatcher(max_batch=4, window_ms=200.0,
+                                mesh=make_mesh(4, eval_parallel=1))
+        try:
+            (batch_r,) = run_concurrent(batcher, [enc])
+        finally:
+            batcher.stop()
+        assert batcher.stats["lone_dispatches"] == 1
+        for k, name in enumerate(("chosen", "scores", "pulls", "skipped")):
+            np.testing.assert_array_equal(
+                np.asarray(single[k]), np.asarray(batch_r[k]), err_msg=name)
+
     def test_mesh_sharded_c1m_slice_bit_identical(self):
         """A C1M-shaped slice — exact INT spec, DISTINCT
         per-eval inputs, batch sharded over the full ("evals","nodes")
@@ -947,12 +974,13 @@ class _Recorder(DeviceBatcher):
         return super().run(enc, expected)
 
 
-def _encoded_by_the_scheduler():
+def _encoded_by_the_scheduler(n_nodes=30):
     """{name: EncodedEval} from the real encoder, in the exact integer
     spec (``int32-*``) and the float32 throughput mode (``float32-*``):
     a plain job, a spread, an affinity, a distinct_property constraint, a
     destructive update (eviction steps), and a system job that preempts
-    (candidate tables, int64 ``pre_remaining``)."""
+    (candidate tables, int64 ``pre_remaining``). ``n_nodes``: the fleet
+    of all but the last, which keeps its four nodes."""
     rec = _Recorder()
     out = {}
 
@@ -983,7 +1011,7 @@ def _encoded_by_the_scheduler():
 
     try:
         for det, mode in ((True, "int32"), (False, "float32")):
-            h = harness(make_nodes(30, seed=42))
+            h = harness(make_nodes(n_nodes, seed=42))
             process(h, job("plain", 5), f"{mode}-plain", deterministic=det)
             j = job("spread", 5)
             j.task_groups[0].spreads = [Spread(
@@ -1164,7 +1192,8 @@ def _scan_under_the_old_padding(encs, dtype):
     16/64/256/1024 step buckets; every padded step runs."""
     import jax
     from jax import lax
-    from nomad_tpu.tpu.engine import _build_place_scan, _make_step
+    from nomad_tpu.tpu.engine import (
+        _build_place_scan, _make_step, _step_layout)
 
     _build_place_scan()   # x64 on before any array is made
     dims = DeviceBatcher._batch_dims(encs)
@@ -1183,9 +1212,12 @@ def _scan_under_the_old_padding(encs, dtype):
         padded.append((static, carry, (tg_idx,) + tuple(xs[1:])))
     stacked = jax.tree_util.tree_map(lambda *a: np.stack(a), *padded)
     step = _make_step()
-    scan = jax.jit(jax.vmap(lambda static, carry, xs: lax.scan(
-        lambda c, x: step(static, c, x), carry, xs)))
-    _carry, outs = scan(*stacked)
+
+    def scan_one(static, carry, xs):
+        static, carry = _step_layout(static, carry, fold=False)
+        return lax.scan(lambda c, x: step(static, c, x), carry, xs)
+
+    _carry, outs = jax.jit(jax.vmap(scan_one))(*stacked)
     return [np.asarray(o) for o in outs]
 
 
@@ -1259,6 +1291,186 @@ def test_bounded_loop_equals_the_scan_it_replaces(wire_evals, case):
                               f"eval {bi} (p={enc.p}, g={enc.g}) {name}")
         # past its own count an eval's rows are skipped steps' or the fill
         assert have[3][bi, enc.p:].all() and (have[0][bi, enc.p:] == -1).all()
+
+
+# ---------------------------------------------------------------------------
+# the lone program (engine._batched_scan_fn at b_pad == 1): the step runs
+# without a batch axis, its node planes folded to (n_pad // 128, 128). The
+# plain reference, kept here, is the program it replaces: vmap(step) at
+# b = 1 under the same bounded loop, every plane with its unit batch axis.
+# ---------------------------------------------------------------------------
+
+
+def _with_the_batch_axis(layout, *buffers):
+    """``body`` as it was for every batch bucket: the vmapped step under
+    the bounded loop, whatever the width."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from nomad_tpu.tpu.engine import _make_step, _step_layout
+
+    static_b, carry_b, xs_b, p_real = wire.unpack(layout, buffers, jnp)
+    static_b, carry_b = jax.vmap(
+        lambda s, c: _step_layout(s, c, fold=False))(static_b, carry_b)
+    vstep = jax.vmap(_make_step())
+    xs_t = tuple(jnp.moveaxis(a, 1, 0) for a in xs_b)
+
+    def at(i):
+        return tuple(lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+                     for a in xs_t)
+
+    p_pad = xs_t[0].shape[0]
+    zero = jnp.int32(0)
+    _, shapes = jax.eval_shape(
+        vstep, static_b, carry_b, at(zero), zero >= p_real)
+    outs0 = tuple(jnp.full((p_pad,) + o.shape, fill, o.dtype)
+                  for o, fill in zip(shapes, (-1, 0, 0, True, -1, -1)))
+
+    def body(i, state):
+        carry, outs = state
+        carry, out = vstep(static_b, carry, at(i), i >= p_real)
+        return carry, tuple(lax.dynamic_update_index_in_dim(buf, o, i, 0)
+                            for buf, o in zip(outs, out))
+
+    _, outs = lax.fori_loop(
+        zero, jnp.minimum(jnp.max(p_real), p_pad), body, (carry_b, outs0))
+    return wire.pack_outputs(
+        layout, *(jnp.moveaxis(o, 0, 1) for o in outs))
+
+
+# fleets of 128 nodes and more fold (n_pad is a multiple of 128); the
+# preempting eval's candidate tables keep the node axis first, and it and
+# the small fleets run the step unfolded
+_LONE_SYNTHETIC = {
+    f"syn-{np.dtype(dt).name}-n{n}-g{g}-s{s}-p{p}": dict(
+        n_nodes=n, n_tgs=g, n_placements=p, n_spreads=s, seed=seed, dtype=dt)
+    for seed, (dt, n, g, p, s) in enumerate([
+        (np.int32, 130, 1, 1, 0), (np.int32, 200, 2, 50, 1),
+        (np.int32, 300, 3, 64, 2), (np.int32, 17, 1, 3, 1),
+        (np.float32, 130, 1, 1, 1), (np.float32, 200, 2, 50, 0),
+        (np.float32, 260, 2, 64, 2),
+    ], start=40)
+}
+_LONE_ENCODED = [f"{mode}-{what}" for mode in ("int32", "float32")
+                 for what in ("plain", "spread", "affinity", "distinct",
+                              "evict")] + ["int32-preempt-high"]
+
+
+@pytest.fixture(scope="module")
+def lone_evals():
+    from nomad_tpu.tpu.engine import _build_place_scan
+
+    _build_place_scan()   # x64 on before any array is made
+    evals = {n: synthetic_enc(**kw) for n, kw in _LONE_SYNTHETIC.items()}
+    evals.update(_encoded_by_the_scheduler(n_nodes=150))
+    assert evals["int32-spread"].n_pad == 256
+    assert evals["int32-preempt-high"].static[20].shape[1] > 0
+    return evals
+
+
+def _lone_layout(enc, b_pad=1):
+    dims = DeviceBatcher._batch_dims([enc])
+    layout = wire.WireLayout(
+        wire.shape_key(enc, dims, enc.dtype), b_pad, dims)
+    bufs = wire.WireBuffers(layout)
+    wire.pack(bufs, [enc] * min(b_pad, 3))
+    return layout, bufs
+
+
+@pytest.mark.parametrize("name", list(_LONE_SYNTHETIC) + _LONE_ENCODED)
+def test_lone_program_equals_the_batched_one(lone_evals, name):
+    """The whole array a lone dispatch brings down, fill rows included, is
+    bit for bit what the vmapped step at b = 1 writes."""
+    import jax
+    from nomad_tpu.tpu.engine import _build_wire_scan
+
+    enc = lone_evals[name]
+    layout, bufs = _lone_layout(enc)
+    have = np.asarray(_build_wire_scan()(layout, *bufs.arrays))
+    want = np.asarray(jax.jit(_with_the_batch_axis, static_argnums=0)(
+        layout, *bufs.arrays))
+    _assert_same_bits(have, want, name)
+    chosen = wire.split_outputs(layout, have)[0]
+    assert (chosen[0, :enc.p] >= 0).any(), "an eval that places nothing"
+
+
+def _loop_body_shapes(fn, *args):
+    """Every array shape inside the ``while`` of ``fn``'s jaxpr."""
+    import jax
+
+    def walk(jaxpr, inside):
+        for eqn in jaxpr.eqns:
+            here = inside or eqn.primitive.name == "while"
+            if inside:
+                for v in list(eqn.invars) + list(eqn.outvars):
+                    if hasattr(v.aval, "shape"):
+                        yield tuple(v.aval.shape)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub, here)
+
+    return set(walk(jax.make_jaxpr(fn, static_argnums=0)(*args).jaxpr,
+                    False))
+
+
+def test_lone_loop_body_has_no_unit_batch_axis(lone_evals):
+    """Inside the 1-wide program's loop no plane is ``[1, n_pad, ...]``
+    or ``[1, ..., n_pad]``: the node axis is there only folded, and flat
+    for the cumsum. The 16-wide program's planes are ``[16, n_pad]``
+    still, none folded."""
+    from nomad_tpu.tpu.engine import _build_wire_scan
+
+    enc = lone_evals["syn-int32-n300-g3-s2-p64"]
+    n_pad, rows = enc.n_pad, enc.n_pad // 128
+    assert (n_pad, rows) == (384, 3)
+    scan = _build_wire_scan()
+    layout, bufs = _lone_layout(enc)
+    lone = _loop_body_shapes(scan, layout, *bufs.arrays)
+    assert {(rows, 128), (4, rows, 128), (2, rows, 128)} <= lone
+    assert {s for s in lone if n_pad in s} <= {(n_pad,)}
+    wide, wide_bufs = _lone_layout(enc, b_pad=16)
+    batched = _loop_body_shapes(scan, wide, *wide_bufs.arrays)
+    assert {(16, n_pad), (16, 4, n_pad), (16, 2, n_pad)} <= batched
+    assert not {s for s in batched if s[-2:] == (rows, 128)}
+    # plane for plane the reference's
+    assert {s for s in batched if n_pad in s} == {
+        s for s in _loop_body_shapes(
+            _with_the_batch_axis, wide, *wide_bufs.arrays) if n_pad in s}
+
+
+def test_step_layout_knows_the_node_axes_the_wire_declares():
+    """``engine._STATIC_NODE_AXIS`` / ``_CARRY_NODE_AXIS`` against
+    ``wire.FIELDS``: every field with a node axis is listed at that axis,
+    but the preemption tables, which stay node-first."""
+    from nomad_tpu.tpu import engine
+
+    for part, table in (("static", engine._STATIC_NODE_AXIS),
+                        ("carry", engine._CARRY_NODE_AXIS)):
+        fields = [f for f in wire.FIELDS if f.part == part]
+        declared = {
+            i: next(k for k, a in enumerate(f.axes) if a in ("n", "n?"))
+            for i, f in enumerate(fields)
+            if {"n", "n?"} & set(f.axes) and "prec" not in f.axes}
+        assert table == declared, part
+        assert all(f.name.startswith("pre_") for f in fields
+                   if {"prec", "n_if_prec"} & set(f.axes))
+
+
+@pytest.mark.parametrize("b", [1, 2], ids=["a-lone-wave", "a-pair"])
+def test_lone_dispatches_counts_the_unbatched_program(b):
+    """``stats["lone_dispatches"]``: the dispatches that took the program
+    without a batch axis (b_pad == 1), beside ``dispatches``."""
+    batcher = DeviceBatcher(max_batch=2, window_ms=200.0)
+    try:
+        run_concurrent(batcher, [synthetic_enc(24, 2, 5, seed=s)
+                                 for s in range(b)])
+        with batcher._lock:
+            stats = dict(batcher.stats)
+        assert [d["b_pad"] for d in _dispatches_of(batcher)] == [b]
+        assert stats["dispatches"] == 1
+        assert stats["lone_dispatches"] == (1 if b == 1 else 0)
+    finally:
+        batcher.stop()
+
 
 
 def _case_padded_steps_are_the_batch_times_the_longest_eval():
