@@ -20,12 +20,18 @@ read back and held against the configuration's guarantees:
 * every fallback counter reads zero and nothing compiled inside the
   window: a window served by a fallback is not this system's result.
 
+A configuration's deployment module (deployment.py states the contract)
+may bring how its jobs' placements are keyed and how many a whole job
+holds, its own reference and checks, and the placements set-up made: those
+count in every snapshot and in the capacity check, which reads the last
+state and the one each departure from ``run`` was applied to.
+
 The snapshot: the program does not say which raft index a plan was made
 against (see PERF.md, for the tracing issue), but a plan sees all of the
-fleet and, of the placements, exactly those committed at or below its
-snapshot's index, and the window only adds placements. So the candidates
-are the states after each commit between the evaluation's creation and its
-own plan's commit; the reference walks each until its first disagreement.
+fleet and, of the placements, exactly those live at its snapshot's index,
+and the store changes only at commits. So the candidates are the states
+after each commit between the evaluation's creation and its own plan's
+commit; the reference walks each until its first disagreement.
 A plan that agrees with the reference on no candidate is a mismatch, and
 is reported with the candidate that agreed longest.
 """
@@ -42,74 +48,113 @@ def name_index(name: str) -> int:
     return int(name[name.rindex("[") + 1:-1])
 
 
-def read_back(state, records: list, fleet) -> dict:
-    """Every placement of the run's jobs from the state store, as arrays."""
+NEVER = np.iinfo(np.int64).max   # the left index of a placement still in run
+LEFT_RUN = ("stop", "evict")
+
+
+def read_back(state, records: list, fleet, dep, setup_records: list = ()) -> dict:
+    """Every placement of the run's jobs, and of the jobs that set-up
+    placed, from the state store, as arrays: its node, the raft index it
+    was created at and the one it left ``run`` at (NEVER while it runs).
+    Per job the placements still in ``run``, keyed by the deployment's
+    ``placement_key``; a window's job is whole when its keys are its
+    ``expected_placements``. Set-up's jobs follow the window's, marked
+    ``setup``."""
     node_of = {nid: i for i, nid in enumerate(fleet.ids)}
     jobs = []
-    node, cidx, jobno = [], [], []
-    for j, rec in enumerate(records):
-        allocs = system.run_allocs(state, rec["id"])
-        ks = [name_index(a.name) for a in allocs]
+    node, cidx, left, jobno = [], [], [], []
+    for j, rec in enumerate(list(records) + list(setup_records)):
+        every = state.allocs_by_job(system.NS, rec["id"], True)
+        allocs = [a for a in every if a.desired_status == "run"]
+        gone = [a for a in every if a.desired_status in LEFT_RUN]
+        ks = [dep.placement_key(a) for a in allocs]
         ns = [node_of.get(a.node_id, -1) for a in allocs]
         cs = [int(a.create_index) for a in allocs]
+        setup = j >= len(records)
         jobs.append({
-            "rec": rec, "k": ks, "node": ns, "cidx": cs,
+            "rec": rec, "k": ks, "node": ns, "cidx": cs, "setup": setup,
             "score": [system.recorded_score(a) for a in allocs],
             "evals": sorted({a.eval_id for a in allocs}),
-            "whole": sorted(ks) == list(range(rec["count"])),
+            "whole": sorted(ks) == sorted(dep.expected_placements(rec["spec"], fleet)),
         })
-        node += ns
-        cidx += cs
-        jobno += [j] * len(ns)
+        node += ns + [node_of.get(a.node_id, -1) for a in gone]
+        cidx += cs + [int(a.create_index) for a in gone]
+        left += [NEVER] * len(allocs) + [int(a.modify_index) for a in gone]
+        jobno += [j] * (len(ns) + len(gone))
     return {"jobs": jobs, "node": np.asarray(node, np.int64),
             "cidx": np.asarray(cidx, np.int64),
+            "left": np.asarray(left, np.int64),
             "job": np.asarray(jobno, np.int64)}
 
 
+class _Falling:
+    """Per-node sums of ``per`` over the entries at or below an index, for
+    a falling series of indices: the entries in index order, taken away
+    from the newest sums as the index falls."""
+
+    def __init__(self, index, node, per, n_nodes) -> None:
+        order = np.argsort(index, kind="stable")
+        self.index, self.node, self.per = index[order], node[order], per[order]
+        self.n = n_nodes
+        self.hi = len(self.index)
+        self.cur = None
+
+    def at(self, index) -> list:
+        lo = int(np.searchsorted(self.index, index, side="right"))
+        if self.cur is None:
+            self.cur = [np.bincount(self.node[:lo], weights=self.per[:lo, d],
+                                    minlength=self.n) for d in range(3)]
+        else:
+            for d in range(3):
+                self.cur[d] -= np.bincount(self.node[lo:self.hi],
+                                           weights=self.per[lo:self.hi, d],
+                                           minlength=self.n)
+        self.hi = lo
+        return self.cur
+
+
 class Usage:
-    """(cpu, mem, disk) used per node by the placements committed at or
-    below a raft index, for a falling series of indices without summing
-    the whole run again each time: the placements in commit order, taken
-    away from the newest state as the index falls."""
+    """(cpu, mem, disk) used per node at a raft index: every placement
+    counts over ``[create index, left index)``, set-up's included. What was
+    created at or below the index, less what left at or below it."""
 
     def __init__(self, back: dict, asks: np.ndarray, n_nodes: int) -> None:
         ok = back["node"] >= 0
-        order = np.argsort(back["cidx"][ok], kind="stable")
-        self.cidx = back["cidx"][ok][order]
-        self.node = back["node"][ok][order]
-        self.per = asks[back["job"][ok][order]]
+        gone = ok & (back["left"] < NEVER)
+        self.made = (back["cidx"][ok], back["node"][ok], asks[back["job"][ok]])
+        self.gone = (back["left"][gone], back["node"][gone],
+                     asks[back["job"][gone]])
+        self.departures = np.unique(self.gone[0])
         self.n = n_nodes
 
     def series(self, indices: list):
         """Yields (index, [cpu, mem, disk] arrays) for ``indices``, which
         fall."""
-        hi = len(self.cidx)
-        cur = None
+        made = _Falling(*self.made, self.n)
+        gone = _Falling(*self.gone, self.n) if len(self.departures) else None
         for index in indices:
-            lo = int(np.searchsorted(self.cidx, index, side="right"))
-            if cur is None:
-                cur = [np.bincount(self.node[:lo], weights=self.per[:lo, d],
-                                   minlength=self.n) for d in range(3)]
-            else:
-                for d in range(3):
-                    cur[d] -= np.bincount(self.node[lo:hi],
-                                          weights=self.per[lo:hi, d],
-                                          minlength=self.n)
-            hi = lo
+            cur = made.at(index)
+            if gone is not None:
+                cur = [u - g for u, g in zip(cur, gone.at(index))]
             yield index, [np.rint(u).astype(np.int64) for u in cur]
 
 
 def invariants(back: dict, fleet) -> dict:
-    """Counts that the guarantees hold to zero, over every placement."""
+    """Counts that the guarantees hold to zero, over every placement.
+    Capacity is read on the last state and on the one each departure was
+    applied to: between two departures a node's usage only grows, so these
+    are its peaks."""
     recs = [j["rec"] for j in back["jobs"]]
     asks = np.asarray([[r["spec"]["cpu"], r["spec"]["mem"], r["spec"]["disk"]]
                        for r in recs], np.int64).reshape(-1, 3)
     n = len(fleet)
-    _, used = next(Usage(back, asks, n).series([np.iinfo(np.int64).max]))
+    usage = Usage(back, asks, n)
+    peaks = [NEVER] + [int(i) - 1 for i in usage.departures[::-1]]
     over = np.zeros(n, bool)
-    for u, tot, res in zip(used, (fleet.cpu, fleet.mem, fleet.disk),
-                           (fleet.rcpu, fleet.rmem, fleet.rdisk)):
-        over |= u + res > tot
+    for _, used in usage.series(peaks):
+        for u, tot, res in zip(used, (fleet.cpu, fleet.mem, fleet.disk),
+                               (fleet.rcpu, fleet.rmem, fleet.rdisk)):
+            over |= u + res > tot
     unknown = int((back["node"] < 0).sum())
     nodes = np.where(back["node"] >= 0, back["node"], 0)
     linux_only = np.asarray([r["spec"]["linux_only"] for r in recs], bool)
@@ -139,15 +184,17 @@ def snapshots_of(state, back: dict, job: dict) -> list:
     return [int(c) for c in between[::-1]] + [born]
 
 
-def choose_sample(state, back: dict, seed: int, want: int) -> list:
+def choose_sample(state, back: dict, seed: int, want: int, kind_of) -> list:
     """Indices of the jobs to replay: whole, committed by one plan, and
     with no more candidate snapshots than MAX_SNAPSHOTS (a job that waited
     through more commits than that is left to the checks over every
     placement); the largest first, then one of each kind the window
-    finished, then more drawn from the seed."""
+    finished (``kind_of``, the deployment's ``sample_kind``), then more
+    drawn from the seed. Set-up's jobs are not replayed."""
     ok = []
     for i, j in enumerate(back["jobs"]):
-        if j["whole"] and len(set(j["cidx"])) == 1 and len(j["evals"]) == 1:
+        if (j["whole"] and not j["setup"] and len(set(j["cidx"])) == 1
+                and len(j["evals"]) == 1):
             j["snapshots"] = snapshots_of(state, back, j)
             if len(j["snapshots"]) <= MAX_SNAPSHOTS:
                 ok.append(i)
@@ -157,9 +204,9 @@ def choose_sample(state, back: dict, seed: int, want: int) -> list:
     order = [ok[i] for i in rng.permutation(len(ok))]
     count = lambda i: back["jobs"][i]["rec"]["count"]  # noqa: E731
     picked = [max(order, key=count)]
-    seen = {_kind(back["jobs"][picked[0]]["rec"]["spec"])}
+    seen = {kind_of(back["jobs"][picked[0]]["rec"]["spec"])}
     for i in order:
-        kind = _kind(back["jobs"][i]["rec"]["spec"])
+        kind = kind_of(back["jobs"][i]["rec"]["spec"])
         if kind not in seen and len(picked) < want:
             seen.add(kind)
             picked.append(i)
@@ -176,17 +223,19 @@ def _kind(spec: dict) -> tuple:
 
 
 def served_plan(job: dict) -> tuple:
-    """(fleet node, recorded score) per name index of a read-back job."""
+    """(fleet node, recorded score) per placement key of a read-back job."""
     order = sorted(range(len(job["k"])), key=job["k"].__getitem__)
     return ([job["node"][i] for i in order], [job["score"][i] for i in order])
 
 
-def replay_job(back: dict, usage: Usage, fleet, i: int, gap_limit: float) -> dict:
+def replay_job(back: dict, usage: Usage, fleet, i: int, gap_limit: float,
+               dep) -> dict:
     """Replay job ``i`` against each candidate snapshot, newest first, until
     one agrees in every node and, within ``gap_limit``, in every recorded
     score (two snapshots that differ in a few placements often lead to the
     same nodes, and the scores tell them apart); else the result of the
-    candidate that agreed longest, the closer in score of two such."""
+    candidate that agreed longest, the closer in score of two such, by
+    the deployment's ``replay``."""
     job = back["jobs"][i]
     spec = job["rec"]["spec"]
     served, scores = served_plan(job)
@@ -194,9 +243,10 @@ def replay_job(back: dict, usage: Usage, fleet, i: int, gap_limit: float) -> dic
     candidates = job["snapshots"]
     best = None
     for index, used in usage.series(candidates):
-        mism, gap, steps = reference.compare(
+        mism, gap, steps = dep.replay(
             fleet, used, spec, eval_id, served, scores, stop_at_first=True)
-        res = {"job": spec["id"], "kind": _kind(spec), "placements": len(served),
+        res = {"job": spec["id"], "kind": dep.sample_kind(spec),
+               "placements": len(served),
                "mismatched": mism, "agreed": steps - mism, "score_gap": gap,
                "snapshot": index, "candidates": len(candidates)}
         if best is None or (res["agreed"], -gap) > (best["agreed"],
@@ -207,28 +257,35 @@ def replay_job(back: dict, usage: Usage, fleet, i: int, gap_limit: float) -> dic
     if best["mismatched"]:
         # count every disagreement on the candidate that agreed longest
         _, used = next(usage.series([best["snapshot"]]))
-        mism, gap, _ = reference.compare(fleet, used, spec, eval_id, served,
-                                         scores)
+        mism, gap, _ = dep.replay(fleet, used, spec, eval_id, served, scores,
+                                  stop_at_first=False)
         best.update(mismatched=mism, score_gap=gap)
     return best
 
 
 def judge(state, records: list, fleet, seed: int, want: int,
           score_gap_limit: float, counters: dict,
-          compiles_in_window: int) -> dict:
+          compiles_in_window: int, setup_records: list = (),
+          dep=None) -> dict:
     """All of the above; returns {"correct", "checks": [[name, value,
-    limit], ...], "replayed": [...], "failed_jobs": n}."""
+    limit], ...], "replayed": [...], "failed_jobs": n}. ``dep`` is the
+    configuration's deployment (deployment.load); None is the defaults."""
+    from . import deployment
+
+    dep = dep or deployment.defaults()
     t0 = time.perf_counter()
-    back = read_back(state, records, fleet)
+    back = read_back(state, records, fleet, dep, setup_records)
     t_read = time.perf_counter() - t0
     inv = invariants(back, fleet)
     asks = inv.pop("asks")
-    not_whole = sum(1 for j in back["jobs"] if not j["whole"])
-    twice = sum(1 for j in back["jobs"] if len(j["k"]) != len(set(j["k"])))
-    back["commits"] = np.unique(back["cidx"])
-    sample = choose_sample(state, back, seed, want)
+    window_jobs = [j for j in back["jobs"] if not j["setup"]]
+    not_whole = sum(1 for j in window_jobs if not j["whole"])
+    twice = sum(1 for j in window_jobs if len(j["k"]) != len(set(j["k"])))
+    back["commits"] = np.unique(np.concatenate(
+        [back["cidx"], back["left"][back["left"] < NEVER]]))
+    sample = choose_sample(state, back, seed, want, dep.sample_kind)
     usage = Usage(back, asks, len(fleet))
-    replayed = [replay_job(back, usage, fleet, i, score_gap_limit)
+    replayed = [replay_job(back, usage, fleet, i, score_gap_limit, dep)
                 for i in sample]
     compared = sum(r["placements"] for r in replayed)
     mismatched = sum(r["mismatched"] for r in replayed)
@@ -247,6 +304,8 @@ def judge(state, records: list, fleet, seed: int, want: int,
     check("placements_compared", compared, min(want, 1), "least")
     for k, v in inv.items():
         check(k, v, 0)
+    for k, v in dep.checks(back, fleet).items():
+        check(k, v, 0)
     for k, (v, limit) in sorted(counters.items()):
         check(k, v, limit)
     check("compiles_in_window", compiles_in_window, 0)
@@ -255,7 +314,7 @@ def judge(state, records: list, fleet, seed: int, want: int,
             "back": back, "usage": usage,
             "failed_jobs": not_whole, "compared_placements": compared,
             "read_back_s": t_read,
-            "multi_plan_jobs": sum(1 for j in back["jobs"]
+            "multi_plan_jobs": sum(1 for j in window_jobs
                                    if len(set(j["cidx"])) > 1)}
 
 

@@ -30,8 +30,10 @@ class JobStream:
     """``next()`` gives job 0, 1, 2, ... of the seed's stream; calling it
     from several clients under their own lock is the caller's business."""
 
-    def __init__(self, templates: list, seed: int, prefix: str = "job"):
+    def __init__(self, templates: list, seed: int, prefix: str = "job",
+                 spec_of=job_spec):
         self.templates = templates
+        self.spec_of = spec_of
         self.rng = np.random.default_rng([int(seed), 0x10B5])
         self.prefix = prefix
         self.i = 0
@@ -41,13 +43,13 @@ class JobStream:
         if not self._block:
             self._block = list(self.rng.permutation(len(self.templates)))
         t = int(self._block.pop(0))
-        spec = job_spec(self.templates[t], f"{self.prefix}-{self.i}")
+        spec = self.spec_of(self.templates[t], f"{self.prefix}-{self.i}")
         spec["template"] = t
         self.i += 1
         return spec
 
 
-def warm_steps(jobs_cfg: dict) -> list:
+def warm_steps(jobs_cfg: dict, spec_of=job_spec) -> list:
     """[(job dict, scale_to or 0)] from a configuration's ``jobs.warm``:
     [{"template": t, "counts": [...], "scale_by": k}]. Each count is one job
     of the template at that size; ``scale_by`` registers the last of them
@@ -59,8 +61,8 @@ def warm_steps(jobs_cfg: dict) -> list:
         template = jobs_cfg["templates"][int(w["template"])]
         counts = [int(c) for c in w["counts"]]
         for i, count in enumerate(counts):
-            spec = job_spec(dict(template, count=count),
-                            f"warm-{w['template']}-{count}")
+            spec = spec_of(dict(template, count=count),
+                           f"warm-{w['template']}-{count}")
             last = i == len(counts) - 1
             steps.append((spec, count + int(w.get("scale_by", 0))
                           if last and w.get("scale_by") else 0))

@@ -63,10 +63,10 @@ class Observer(threading.Thread):
 
 
 def _submit(server, stream, observer, t_due: float, records: list,
-            pool=None) -> dict:
+            pool, job_of, count_of) -> dict:
     spec = stream.next()
-    job = system.program_job(spec)
-    rec = {"id": spec["id"], "spec": spec, "count": spec["count"],
+    job = job_of(spec)
+    rec = {"id": spec["id"], "spec": spec, "count": count_of(spec),
            "t_due": t_due, "t_commit": None}
     records.append(rec)
     observer.watch(rec)
@@ -83,10 +83,16 @@ def _submit(server, stream, observer, t_due: float, records: list,
     return rec
 
 
-def run_window(server, stream, mix: dict, due: list, seconds: float) -> dict:
+def run_window(server, stream, mix: dict, due: list, seconds: float,
+               job_of, count_of) -> dict:
     """Drive the mix for ``seconds``; returns the records of every job due
-    in the window, the window's bounds on the benchmark's clock, and the
-    placements the state store held at both ends."""
+    in the window, the window's bounds on the benchmark's clock, and at
+    both ends the allocations the state store had been given (``placed0``,
+    ``placed1``: their difference is the placements made inside the window)
+    and of those the ones that had left ``run`` (``left0``, ``left1``).
+    ``job_of`` and ``count_of`` are the deployment's ``program_job`` and
+    the number of its ``expected_placements``: what a job's record waits
+    for."""
     state = server.fsm.state
     observer = Observer(state)
     records: list = []
@@ -102,7 +108,8 @@ def run_window(server, stream, mix: dict, due: list, seconds: float) -> dict:
         for _ in range(int(mix["clients"])):
             if stop.is_set():
                 return
-            _submit(server, stream, observer, time.perf_counter(), records)
+            _submit(server, stream, observer, time.perf_counter(), records,
+                    None, job_of, count_of)
         while not stop.is_set():
             try:
                 free.get(timeout=0.05)
@@ -110,7 +117,8 @@ def run_window(server, stream, mix: dict, due: list, seconds: float) -> dict:
                 continue
             if stop.is_set():
                 return
-            _submit(server, stream, observer, time.perf_counter(), records)
+            _submit(server, stream, observer, time.perf_counter(), records,
+                    None, job_of, count_of)
 
     def open_loop(t0: float) -> None:
         # independent owners: a register call that is slow to return does
@@ -123,10 +131,11 @@ def run_window(server, stream, mix: dict, due: list, seconds: float) -> dict:
                 break
             if stop.is_set():
                 break
-            _submit(server, stream, observer, t0 + offset, records, pool)
+            _submit(server, stream, observer, t0 + offset, records, pool,
+                    job_of, count_of)
         pool.shutdown(wait=False)
 
-    placed0 = state.count_allocs_desired_run()
+    placed0, left0 = system.alloc_counts(state)
     t0 = time.perf_counter()
     worker = threading.Thread(target=closed_loop if closed else open_loop,
                               args=(t0,), name="bench-submitter", daemon=True)
@@ -134,7 +143,7 @@ def run_window(server, stream, mix: dict, due: list, seconds: float) -> dict:
     remaining = t0 + seconds - time.perf_counter()
     if remaining > 0:
         time.sleep(remaining)
-    placed1 = state.count_allocs_desired_run()
+    placed1, left1 = system.alloc_counts(state)
     t1 = time.perf_counter()
     stop.set()
     worker.join()
@@ -155,7 +164,8 @@ def run_window(server, stream, mix: dict, due: list, seconds: float) -> dict:
     observer.stop()
     observer.join()
     return {"records": records, "t0": t0, "t1": t1, "t_drained": t_drained,
-            "placed0": placed0, "placed1": placed1}
+            "placed0": placed0, "placed1": placed1,
+            "left0": left0, "left1": left1}
 
 
 def percentile(sorted_values: list, q: float) -> float:

@@ -23,10 +23,11 @@ ENGINE_FALLBACK_COUNTERS = (
     "nomad.tpu_engine.dispatch_fallback_host",
 )
 # The server's own routing rule: an eval with fewer placements than
-# ``device_min_placements`` is placed by the host stack (for the tail of a
-# partially committed plan: fewer than engine.RETRY_DEVICE_FLOOR). Held as a
-# share of the jobs due, to the limit that the cell's traffic file states:
-# 0 where no plan is ever committed in part.
+# ``device_min_placements`` is placed by the host stack until the batcher
+# has completed a batch; after that such an eval, the tail of a partially
+# committed plan among them, rides a warm program (engine.py,
+# ``small_eval_device_retry``). Held as a share of the jobs due, to the
+# limit that the cell's traffic file states.
 SMALL_EVAL_COUNTER = "nomad.tpu_engine.small_eval_host"
 BATCHER_FALLBACK_STATS = ("batch_fallbacks", "prewarm_failures")
 
@@ -252,6 +253,33 @@ def committed_count(state, job_id: str) -> int:
             + len(state._allocs_by_job.get(key, ())))
 
 
+def live_count(state, job_id: str) -> int:
+    """Placements of ``job_id`` whose desired status is still ``run``,
+    counted as committed_count counts them: a stopped dense slot is
+    superseded by a table entry."""
+    key = (NS, job_id)
+    with state._lock:
+        gone = state._dense_superseded
+        return (sum(1 for b in state._dense_by_job.get(key, ())
+                    for aid in b.ids if aid not in gone)
+                + sum(1 for aid in state._allocs_by_job.get(key, ())
+                      if aid in state.allocs_table
+                      and state.allocs_table[aid].desired_status == "run"))
+
+
+def alloc_counts(state) -> tuple:
+    """(allocations the store has ever been given, those of them whose
+    desired status is no longer ``run``), read under one hold of the
+    store's lock. A placement that is stopped or evicted stays in the
+    store as a table entry, so the first only grows; where nothing leaves
+    ``run`` its change equals that of count_allocs_desired_run."""
+    with state._lock:
+        total = (len(state.allocs_table)
+                 + sum(len(b.ids) for b in state._dense_blocks)
+                 - len(state._dense_superseded))
+        return total, total - state.count_allocs_desired_run()
+
+
 def run_allocs(state, job_id: str) -> list:
     return [a for a in state.allocs_by_job(NS, job_id, True)
             if a.desired_status == "run"]
@@ -267,7 +295,7 @@ def recorded_score(alloc) -> float:
     return float("nan")
 
 
-def warm_up(server, steps: list, timeout_s: float) -> int:
+def warm_up(server, steps: list, timeout_s: float, job_of, count_of) -> int:
     """Every compiled shape the cell's traffic can reach, through the whole
     served path, one job at a time. ``steps`` is [(job dict, scale_to)]: the
     job is registered and waited for; where ``scale_to`` is set it is then
@@ -276,7 +304,9 @@ def warm_up(server, steps: list, timeout_s: float) -> int:
     a partially committed plan rides (an eval of fewer placements than
     device_min_placements goes to the device only once the batcher is
     warm). Then every sibling batch bucket's background compile is joined,
-    the warm jobs are stopped and their stop evals landed.
+    the warm jobs are stopped and their stop evals landed. ``job_of`` and
+    ``count_of`` are the deployment's ``program_job`` and the number of its
+    ``expected_placements``.
     Returns the number of device dispatches it took."""
     state = server.fsm.state
     d0 = batcher_stats(server)["dispatches"]
@@ -285,16 +315,17 @@ def warm_up(server, steps: list, timeout_s: float) -> int:
             if not count:
                 continue
             spec = dict(spec, count=int(count))
-            server.register_job(program_job(spec))
-            _wait(lambda: committed_count(state, spec["id"]) >= spec["count"]
+            want = count_of(spec)
+            server.register_job(job_of(spec))
+            _wait(lambda: committed_count(state, spec["id"]) >= want
                   and quiescent(server), timeout_s, f"warm job {spec['id']}")
     # each shape's sibling buckets load on a thread of their own meanwhile:
     # joined once, here, not after every job
     server.device_batcher.wait_warm()
     for spec, _ in steps:
         server.deregister_job(NS, spec["id"], purge=False)
-    _wait(lambda: state.count_allocs_desired_run() == 0 and quiescent(server),
-          timeout_s, "warm jobs stopped")
+    _wait(lambda: not any(live_count(state, spec["id"]) for spec, _ in steps)
+          and quiescent(server), timeout_s, "warm jobs stopped")
     server.device_batcher.wait_warm()
     return batcher_stats(server)["dispatches"] - d0
 

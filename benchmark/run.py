@@ -7,9 +7,13 @@ One process, one cell of BENCHMARK.json, on the machine it is started on.
 Everything that belongs to one configuration, one traffic mix or one metric
 is a file found by its name:
 
-    benchmark/configs/<config>.json    the deployment
-    benchmark/traffic/<traffic>.json   the mix
-    benchmark/metrics/<metric>.py      read(ctx) -> number or None
+    benchmark/configs/<config>.json       the deployment's sizes
+    benchmark/deployments/<config>.py     where it has them: its own job
+                                          kinds, starting state and
+                                          reference (harness/deployment.py
+                                          states the hooks and defaults)
+    benchmark/traffic/<traffic>.json      the mix
+    benchmark/metrics/<metric>.py         read(ctx) -> number or None
 
 so a later cell or metric is new files and a new entry in BENCHMARK.json,
 and no edit here. It refuses any platform but ``tpu`` (exit 2, no result
@@ -37,7 +41,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 if HERE not in sys.path:
     sys.path.insert(0, HERE)
 
-from harness import cluster, compare, jobs, loadgen, system, trace, traffic, work  # noqa: E402
+from harness import (cluster, compare, deployment, jobs, loadgen, system,  # noqa: E402
+                     trace, traffic, work)
 
 TRACE_MARGIN_S = 0.05      # a traced run profiles the window less this, each end
 WARM_TIMEOUT_S = 900.0
@@ -165,9 +170,14 @@ def run_cell(manifest: dict, repo: str, workload: str, seed: int,
     meter = system.CompileMeter()
     sink = system.CounterSink()
     prog_metrics.register_sink(sink)
+    dep = deployment.load(root, cfg_entry["name"])
     fleet = cluster.make_fleet(config["cluster"], seed)
     templates = config["jobs"]["templates"]
-    stream = jobs.JobStream(templates, seed)
+    stream = jobs.JobStream(templates, seed, spec_of=dep.job_spec)
+
+    def count_of(spec):
+        return len(dep.expected_placements(spec, fleet))
+
     server = system.start_server(config["server"], "bench-" + workload,
                                  WARM_TIMEOUT_S / 3)
     lingering: list = []
@@ -175,11 +185,15 @@ def run_cell(manifest: dict, repo: str, workload: str, seed: int,
         system.register_nodes(server, system.program_nodes(fleet))
         log(f"{len(fleet)} nodes registered "
             f"({time.perf_counter() - T_PROCESS:.1f}s since process start)")
-        warm = jobs.warm_steps(config["jobs"])
-        dispatches = system.warm_up(server, warm, WARM_TIMEOUT_S)
+        warm = jobs.warm_steps(config["jobs"], dep.job_spec)
+        dispatches = system.warm_up(server, warm, WARM_TIMEOUT_S,
+                                    dep.program_job, count_of)
         log(f"warm: {len(warm)} job(s), {dispatches} dispatches; "
             f"compile-or-load {meter.seconds():.1f}s, "
             f"cache hits {meter.cache_hits} misses {meter.cache_misses}")
+        setup_records = dep.setup(server, fleet, config, seed)
+        if setup_records:
+            log(f"set-up: {len(setup_records)} job(s) placed")
         shapes0 = system.batcher_shapes(server)
         log(f"batcher shapes after warm-up: {shapes0}")
         lifecycle.reset()
@@ -202,7 +216,8 @@ def run_cell(manifest: dict, repo: str, workload: str, seed: int,
                                 + (seconds - length) / 2, length)
             profiler.start()
         setup_s = time.perf_counter() - T_PROCESS
-        window = loadgen.run_window(server, stream, mix, due, seconds)
+        window = loadgen.run_window(server, stream, mix, due, seconds,
+                                    dep.program_job, count_of)
         memory_peak = system.memory_peak_bytes()
         if traced:
             profiler.join()
@@ -215,7 +230,8 @@ def run_cell(manifest: dict, repo: str, workload: str, seed: int,
         latencies = loadgen.latencies_ms(window)
         log(f"window {window['t1'] - window['t0']:.2f}s: "
             f"{len(window['records'])} jobs due, "
-            f"{window['placed1'] - window['placed0']} placements inside it; "
+            f"{window['placed1'] - window['placed0']} placements inside it, "
+            f"{window['left1'] - window['left0']} left run; "
             f"drained {window['t_drained'] - window['t1']:.2f}s")
         log("commits after the window closed, by second: "
             f"{loadgen.late_commits(window)}; backlog at half and at the "
@@ -285,9 +301,10 @@ def run_cell(manifest: dict, repo: str, workload: str, seed: int,
                 out_metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
 
         # every path that ends in a plan without the device having done the
-        # work reads zero; the server's own routing rule (the few-placement
-        # tail of a partially committed plan goes to the host stack) is held
-        # as a share of the jobs due, to the limit the traffic file states
+        # work reads zero; the server's own routing rule (an eval of fewer
+        # placements than device_min_placements goes to the host stack
+        # until the batcher is warm) is held as a share of the jobs due, to
+        # the limit the traffic file states
         fallbacks = {n: (ctx["counters"].get(n, 0.0), 0)
                      for n in system.ENGINE_FALLBACK_COUNTERS}
         fallbacks.update({"device_batcher." + k: (ctx["stats"][k], 0)
@@ -305,7 +322,7 @@ def run_cell(manifest: dict, repo: str, workload: str, seed: int,
         verdict = compare.judge(state, window["records"], fleet,
                                 seed, int(mix["sample_jobs"]),
                                 float(mix["limits"]["widest_score_gap"]),
-                                fallbacks, len(compiles))
+                                fallbacks, len(compiles), setup_records, dep)
         log(f"comparison: {verdict['compared_placements']} placements of "
             f"{len(verdict['replayed'])} jobs replayed in "
             f"{time.perf_counter() - t_check:.1f}s "

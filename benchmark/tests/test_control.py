@@ -117,7 +117,7 @@ def test_over_capacity_and_wrong_datacenter_are_counted():
     dc2 = int(np.flatnonzero(fleet.dc == 1)[0])
     back = {"jobs": [{"rec": {"spec": spec, "id": "j", "count": 4}}],
             "node": np.array([dc1, dc1, dc1, dc2]), "cidx": np.array([5, 5, 5, 5]),
-            "job": np.array([0, 0, 0, 0])}
+            "job": np.array([0, 0, 0, 0]), "left": np.full(4, compare.NEVER)}
     inv = compare.invariants(back, fleet)
     # 3 x 3,000 MHz on one node of at most 16,000 with 100 reserved, and
     # one placement in dc2 of a job that names dc1 alone

@@ -99,9 +99,11 @@ def test_nothing_that_exists_was_edited(checkout):
     theirs = _digest(os.path.join(checkout["repo"], "benchmark"))
     assert {k: theirs[k] for k in ours} == ours
     added = sorted(set(theirs) - set(ours))
-    assert added == ["configs/tiny-64.json", "metrics/jobs_due.tiny.py",
+    assert added == ["configs/tiny-64.json", "configs/tiny-sys-64.json",
+                     "deployments/tiny-sys-64.py", "metrics/jobs_due.tiny.py",
                      "metrics/placements_per_s.py",
-                     "traffic/tiny-closed.json", "traffic/tiny-open.json"]
+                     "traffic/tiny-closed.json", "traffic/tiny-open.json",
+                     "traffic/tiny-sys-open.json"]
 
 
 def test_seed_changes_the_stream_and_repeats_it():

@@ -1,8 +1,11 @@
 """A scratch checkout for the rehearsal: BENCHMARK.json and benchmark/ copied
 whole, plus what a later PR would add as data: one tiny configuration, two
 tiny traffic mixes (a closed and an open loop), one end-to-end metric and
-one per-layer metric with their readers, and the entries for them. No file
-that exists is edited."""
+one per-layer metric with their readers, and the entries for them; and a
+second configuration that brings its own job kind, starting state and
+reference (``tiny-sys-64``: deployment_tiny_sys.py, copied as
+``deployments/tiny-sys-64.py``) with its open loop. No file that exists is
+edited."""
 import copy
 import json
 import os
@@ -37,6 +40,23 @@ TINY_CONFIG = {
     ], "warm": [{"template": 1, "counts": [28], "scale_by": 6}, {"template": 0, "counts": [30], "scale_by": 6}]},
     "guarantees": ["as svc-spread-5k"], "reduced": [], "assumed": ["everything"],
 }
+# a priority-20 system job over every linux node and a generic service job
+# placed in set-up; the window mixes system jobs (small: they fit on every
+# node, nothing is evicted) with tiny-64's plain service jobs
+_SYS = {"kind": "system", "cpu": 20, "mem": 16, "disk": 10, "count": 1,
+        "datacenters": ["dc1", "dc2"], "linux_only": True, "priority": 50}
+TINY_SYS_CONFIG = dict(
+    TINY_CONFIG, name="tiny-sys-64",
+    source="benchmark/tests: tiny-64's fleet filled in set-up by a priority-20 system job and a service job, then system and service jobs arriving; CPU rehearsal only",
+    jobs={"templates": [_SYS, TINY_CONFIG["jobs"]["templates"][1]],
+          "warm": [{"template": 0, "counts": [1]},
+                   {"template": 1, "counts": [28], "scale_by": 6}],
+          "setup": [{"id": "sys-low", "template": dict(
+                         _SYS, cpu=100, mem=64, disk=300, priority=20)},
+                    {"id": "svc-base", "template": dict(
+                         TINY_CONFIG["jobs"]["templates"][1], count=40,
+                         cpu=500, mem=512)}]})
+DEPLOYMENT_FILE = os.path.join(HERE, "deployment_tiny_sys.py")
 EXTRA_METRIC = '''"""Added by the rehearsal: jobs due in the window."""
 
 
@@ -71,6 +91,13 @@ def scratch_checkout(tmp: str) -> tuple:
     with open(os.path.join(bench, "traffic", "tiny-open.json"), "w") as f:
         json.dump({"loop": "open", "rate_per_s": 4.0, "drain_s": 30,
                    "sample_jobs": 4, "trace_s": 0.5, "limits": LIMITS}, f)
+    with open(os.path.join(bench, "configs", "tiny-sys-64.json"), "w") as f:
+        json.dump(TINY_SYS_CONFIG, f)
+    with open(os.path.join(bench, "traffic", "tiny-sys-open.json"), "w") as f:
+        json.dump({"loop": "open", "rate_per_s": 4.0, "drain_s": 30,
+                   "sample_jobs": 4, "trace_s": 0.5, "limits": LIMITS}, f)
+    os.makedirs(os.path.join(bench, "deployments"), exist_ok=True)
+    shutil.copy(DEPLOYMENT_FILE, os.path.join(bench, "deployments", "tiny-sys-64.py"))
     with open(os.path.join(bench, "metrics", "jobs_due.tiny.py"), "w") as f:
         f.write(EXTRA_METRIC)
     with open(os.path.join(bench, "metrics", "placements_per_s.py"), "w") as f:
@@ -79,20 +106,26 @@ def scratch_checkout(tmp: str) -> tuple:
         "name": "tiny-64", "source": TINY_CONFIG["source"],
         "file": "benchmark/configs/tiny-64.json", "reduced": [],
         "why": "rehearsal"})
-    cells = ["tiny-64.closed", "tiny-64.open"]
+    manifest["configs"].append({
+        "name": "tiny-sys-64", "source": TINY_SYS_CONFIG["source"],
+        "file": "benchmark/configs/tiny-sys-64.json", "reduced": [],
+        "why": "rehearsal of a deployment module"})
+    cells = ["tiny-64.closed", "tiny-64.open", "tiny-sys-64.open"]
     manifest["workloads"] += [
         {"name": "tiny-64.closed", "config": "tiny-64",
          "traffic": "tiny-closed", "chips": 1, "why": "rehearsal"},
         {"name": "tiny-64.open", "config": "tiny-64", "traffic": "tiny-open",
-         "chips": 1, "why": "rehearsal"}]
+         "chips": 1, "why": "rehearsal"},
+        {"name": "tiny-sys-64.open", "config": "tiny-sys-64",
+         "traffic": "tiny-sys-open", "chips": 1, "why": "rehearsal"}]
     for m in manifest["end_to_end"]:
         if m["name"].startswith("submit_commit"):
-            m["workloads"].append(cells[1])
+            m["workloads"] += cells[1:]
     manifest["end_to_end"].append({
         "name": "placements_per_s", "unit": "placements/s", "better": "higher",
         "bound": 0.05, "source": "host_clock", "workloads": [cells[0]]})
     for m in manifest["per_layer"]:
-        m["workloads"].append(cells[1])
+        m["workloads"] += cells[1:]
     manifest["per_layer"].append({
         "name": "jobs_due.tiny", "unit": "jobs", "better": "higher",
         "source": "host_clock", "layer": "load generator",
