@@ -197,6 +197,218 @@ def _round_up(n: int, multiple: int = 128) -> int:
 # ---------------------------------------------------------------------------
 
 
+# the near-tie key, an int32: (distance >> 1) << 15 | node index (the
+# band's 60 * 2**10 halves into 15 bits); two indices and the crowded bit
+# ride one int32 out (intscore.RIVAL_BITS). _MIX: odd multipliers of the
+# twin test's wrapping sum
+_I32_MAX = (1 << 31) - 1
+_MIX = (-1640531527, 506961463, 668265263, 374761393)
+
+
+def _select(final, feasible, iota, n_real, offset, limit, skip_step,
+            totals, util):
+    """A step's ``select``: the ring-ordered LimitIterator emulation and
+    MaxScore over one node plane (``final``, the score60 int64 of int
+    mode or the float score; ``iota`` the node index in the plane's
+    shape). Returns ``(chosen, best_score, pulls, offset, rival)``:
+    ``chosen`` -1 where no candidate is left or the step is skipped, the
+    ring offset advanced by the nodes the limit pulled, and ``rival`` the
+    near-tie flag of the refereed path (-1 elsewhere).
+
+    Three reductions to a scalar on the refereed path, two off it (the
+    ring cumsum aside): ``before``, the ring offset's sum; ONE
+    lexicographic reduction that keeps the highest score and, on equal
+    scores, the lowest rank, carrying the node index and the count of
+    pulled nodes along; and the near-tie reduction, which reads the
+    winner's score."""
+    import jax.numpy as jnp
+    from jax import lax as jlax
+
+    from .intscore import (
+        NEAR_TIE_BAND60,
+        PACK_COUNT_MAX,
+        RIVAL_BITS,
+        pack_count_lanes,
+        unpack_count_hi,
+        unpack_count_lo,
+    )
+
+    assert NEAR_TIE_BAND60 >> 1 < 1 << (31 - RIVAL_BITS)
+    i32 = jnp.int32
+    node_shape = final.shape
+    nodes = tuple(range(len(node_shape)))
+    n_pad = math.prod(node_shape)
+    int_mode = jnp.issubdtype(final.dtype, jnp.integer)
+
+    # -- ring-ordered limit (no permutation) ---------------------------------
+    # Ring prefix sums at natural index i: with S = natural inclusive
+    # cumsum, T = total, o = offset, the ring-order cumsum is
+    # S(i) - S(o-1) for i >= o and S(i) + (T - S(o-1)) for i < o —
+    # elementwise, so the LimitIterator emulation needs no gathers.
+    #
+    # ONE packed int32 ring cumsum carries everything: the low-score
+    # and feasible count planes ride 16-bit lanes of one int32 plane
+    # (intscore.pack_count_lanes). Lane exactness: both totals are
+    # bounded by n_pad < 2**15, so the low lane never carries into the
+    # high lane, and every SELECTED ring branch is lane-wise
+    # non-negative (i >= o selects S(i) - S(o-1) with [0..o-1] a
+    # subset of [0..i]; i < o selects S(i) + the suffix sum — both
+    # >= 0 per lane), so no borrow crosses lanes either. The skip
+    # prefix is then min(low_cum, MAX_SKIP) (skipped = the first
+    # MAX_SKIP low entries in ring order) and the source prefix is
+    # feas_cum - skip_cum. (int64 field-packing would lift the 2**15
+    # bound, but int64 prefix sums are pathologically slow on this
+    # backend — int32 lanes are free.)
+    valid = iota < n_real
+    nr = jnp.maximum(n_real, 1)
+
+    feas_v = feasible & valid
+    # threshold 0 is exact in both modes (int: score60 <= 0 iff the
+    # rational score <= 0; float: the host's 0.0 skip threshold)
+    low = feas_v & (final <= 0)
+
+    def ring_cumsum(a_int):
+        # the natural order is the flat one: a folded plane is the
+        # same words in the same order, so the flat view is free
+        s_flat = jnp.cumsum(a_int.reshape(n_pad))
+        s_nat = s_flat.reshape(node_shape)
+        total = s_flat[-1]
+        before = jnp.sum(jnp.where(iota < offset, a_int, 0), dtype=i32)
+        ring = jnp.where(
+            iota >= offset, s_nat - before, s_nat + (total - before)
+        )
+        return ring, total
+
+    if n_pad < PACK_COUNT_MAX:
+        packed_cum, packed_total = ring_cumsum(pack_count_lanes(low, feas_v))
+        low_cum = unpack_count_lo(packed_cum)
+        feas_cum = unpack_count_hi(packed_cum)
+        low_total = unpack_count_lo(packed_total)
+        feas_total = unpack_count_hi(packed_total)
+    else:
+        # lanes would overflow on a >32K-node pad: two plain cumsums
+        low_cum, low_total = ring_cumsum(low.astype(i32))
+        feas_cum, feas_total = ring_cumsum(feas_v.astype(i32))
+
+    skipped = low & (low_cum <= MAX_SKIP)
+    skip_cum = jnp.minimum(low_cum, MAX_SKIP)
+    ret = feas_v & ~skipped
+    ret_i = ret.astype(i32)
+    ret_cum = feas_cum - skip_cum
+    ret_excl = ret_cum - ret_i
+
+    pulled = valid & (ret_excl < limit)
+    src_cand = ret & pulled
+    ret_total = feas_total - jnp.minimum(low_total, MAX_SKIP)
+    backlog_n = jnp.maximum(limit - ret_total, 0)
+    skip_i = skipped.astype(i32)
+    skip_excl = skip_cum - skip_i
+    backlog_cand = skipped & (skip_excl < backlog_n)
+    cand = src_cand | backlog_cand
+
+    # -- the winner: ONE lexicographic reduction -----------------------------
+    # ranks are unique across candidates (source ranks < ret_total <=
+    # backlog ranks), so (max score, min rank) names one node exactly.
+    # Every non-candidate is the same sentinel (neg_inf, MAX, MAX), and
+    # two candidates never tie on both keys: the join is a maximum under
+    # a total order, associative and commutative over the whole plane.
+    # The node index rides with the kept side; the pulled count is a sum
+    # beside it. A candidate's rank is under n_pad, so "any candidate" is
+    # "the kept rank is not the sentinel". Int mode compares the int64
+    # score60 as two keys, its high half signed and its low half
+    # unsigned: int32 compares, a step 1.0-1.3 us faster on a v5e than one
+    # int64 compare (PERF.md §7).
+    rank = jnp.where(src_cand, ret_excl, ret_total + skip_excl)
+    big = i32(_I32_MAX)
+    if int_mode:
+        neg_inf = jnp.iinfo(jnp.int64).min // 4
+    else:
+        neg_inf = jnp.asarray(-jnp.inf, final.dtype)
+    cand_scores = jnp.where(cand, final, neg_inf)
+    rest = (jnp.where(cand, rank, big), jnp.where(cand, iota, big),
+            pulled.astype(i32))
+    if int_mode:
+        keys = ((cand_scores >> 32).astype(i32),
+                cand_scores.astype(jnp.uint32))
+        key_inits = (i32(neg_inf >> 32), jnp.uint32(neg_inf & 0xFFFFFFFF))
+    else:
+        keys, key_inits = (cand_scores,), (neg_inf,)
+    n_keys = len(keys)
+
+    def keep_max(a, b):
+        # (*score keys, rank, index, pulled): the higher score, else the
+        # lower rank, keeps its keys, rank and index; pulled adds up
+        def a_first(k):
+            if k == n_keys:
+                return a[k] < b[k]
+            return (a[k] > b[k]) | ((a[k] == b[k]) & a_first(k + 1))
+
+        take_a = a_first(0)
+        return tuple(jlax.select(take_a, x, y)
+                     for x, y in zip(a[:-1], b[:-1])) + (a[-1] + b[-1],)
+
+    *best_keys, best_rank, index, n_pulled = jlax.reduce(
+        keys + rest, key_inits + (big, big, i32(0)), keep_max, nodes)
+    if int_mode:
+        best_hi, best_lo = best_keys
+        best_score = ((best_hi.astype(jnp.int64) << 32)
+                      | best_lo.astype(jnp.int64))
+    else:
+        (best_score,) = best_keys
+    any_cand = best_rank != big
+    chosen = jnp.where(any_cand & (~skip_step), index, -1)
+
+    pulls = jnp.where(skip_step, 0, n_pulled).astype(i32)
+    offset = jnp.where(skip_step, offset, (offset + pulls) % nr).astype(i32)
+
+    # -- near tie: the candidates inside the band ----------------------------
+    # Q30 orders two nodes as float64 does only while their scores lie
+    # farther apart than the two roundings (intscore.py:
+    # NEAR_TIE_BAND_Q30). The step names the candidates whose score is
+    # UNDER the winner's by no more than the band: the nearest, the
+    # farthest, and whether more than these two crowd it; or -1, on
+    # nearly every step. The host scores them in float64
+    # (tpu/referee.py) and the device's pick stands unless float64 orders
+    # them the other way. Candidates that tie with the winner to the last
+    # bit are its twins wherever they hold the winner's cpu and memory,
+    # capacity and use (an idle fleet is all twins): they score the same
+    # in both arithmetics and tie to the ring's first in both. Whether all
+    # of them do is ONE comparison: the four numbers ride a wrapping int32
+    # sum of odd multiples (two different nodes share one once in 2**32),
+    # and its min and max over the tied set differ when a tie is not a
+    # twin, which also reads "crowded". All of it is one reduction after
+    # the winner's, which it reads. The key costs a step 5 us on a v5e
+    # however its reductions are written (PERF.md §6): its arithmetic over
+    # the node plane, not them.
+    if not (int_mode and n_pad <= (1 << RIVAL_BITS)):
+        return chosen, best_score, pulls, offset, i32(-1)
+    winners = cand & (cand_scores == best_score)
+    mix = (totals[0] * i32(_MIX[0]) + totals[1] * i32(_MIX[1])
+           + util[0] * i32(_MIX[2]) + util[1] * i32(_MIX[3]))
+    delta = best_score - cand_scores
+    in_band = (delta > 0) & (delta <= NEAR_TIE_BAND60)
+    key = ((delta >> 1).astype(i32) << RIVAL_BITS) | iota
+    operands = [
+        (jnp.where(winners, mix, _I32_MAX), _I32_MAX, jnp.minimum),
+        (jnp.where(winners, mix, -_I32_MAX - 1), -_I32_MAX - 1, jnp.maximum),
+        (jnp.where(in_band, key, _I32_MAX), _I32_MAX, jnp.minimum),
+        (jnp.where(in_band, key, 0), 0, jnp.maximum),
+        (in_band.astype(i32), 0, jnp.add)]
+    arrays, inits, joins = zip(*operands)
+    mix_lo, mix_hi, nearest, farthest, members = jlax.reduce(
+        arrays, tuple(i32(v) for v in inits),
+        lambda a, b: tuple(j(x, y) for j, x, y in zip(joins, a, b)), nodes)
+    uneven = mix_lo != mix_hi
+    index_bits = i32((1 << RIVAL_BITS) - 1)
+    rival = jnp.where(
+        ((members > 0) | uneven) & any_cand & (~skip_step),
+        (nearest & index_bits) | ((farthest & index_bits) << RIVAL_BITS)
+        | (((members > 2) | uneven).astype(i32) << (2 * RIVAL_BITS)),
+        i32(-1),
+    )
+    return chosen, best_score, pulls, offset, rival
+
+
 def _make_step():
     """The per-placement scan body, shared by the single-eval scan, the
     eval-batched scan (vmapped over independent evals — the production
@@ -244,23 +456,9 @@ def _make_step():
     from .intscore import (
         FEAT_AFF_BIT,
         FEAT_FEAS_BIT,
-        NEAR_TIE_BAND60,
-        PACK_COUNT_MAX,
-        RIVAL_BITS,
-        pack_count_lanes,
         pack_presence_lanes,
-        unpack_count_hi,
-        unpack_count_lo,
         unpack_feat_lane,
     )
-
-    # the near-tie key, an int32: (distance >> 1) << 15 | node index (the
-    # band's 60 * 2**10 halves into 15 bits); two indices and the crowded
-    # bit ride one int32 out (intscore.RIVAL_BITS). _MIX: odd multipliers
-    # of the twin test's wrapping sum
-    _I32_MAX = (1 << 31) - 1
-    _MIX = (-1640531527, 506961463, 668265263, 374761393)
-    assert NEAR_TIE_BAND60 >> 1 < 1 << (31 - RIVAL_BITS)
 
     def step(static, carry, x, past_end=False):
         (totals, reserved, asks, feat_packed, aff_score, desired_counts,
@@ -622,7 +820,6 @@ def _make_step():
                     binpack + anti + resched
                     + jnp.where(aff_p, aff.astype(i64), 0) + spread_total
                 ) * factor
-                neg_inf = jnp.iinfo(jnp.int64).min // 4
                 score_zero = i64(0)
         else:
             with jax.named_scope("binpack_score"):
@@ -695,150 +892,12 @@ def _make_step():
                 presence = pack_presence_lanes(anti_present, pmask, aff_p, spread_p)
                 num_terms = (1 + jlax.population_count(presence)).astype(fdt)
                 final = (binpack + anti + resched + jnp.where(aff_p, aff, 0.0) + spread_total) / num_terms
-                neg_inf = -jnp.inf
                 score_zero = jnp.asarray(0.0, fdt)
 
         with jax.named_scope("select"):
-            # -- ring-ordered limit + max-score selection (no permutation) -----
-            # Ring prefix sums at natural index i: with S = natural inclusive
-            # cumsum, T = total, o = offset, the ring-order cumsum is
-            # S(i) - S(o-1) for i >= o and S(i) + (T - S(o-1)) for i < o —
-            # elementwise, so the LimitIterator emulation needs no gathers.
-            #
-            # ONE packed int32 ring cumsum carries everything: the low-score
-            # and feasible count planes ride 16-bit lanes of one int32 plane
-            # (intscore.pack_count_lanes). Lane exactness: both totals are
-            # bounded by n_pad < 2**15, so the low lane never carries into the
-            # high lane, and every SELECTED ring branch is lane-wise
-            # non-negative (i >= o selects S(i) - S(o-1) with [0..o-1] a
-            # subset of [0..i]; i < o selects S(i) + the suffix sum — both
-            # >= 0 per lane), so no borrow crosses lanes either. The skip
-            # prefix is then min(low_cum, MAX_SKIP) (skipped = the first
-            # MAX_SKIP low entries in ring order) and the source prefix is
-            # feas_cum - skip_cum. (int64 field-packing would lift the 2**15
-            # bound, but int64 prefix sums are pathologically slow on this
-            # backend — int32 lanes are free.)
-            valid = iota < n_real
-            nr = jnp.maximum(n_real, 1)
-
-            feas_v = feasible & valid
-            # threshold 0 is exact in both modes (int: score60 <= 0 iff the
-            # rational score <= 0; float: the host's 0.0 skip threshold)
-            low = feas_v & (final <= 0)
-
-            def ring_cumsum(a_int):
-                # the natural order is the flat one: a folded plane is the
-                # same words in the same order, so the flat view is free
-                s_flat = jnp.cumsum(a_int.reshape(n_pad))
-                s_nat = s_flat.reshape(node_shape)
-                total = s_flat[-1]
-                before = jnp.sum(jnp.where(iota < offset, a_int, 0),
-                                 dtype=jnp.int32)
-                ring = jnp.where(
-                    iota >= offset, s_nat - before, s_nat + (total - before)
-                )
-                return ring, total
-
-            if n_pad < PACK_COUNT_MAX:
-                packed_cum, packed_total = ring_cumsum(pack_count_lanes(low, feas_v))
-                low_cum = unpack_count_lo(packed_cum)
-                feas_cum = unpack_count_hi(packed_cum)
-                low_total = unpack_count_lo(packed_total)
-                feas_total = unpack_count_hi(packed_total)
-            else:
-                # lanes would overflow on a >32K-node pad: two plain cumsums
-                low_cum, low_total = ring_cumsum(low.astype(jnp.int32))
-                feas_cum, feas_total = ring_cumsum(feas_v.astype(jnp.int32))
-
-            skipped = low & (low_cum <= MAX_SKIP)
-            skip_cum = jnp.minimum(low_cum, MAX_SKIP)
-            ret = feas_v & ~skipped
-            ret_i = ret.astype(jnp.int32)
-            ret_cum = feas_cum - skip_cum
-            ret_excl = ret_cum - ret_i
-
-            limit = limit_p
-            pulled = valid & (ret_excl < limit)
-            src_cand = ret & pulled
-            ret_total = feas_total - jnp.minimum(low_total, MAX_SKIP)
-            backlog_n = jnp.maximum(limit - ret_total, 0)
-            skip_i = skipped.astype(jnp.int32)
-            skip_excl = skip_cum - skip_i
-            backlog_cand = skipped & (skip_excl < backlog_n)
-            cand = src_cand | backlog_cand
-
-            # ranks are unique across candidates (source ranks < ret_total <=
-            # backlog ranks), so (max score, min rank) names one node exactly
-            rank = jnp.where(src_cand, ret_excl, ret_total + skip_excl)
-
-            cand_scores = jnp.where(cand, final, neg_inf)
-            best_score = jnp.max(cand_scores)
-            winners = cand & (cand_scores == best_score)
-            winner_rank = jnp.where(winners, rank, jnp.int32(2**31 - 1))
-            best_rank = jnp.min(winner_rank)
-            any_cand = jnp.any(cand)
-            first = winners & (rank == best_rank)
-
-            # -- near tie: the candidates inside the band --------------------
-            # Q30 orders two nodes as float64 does only while their scores
-            # lie farther apart than the two roundings (intscore.py:
-            # NEAR_TIE_BAND_Q30). The step names the candidates whose score
-            # is UNDER the winner's by no more than the band: the nearest,
-            # the farthest, and whether more than these two crowd it; or
-            # -1, on nearly every step. The host scores them in float64
-            # (tpu/referee.py) and the device's pick stands unless float64
-            # orders them the other way. Candidates that tie with the
-            # winner to the last bit are its twins wherever they hold the
-            # winner's cpu and memory, capacity and use (an idle fleet is
-            # all twins): they score the same in both arithmetics and tie
-            # to the ring's first in both. Whether all of them do is ONE
-            # comparison: the four numbers ride a wrapping int32 sum of odd
-            # multiples (two different nodes share one once in 2**32), and
-            # its min and max over the tied set differ when a tie is not a
-            # twin, which also reads "crowded". All of it rides the
-            # reduction that finds ``chosen``, as further operands, so the
-            # compiled loop has the parent's count of kernels. The key costs
-            # a step 5 us on a v5e however its reductions are written
-            # (PERF.md §6): its arithmetic over the node plane, not them.
-            i32 = jnp.int32
-            refereed = int_mode and n_pad <= (1 << RIVAL_BITS)
-            operands = [(jnp.where(first, iota, _I32_MAX), _I32_MAX, jnp.minimum)]
-            if refereed:
-                mix = (totals[0] * i32(_MIX[0]) + totals[1] * i32(_MIX[1])
-                       + util[0] * i32(_MIX[2]) + util[1] * i32(_MIX[3]))
-                delta = best_score - cand_scores
-                in_band = (delta > 0) & (delta <= NEAR_TIE_BAND60)
-                key = ((delta >> 1).astype(i32) << RIVAL_BITS) | iota
-                operands += [
-                    (jnp.where(winners, mix, _I32_MAX), _I32_MAX, jnp.minimum),
-                    (jnp.where(winners, mix, -_I32_MAX - 1), -_I32_MAX - 1,
-                     jnp.maximum),
-                    (jnp.where(in_band, key, _I32_MAX), _I32_MAX, jnp.minimum),
-                    (jnp.where(in_band, key, 0), 0, jnp.maximum),
-                    (in_band.astype(i32), 0, jnp.add)]
-            arrays, inits, joins = zip(*operands)
-            found = jlax.reduce(
-                arrays, tuple(i32(v) for v in inits),
-                lambda a, b: tuple(j(x, y) for j, x, y in zip(joins, a, b)),
-                tuple(range(len(node_shape))))
-            chosen = jnp.where(any_cand & (~skip_step), found[0], -1)
-
-            pulls = jnp.where(skip_step, 0, jnp.sum(pulled.astype(jnp.int32))).astype(jnp.int32)
-            offset = jnp.where(skip_step, offset, (offset + pulls) % nr).astype(jnp.int32)
-
-            if refereed:
-                _first, mix_lo, mix_hi, nearest, farthest, members = found
-                uneven = mix_lo != mix_hi
-                index = i32((1 << RIVAL_BITS) - 1)
-                rival = jnp.where(
-                    ((members > 0) | uneven) & any_cand & (~skip_step),
-                    (nearest & index) | ((farthest & index) << RIVAL_BITS)
-                    | (((members > 2) | uneven).astype(i32)
-                       << (2 * RIVAL_BITS)),
-                    i32(-1),
-                )
-            else:
-                rival = i32(-1)
+            chosen, best_score, pulls, offset, rival = _select(
+                final, feasible, iota, n_real, offset, limit_p, skip_step,
+                totals, util)
 
         with jax.named_scope("carry_update"):
             # -- apply placement / revert eviction (one-hot adds) --------------
